@@ -1,7 +1,7 @@
 """2D (data × model) feature-sharded solver benchmark (DESIGN.md §10):
 
 1. **d-sweep** — 1D replicated-primal vs 2D feature-sharded epoch time
-   at equal device count on an 8-host-device subprocess.  The 1D path
+   at equal device count, on the devices this process sees.  The 1D path
    pays O(d) per round (full-primal psum + update) regardless of
    sparsity; the 2D path pays O(d/m) plus per-update scalar psums, so
    the crossover moves toward 2D as d grows — the webspam/kddb regime.
@@ -18,12 +18,6 @@ out/BENCH_feature.json.
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
 from benchmarks.common import emit
 from repro.dist.mesh import (
     dcd_ell_kernel_fits,
@@ -34,34 +28,37 @@ from repro.dist.mesh import (
     dcd_kernel_vmem_bytes,
 )
 
-# the sweep runs in a subprocess so it can fan 8 host devices out as a
-# (data=8) mesh vs a (data=2, model=4) mesh without polluting the
-# parent's single-device jax state (same trick as the sharded tests)
-_SWEEP = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import sys
-    sys.path.insert(0, {root!r})
-    sys.path.insert(0, {src!r})
-    import json
+D_SWEEP = (131_072, 1_048_576, 4_194_304)
+
+
+def _run_sweep(rows):
+    """1D replicated primal vs 2D feature-sharded epoch, on every device
+    this process sees: ``data=p`` against ``(data=p/m, model=m)`` with
+    the widest m ∈ {4, 2, 1} dividing p.  Runs in this process, so on a
+    chip host it holds the chip itself; a failure propagates."""
+    import jax
+    import jax.numpy as jnp
     import numpy as np
-    import jax, jax.numpy as jnp
+
     from benchmarks.common import timeit
     from repro.core.duals import Hinge
     from repro.core.sharded import (
-        _masked_block_perms, make_sharded_epoch, make_sharded_epoch_2d,
+        _masked_block_perms,
+        make_sharded_epoch,
+        make_sharded_epoch_2d,
     )
     from repro.data.sparse import EllMatrix, ell_column_split
+    from repro.dist.mesh import solver_mesh, solver_mesh_2d
     from repro.dist.sharding import named, replicated
 
     N, K, B = 256, 8, 32
-    D_SWEEP = (131_072, 1_048_576, 4_194_304)
     loss = Hinge(C=1.0)
     rng = np.random.default_rng(7)
-    rows = []
-
-    mesh1 = jax.make_mesh((8,), ("data",))
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    p1 = len(jax.devices())
+    m2 = next(m for m in (4, 2, 1) if p1 % m == 0)
+    p2 = p1 // m2
+    mesh1 = solver_mesh("data")
+    mesh2 = solver_mesh_2d(model=m2)
 
     for d in D_SWEEP:
         idx = np.stack([rng.choice(d, size=K, replace=False)
@@ -72,8 +69,7 @@ _SWEEP = textwrap.dedent("""
         sq = ell.row_sq_norms()
         alpha = jnp.zeros((N,), jnp.float32)
 
-        # ---- 1D replicated primal (the PR-3 ELL path) ----
-        p1 = 8
+        # ---- 1D replicated primal (the ELL path) ----
         blocks1 = _masked_block_perms(jax.random.PRNGKey(0), p1, N // p1,
                                       N, max(N // p1 // B, 1), B)
         blocks1 = jax.device_put(
@@ -89,12 +85,11 @@ _SWEEP = textwrap.dedent("""
         fn1 = make_sharded_epoch(mesh1, loss, ell=True)
         t1 = timeit(lambda: fn1(X1, sq1, a1, w1, blocks1, c1))
         rows.append(dict(
-            name=f"feature/sweep_1d_replicated/n={{N}},d={{d}},p=8",
+            name=f"feature/sweep_1d_replicated/n={N},d={d},p={p1}",
             us_per_call=t1 * 1e6,
-            derived=f"primal_words_per_device={{d + 1}}"))
+            derived=f"primal_words_per_device={d + 1}"))
 
-        # ---- 2D feature-sharded (this PR) ----
-        p2, m2 = 2, 4
+        # ---- 2D feature-sharded ----
         fse = ell_column_split(ell, m2)
         d1_loc = fse.d_loc + 1
         n_loc = N // p2
@@ -115,30 +110,10 @@ _SWEEP = textwrap.dedent("""
         fn2 = make_sharded_epoch_2d(mesh2, loss)
         t2 = timeit(lambda: fn2(X2, sq2, a2, w2, blocks2, c2))
         rows.append(dict(
-            name=f"feature/sweep_2d_sharded/n={{N}},d={{d}},p=2,m=4",
+            name=f"feature/sweep_2d_sharded/n={N},d={d},p={p2},m={m2}",
             us_per_call=t2 * 1e6,
-            derived=(f"primal_words_per_device={{d1_loc}},"
-                     f"speedup_vs_1d={{t1 / t2:.2f}}x")))
-
-    print("ROWS_JSON " + json.dumps(rows))
-""")
-
-
-def _run_sweep(rows):
-    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-    src = os.path.join(root, "src")
-    code = _SWEEP.format(root=root, src=src)
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=1800)
-    if out.returncode != 0:
-        print(f"# feature sweep subprocess failed:\n{out.stderr[-2000:]}",
-              file=sys.stderr)
-        return
-    for line in out.stdout.splitlines():
-        if line.startswith("ROWS_JSON "):
-            rows.extend(json.loads(line[len("ROWS_JSON "):]))
+            derived=(f"primal_words_per_device={d1_loc},"
+                     f"speedup_vs_1d={t1 / t2:.2f}x")))
 
 
 def _vmem_frontier(rows):

@@ -38,7 +38,7 @@ from repro.core.cocoa import cocoa_pod_solve
 from repro.core.duals import Hinge
 from repro.core.sharded import _n_blocks, make_sharded_pipeline
 from repro.data.sparse import EllMatrix
-from repro.dist.mesh import solver_mesh
+from repro.dist.mesh import make_mesh, solver_mesh
 from repro.dist.sharding import named, replicated
 
 
@@ -88,10 +88,10 @@ def _bench_overhead(rows, *, smoke: bool):
     loss = Hinge(C=1.0)
     n_dev = len(jax.devices())
     meshes = [("plain", solver_mesh("data"))]
-    meshes.append(("pod1", jax.make_mesh((1, n_dev), ("pod", "data"))))
+    meshes.append(("pod1", make_mesh((1, n_dev), ("pod", "data"))))
     if n_dev >= 2 and n_dev % 2 == 0:
         meshes.append(
-            ("pod2", jax.make_mesh((2, n_dev // 2), ("pod", "data"))))
+            ("pod2", make_mesh((2, n_dev // 2), ("pod", "data"))))
     ell = _make_ell(np.random.default_rng(11), n, d, k)
     times = {}
     for name, mesh in meshes:

@@ -34,6 +34,7 @@ import jax
 from benchmarks.common import emit, timeit
 from repro.core.duals import Hinge
 from repro.core.sharded import sharded_passcode_solve
+from repro.dist.mesh import make_mesh
 from repro.resilience import FaultPlan, load_solver_state, solve_segmented
 from repro.train.checkpoint import latest_step, save_checkpoint
 
@@ -97,7 +98,7 @@ def _bench_recovery(rows, *, smoke: bool):
     loss = Hinge(C=1.0)
     X = _make_dense(np.random.default_rng(11), n, d)
     mid = epochs // 2  # fault epoch: mid-solve, second segment
-    pod_mesh = jax.make_mesh((1, len(jax.devices())), ("pod", "data"))
+    pod_mesh = make_mesh((1, len(jax.devices())), ("pod", "data"))
     cases = [
         ("nan_psum", FaultPlan(nan_psum_epoch=mid),
          dict(delay_rounds=1)),

@@ -57,6 +57,9 @@ def _persist(tag: str, rows) -> None:
 
 
 def main() -> None:
+    from repro.runtime import use_compile_cache
+
+    use_compile_cache(_ROOT)
     from benchmarks import (
         bench_accuracy,
         bench_adaptive,

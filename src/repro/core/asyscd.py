@@ -24,7 +24,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.objective import duality_gap
+from repro.core.objective import duality_gap, f32_dot
 
 
 class AsyscdResult(NamedTuple):
@@ -36,9 +36,9 @@ class AsyscdResult(NamedTuple):
 @functools.partial(jax.jit, static_argnames=("loss", "n_threads"))
 def _asyscd_epoch(X, sq_norms, alpha, rounds_idx, loss, n_threads, gamma):
     def round_step(alpha, idx):
-        w_bar = X.T @ alpha  # no primal maintenance: O(nnz) per round
+        w_bar = f32_dot(X.T, alpha)  # no primal maintenance: O(nnz) per round
         rows = X[idx]
-        grad = jax.vmap(loss.dual_grad)(alpha[idx], rows @ w_bar)
+        grad = jax.vmap(loss.dual_grad)(alpha[idx], f32_dot(rows, w_bar))
         step = gamma * grad / jnp.maximum(sq_norms[idx], 1e-12)
         new = jax.vmap(loss.feasible)(alpha[idx] - step)
         return alpha.at[idx].set(new), ()

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.core.objective import (
     duality_gap,
+    f32_dot,
     perturbed_primal_objective,
     predict_accuracy,
     primal_objective,
@@ -36,7 +37,7 @@ from repro.data.sparse import EllMatrix, ell_matvec
 def _all_row_dots(X, w):
     if isinstance(X, EllMatrix):
         return ell_matvec(X, w)
-    return X @ w
+    return f32_dot(X, w)
 
 
 def fixpoint_residual(X, loss, alpha, w):

@@ -19,7 +19,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.objective import duality_gap, w_of_alpha
+from repro.core.objective import duality_gap, f32_dot, w_of_alpha
 
 
 class CocoaResult(NamedTuple):
@@ -59,7 +59,7 @@ def _cocoa_round(X, sq_norms, alpha, w, part_idx, perm_keys, loss,
             i = rows_idx[local_perm[t % rows_idx.shape[0]]]
             x = X[i]
             a_i = alpha[i] + d_alpha[local_perm[t % rows_idx.shape[0]]]
-            delta = loss.delta(a_i, jnp.dot(w_loc, x), sq_norms[i])
+            delta = loss.delta(a_i, f32_dot(w_loc, x), sq_norms[i])
             d_alpha = d_alpha.at[local_perm[t % rows_idx.shape[0]]].add(delta)
             return d_alpha, w_loc + delta * x
 
@@ -127,7 +127,7 @@ def _pod_local_epoch(X, sq_norms, alpha, w, base, nvalid, rows, loss):
         ok = rows[t] < nvalid
         i = jnp.minimum(base + rows[t], n - 1)
         x = X[i]
-        delta = loss.delta(a[i], jnp.dot(w_loc, x), sq_norms[i])
+        delta = loss.delta(a[i], f32_dot(w_loc, x), sq_norms[i])
         delta = jnp.where(ok, delta, 0.0)
         return a.at[i].add(delta), w_loc + delta * x
 

@@ -17,7 +17,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.objective import duality_gap, w_of_alpha
+from repro.core.objective import duality_gap, f32_dot, w_of_alpha
 from repro.data.sparse import EllMatrix, pad_primal, unpad_primal
 
 
@@ -32,7 +32,7 @@ def _dcd_epoch_dense(X, sq_norms, state: DcdState, perm, loss) -> DcdState:
         alpha, w = carry
         i = perm[k]
         x = X[i]
-        wx = jnp.dot(w, x)
+        wx = f32_dot(w, x)
         delta = loss.delta(alpha[i], wx, sq_norms[i])
         alpha = alpha.at[i].add(delta)
         w = w + delta * x

@@ -15,17 +15,28 @@ import jax.numpy as jnp
 
 from repro.data.sparse import EllMatrix, ell_matvec, ell_rmatvec
 
+# Every f32 dot/matvec of the solvers runs at full f32 precision.  On CPU
+# that is what a dot does anyway; on TPU the default precision rounds
+# f32 operands to bf16 passes, which breaks the atol 1e-5 agreement of
+# the fused, unfused and pipelined paths and the primal–dual invariant.
+DOT_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def f32_dot(a, b):
+    """``jnp.dot`` at ``DOT_PRECISION`` — the solvers' only dot."""
+    return jnp.dot(a, b, precision=DOT_PRECISION)
+
 
 def _matvec(X, w):
     if isinstance(X, EllMatrix):
         return ell_matvec(X, w)
-    return X @ w
+    return f32_dot(X, w)
 
 
 def _rmatvec(X, alpha):
     if isinstance(X, EllMatrix):
         return ell_rmatvec(X, alpha)
-    return X.T @ alpha
+    return f32_dot(X.T, alpha)
 
 
 def w_of_alpha(X, alpha):
@@ -36,13 +47,13 @@ def w_of_alpha(X, alpha):
 def primal_objective(w, X, loss):
     """P(w) = ½‖w‖² + Σ ℓ_i(wᵀx_i)  (eq. 1)."""
     z = _matvec(X, w)
-    return 0.5 * jnp.dot(w, w) + jnp.sum(loss.primal_loss(z))
+    return 0.5 * f32_dot(w, w) + jnp.sum(loss.primal_loss(z))
 
 
 def dual_objective(alpha, X, loss):
     """D(α) = ½‖Σ α_i x_i‖² + Σ ℓ*(−α_i)  (eq. 2)."""
     w = _rmatvec(X, alpha)
-    return 0.5 * jnp.dot(w, w) + jnp.sum(loss.conj(alpha))
+    return 0.5 * f32_dot(w, w) + jnp.sum(loss.conj(alpha))
 
 
 def duality_gap(alpha, X, loss):
@@ -56,7 +67,7 @@ def perturbed_primal_objective(w, X, loss, eps):
     solves under PASSCoDe-Wild (Corollary 1)."""
     z = _matvec(X, w)
     we = w + eps
-    return 0.5 * jnp.dot(we, we) + jnp.sum(loss.primal_loss(z))
+    return 0.5 * f32_dot(we, we) + jnp.sum(loss.primal_loss(z))
 
 
 def predict_accuracy(w, X):

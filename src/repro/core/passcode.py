@@ -37,7 +37,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.objective import duality_gap, w_of_alpha
+from repro.core.objective import duality_gap, f32_dot, w_of_alpha
 from repro.data.sparse import EllMatrix
 
 
@@ -86,7 +86,7 @@ def _passcode_epoch_dense(
             alpha, w = ac
             i = idx[k]
             x = X[i]
-            delta = loss.delta(alpha[i], jnp.dot(w, x), sq_norms[i])
+            delta = loss.delta(alpha[i], f32_dot(w, x), sq_norms[i])
             return alpha.at[i].add(delta), w + delta * x
 
         alpha, w = jax.lax.fori_loop(0, p, body, (alpha, w))
@@ -98,7 +98,7 @@ def _passcode_epoch_dense(
         # --- stale read: round-start snapshot, minus `delay` recent rounds.
         w_read = w - jnp.sum(hist, axis=0) if delay > 0 else w
         rows = X[idx]  # (p, d)
-        wx = rows @ w_read  # (p,)
+        wx = f32_dot(rows, w_read)  # (p,)
         deltas = jax.vmap(loss.delta)(alpha[idx], wx, sq_norms[idx])  # (p,)
         contrib = deltas[:, None] * rows  # (p, d)
         # --- write-back.

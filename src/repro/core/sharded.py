@@ -97,7 +97,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core.objective import duality_gap
+from repro.core.objective import duality_gap, f32_dot
 from repro.core.shrinking import active_mask_from_w
 from repro.data.sparse import (
     EllMatrix,
@@ -109,17 +109,20 @@ from repro.data.sparse import (
 from repro.dist.compat import shard_map
 from repro.dist.mesh import (
     adaptive_delay_policy,
+    auto_mesh,
     data_axes,
     dcd_ell_kernel_fits,
     dcd_feature_kernel_fits,
     dcd_kernel_fits,
     lane_pad,
+    make_mesh,
     pipeline_overlap,
     pod_merge_policy,
     resolve_self_tuning,
     solver_mesh,
     solver_mesh_2d,
     solver_mesh_3d,
+    solver_mesh_tasks,
     task_axis_policy,
     watchdog_trip,
 )
@@ -145,6 +148,7 @@ class ShardedResult(NamedTuple):
     #   regularizer distance of core/backward_error.py (paper §4.2)
     active: jnp.ndarray | None = None  # active-set fraction (shrinking)
     delay: jnp.ndarray | None = None  # effective delay flag (adaptive)
+    engine: str | None = None  # the block engine that ran (engine_name)
 
 
 def _local_block_update(X_loc, sq_loc, alpha_loc, w, idx_block, loss,
@@ -162,7 +166,7 @@ def _local_block_update(X_loc, sq_loc, alpha_loc, w, idx_block, loss,
         alpha_loc, w_loc = carry
         i = idx_block[t]
         x = X_loc[i]
-        wx = jnp.dot(w_loc, x)
+        wx = f32_dot(w_loc, x)
         if y is not None:
             wx = y[i] * wx
         delta = loss.delta(alpha_loc[i], wx, sq_loc[i])
@@ -603,10 +607,10 @@ def _make_gap_1d(loss, X_loc, ell: bool, axes=("data",)):
             return jnp.sum(wa[cols_loc] * vals_loc, axis=1)
     else:
         def rmv(am, d_run):
-            return X_loc.T @ am
+            return f32_dot(X_loc.T, am)
 
         def mv(wa):
-            return X_loc @ wa
+            return f32_dot(X_loc, wa)
 
     def gap(rec, alpha_loc, mask, d_run, w_view, y=None):
         am = jnp.where(mask, alpha_loc, 0.0)
@@ -623,9 +627,9 @@ def _make_gap_1d(loss, X_loc, ell: bool, axes=("data",)):
                 z = y * z
             s = jnp.sum(jnp.where(
                 mask, loss.primal_loss(z) + loss.conj(am), 0.0))
-            g = jnp.dot(wa, wa) + jax.lax.psum(s, axes)
+            g = f32_dot(wa, wa) + jax.lax.psum(s, axes)
             e = wa - w_view  # dummy/pad slots are 0 in both
-            return g, jnp.sqrt(jnp.dot(e, e))
+            return g, jnp.sqrt(f32_dot(e, e))
 
         return jax.lax.cond(
             rec, compute,
@@ -662,10 +666,10 @@ def _make_gap_2d(loss, cols_loc, vals_loc, d1_loc: int, axes=("data",)):
                 z = y * z
             s = jnp.sum(jnp.where(
                 mask, loss.primal_loss(z) + loss.conj(am), 0.0))
-            g = (jax.lax.psum(jnp.dot(wa, wa), "model")
+            g = (jax.lax.psum(f32_dot(wa, wa), "model")
                  + jax.lax.psum(s, axes))
             e = wa - w_view  # dummy slots are 0 in both
-            return g, jnp.sqrt(jax.lax.psum(jnp.dot(e, e), "model"))
+            return g, jnp.sqrt(jax.lax.psum(f32_dot(e, e), "model"))
 
         return jax.lax.cond(
             rec, compute,
@@ -689,7 +693,7 @@ def _make_shrink_1d(loss, X_loc, ell: bool, shrink_tol: float, valid):
             return jnp.sum(wv[cols_loc] * vals_loc, axis=1)
     else:
         def mv(wv):
-            return X_loc @ wv
+            return f32_dot(X_loc, wv)
 
     def mask_fn(alpha_loc, w_view, y=None):
         wx = mv(w_view)
@@ -1848,6 +1852,18 @@ def _place_labels(mesh, y, *, n, n_pad, ridx, pod_on):
     return K, jax.device_put(Yp, named(mesh, tax, data_axes(mesh)))
 
 
+def _pad_host(a, shape, fill, dtype, rowmap=None):
+    """``a`` copied into the leading corner of a ``fill``-valued host
+    array of ``shape``, then, on a pod mesh, its rows gathered through
+    ``rowmap`` (row n is the padding row).  Built in numpy so that
+    ``device_put`` sends each shard straight to its device: the padded
+    dataset never sits whole on one device."""
+    a = np.asarray(a, dtype)
+    out = np.full(shape, fill, dtype)
+    out[tuple(slice(0, s) for s in a.shape)] = a
+    return out if rowmap is None else out[rowmap]
+
+
 def prepare_solver(
     X_host,
     loss,
@@ -1890,8 +1906,7 @@ def prepare_solver(
                 m_ax = 2 if (n_dev // pods) % 2 == 0 else 1
                 mesh = solver_mesh_3d(pod=pods, model=m_ax)
             else:
-                mesh = jax.make_mesh((pods, n_dev // pods),
-                                     ("pod", "data"))
+                mesh = make_mesh((pods, n_dev // pods), ("pod", "data"))
         elif "model" in mesh_axes:
             mesh = solver_mesh_2d()
         else:
@@ -1899,7 +1914,9 @@ def prepare_solver(
     if "model" in mesh.axis_names and "data" not in mesh.axis_names:
         # legacy 1-D ("model",) mesh → (data=1, model=m): serial in i
         # within each round, features sharded
-        mesh = Mesh(mesh.devices.reshape(1, -1), ("data", "model"))
+        mesh = make_mesh((1, mesh.devices.size), ("data", "model"),
+                         devices=mesh.devices.reshape(-1))
+    mesh = auto_mesh(mesh)
     pod_on = "pod" in mesh.axis_names
     if pod_on:
         pod_merge_policy(pod_delay_rounds, n_pods=mesh.shape["pod"],
@@ -1956,27 +1973,18 @@ def prepare_solver(
         # pad rows to n_pad with all-padding rows (local id d_loc, 0)
         k_run = lane_pad(k_loc) if use_k else k_loc
         d1_loc = lane_pad(d_loc + 1) if use_k else d_loc + 1
-        ridx = None
+        ridx = rows = None
         if pod_on:
             rowmap, _ = pod_row_layout(n, pods, per_pod_rows=p * n_loc)
-            ridx = jnp.asarray(rowmap.reshape(-1))  # global id, n = pad
-            cols = jnp.full((n + 1, m, k_run), d_loc, jnp.int32)
-            cols = cols.at[:n, :, :k_loc].set(
-                jnp.asarray(fse.indices, jnp.int32))[ridx]
-            vals = jnp.zeros((n + 1, m, k_run), jnp.float32)
-            vals = vals.at[:n, :, :k_loc].set(
-                jnp.asarray(fse.values, jnp.float32))[ridx]
-            sq_norms = jnp.ones((n + 1,), jnp.float32).at[:n].set(
-                fse.row_sq_norms())[ridx]
-        else:
-            cols = jnp.full((n_pad, m, k_run), d_loc, jnp.int32)
-            cols = cols.at[:n, :, :k_loc].set(
-                jnp.asarray(fse.indices, jnp.int32))
-            vals = jnp.zeros((n_pad, m, k_run), jnp.float32)
-            vals = vals.at[:n, :, :k_loc].set(
-                jnp.asarray(fse.values, jnp.float32))
-            sq_norms = jnp.ones((n_pad,), jnp.float32).at[:n].set(
-                fse.row_sq_norms())
+            rows = rowmap.reshape(-1)  # global id, n = pad
+            ridx = jnp.asarray(rows)
+        lead = n + 1 if pod_on else n_pad
+        cols = _pad_host(fse.indices, (lead, m, k_run), d_loc, np.int32,
+                         rows)
+        vals = _pad_host(fse.values, (lead, m, k_run), 0.0, np.float32,
+                         rows)
+        sq_norms = _pad_host(fse.row_sq_norms(), (lead,), 1.0, np.float32,
+                             rows)
         x_sh = named(mesh, data_axes(mesh), "model", None)
         X = (jax.device_put(cols, x_sh), jax.device_put(vals, x_sh))
         n_tasks, Y = ((0, None) if y is None else _place_labels(
@@ -2005,10 +2013,11 @@ def prepare_solver(
     n_pod_loc = max(-(-n // pods), 1)
     n_loc = -(-n_pod_loc // p)  # ceil: the tail is padded, not dropped
     n_pad = pods * p * n_loc
-    ridx = None
+    ridx = rows = None
     if pod_on:
         rowmap, _ = pod_row_layout(n, pods, per_pod_rows=p * n_loc)
-        ridx = jnp.asarray(rowmap.reshape(-1))  # global id, n = padding
+        rows = rowmap.reshape(-1)  # global id, n = padding
+        ridx = jnp.asarray(rows)
     use_k, interpret = _resolve_kernel_mode(use_kernel, n_loc, d, k_max)
     # a 1-D mesh has no model-axis psum: "auto" resolves to no overlap,
     # an explicit True is an error
@@ -2025,27 +2034,15 @@ def prepare_solver(
         # padded primal with the dummy slot at index d (lane-padded for
         # clean tiling when fused); padding scatter-adds land there
         d_run = lane_pad(d + 1) if use_k else d + 1
-        if pod_on:
-            # pod layout: gather through the flattened rowmap with a
-            # padding row appended at global index n — each pod's
-            # contiguous shard lands with its own padded tail
-            cols = jnp.full((n + 1, k_run), d, jnp.int32)
-            cols = cols.at[:n, :k_max].set(
-                jnp.asarray(X_host.indices, jnp.int32))[ridx]
-            vals = jnp.zeros((n + 1, k_run), jnp.float32)
-            vals = vals.at[:n, :k_max].set(
-                jnp.asarray(X_host.values, jnp.float32))[ridx]
-            sq_norms = jnp.ones((n + 1,), jnp.float32).at[:n].set(
-                X_host.row_sq_norms())[ridx]
-        else:
-            cols = jnp.full((n_pad, k_run), d, jnp.int32)
-            cols = cols.at[:n, :k_max].set(
-                jnp.asarray(X_host.indices, jnp.int32))
-            vals = jnp.zeros((n_pad, k_run), jnp.float32)
-            vals = vals.at[:n, :k_max].set(
-                jnp.asarray(X_host.values, jnp.float32))
-            sq_norms = jnp.ones((n_pad,), jnp.float32)
-            sq_norms = sq_norms.at[:n].set(X_host.row_sq_norms())
+        # pod layout: gather through the flattened rowmap with a padding
+        # row appended at global index n — each pod's contiguous shard
+        # lands with its own padded tail
+        lead = n + 1 if pod_on else n_pad
+        cols = _pad_host(X_host.indices, (lead, k_run), d, np.int32, rows)
+        vals = _pad_host(X_host.values, (lead, k_run), 0.0, np.float32,
+                         rows)
+        sq_norms = _pad_host(X_host.row_sq_norms(), (lead,), 1.0,
+                             np.float32, rows)
         X = (
             jax.device_put(cols, row_sh),
             jax.device_put(vals, row_sh),
@@ -2285,6 +2282,17 @@ def finalize_state(setup: SolverSetup, state: dict,
                      state["epsb"], state["actb"], state["delayb"])
 
 
+def engine_name(setup: SolverSetup) -> str:
+    """``<layout>/<engine>`` of a prepared solve: layout ``dense``,
+    ``ell`` or ``feature`` (2-D), engine ``jnp`` or ``pallas`` with its
+    mode — ``compiled`` on TPU, ``interpret`` elsewhere."""
+    layout = "feature" if setup.two_d else ("ell" if setup.ell else "dense")
+    if not setup.use_k:
+        return f"{layout}/jnp"
+    mode = "interpret" if setup.interpret else "compiled"
+    return f"{layout}/pallas-{mode}"
+
+
 def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
               eps_arr=None, act_arr=None, delay_arr=None):
     """Un-pad a finished solve back to user coordinates: invert the pod
@@ -2294,6 +2302,7 @@ def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
     over the trailing axes of the (K, …) stacks, so the result carries
     (K, n) duals / (K, d) weights / (K, n_gaps) records."""
     n, d = setup.n, setup.d
+    eng = engine_name(setup)
     if setup.n_tasks:
         if setup.pod_on:
             alpha = jax.vmap(
@@ -2306,9 +2315,10 @@ def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
         else:
             w = w[:, :d]
         if eps_arr is None:
-            return ShardedResult(alpha[:, :n], w, gaps_arr, epochs)
+            return ShardedResult(alpha[:, :n], w, gaps_arr, epochs,
+                                 engine=eng)
         return ShardedResult(alpha[:, :n], w, gaps_arr, epochs, eps_arr,
-                             act_arr, delay_arr)
+                             act_arr, delay_arr, eng)
     if setup.pod_on:
         alpha = jnp.zeros((n + 1,), jnp.float32).at[setup.ridx].set(alpha)
     if setup.two_d:
@@ -2317,9 +2327,9 @@ def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
     else:
         w = w[:d]
     if eps_arr is None:
-        return ShardedResult(alpha[:n], w, gaps_arr, epochs)
+        return ShardedResult(alpha[:n], w, gaps_arr, epochs, engine=eng)
     return ShardedResult(alpha[:n], w, gaps_arr, epochs, eps_arr,
-                         act_arr, delay_arr)
+                         act_arr, delay_arr, eng)
 
 
 def _validate_solver_inputs(X_host, y, loss):

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.duals import Hinge, SquaredHinge
-from repro.core.objective import duality_gap
+from repro.core.objective import duality_gap, f32_dot
 
 
 def active_mask(loss, alpha, grads, shrink_tol: float):
@@ -65,7 +65,7 @@ def _shrink_epoch(X, sq_norms, alpha, w, perm, mask, loss):
         i = perm[k]
         x = X[i]
         delta = jnp.where(
-            mask[i], loss.delta(alpha[i], jnp.dot(w, x), sq_norms[i]), 0.0
+            mask[i], loss.delta(alpha[i], f32_dot(w, x), sq_norms[i]), 0.0
         )
         return alpha.at[i].add(delta), w + delta * x
 
@@ -102,7 +102,7 @@ def dcd_solve_shrink(
         # single-device block_size=n sequence
         perm = jax.random.permutation(jax.random.split(sub, 1)[0], n)
         if e % shrink_every == 0:
-            wx = X @ w
+            wx = f32_dot(X, w)
             mask = active_mask_from_w(loss, alpha, wx, shrink_tol)
         run_mask = mask
         if unshrink and e == epochs - 1:

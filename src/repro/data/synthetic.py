@@ -74,63 +74,90 @@ def _zipf_probs(d: int) -> np.ndarray:
     return p / p.sum()
 
 
-def _make_split(rng, recipe: DatasetRecipe, n: int):
+_CHUNK_ROWS = 1 << 16
+_MAX_REDRAWS = 4
+
+
+def _first_distinct(draws: np.ndarray, k: int):
+    """Per row, the first ``k`` distinct ids of ``draws`` in draw order,
+    and which rows had at least ``k`` distinct ids."""
+    order = np.argsort(draws, axis=1, kind="stable")
+    srt = np.take_along_axis(draws, order, axis=1)
+    first_sorted = np.ones(draws.shape, bool)
+    first_sorted[:, 1:] = srt[:, 1:] != srt[:, :-1]
+    first = np.empty_like(first_sorted)
+    np.put_along_axis(first, order, first_sorted, axis=1)
+    keep = first & (np.cumsum(first, axis=1) <= k)
+    ok = keep.sum(axis=1) == k
+    out = np.zeros((draws.shape[0], k), np.int32)
+    out[ok] = draws[ok][keep[ok]].reshape(-1, k)
+    return out, ok
+
+
+def _zipf_rows(rng, n: int, d: int, k: int) -> np.ndarray:
+    """(n, k) column ids: each row k DISTINCT ids drawn Zipf-weighted.
+
+    The first k distinct ids of an i.i.d. Zipf stream are a draw of k
+    ids without replacement (successive sampling), so an over-draw with
+    replacement, deduplicated per row, gives the without-replacement
+    distribution with no per-row Python loop; the rare row with fewer
+    than k distinct ids in its over-draw is redrawn, up to
+    ``_MAX_REDRAWS`` times.  Rows still short after that (k close to d,
+    where the tail ids seldom all come up) are drawn one by one without
+    replacement."""
+    p = _zipf_probs(d)
+    cdf = np.cumsum(p)
+    idx = np.empty((n, k), np.int32)
+    over = 2 * k + 8
+    for lo in range(0, n, _CHUNK_ROWS):
+        todo = np.arange(lo, min(lo + _CHUNK_ROWS, n))
+        for _ in range(_MAX_REDRAWS):
+            if not todo.size:
+                break
+            u = rng.random((todo.size, over)) * cdf[-1]
+            draws = np.minimum(np.searchsorted(cdf, u, side="right"),
+                               d - 1).astype(np.int32)
+            rows, ok = _first_distinct(draws, k)
+            idx[todo[ok]] = rows[ok]
+            todo = todo[~ok]
+        for i in todo:
+            idx[i] = rng.choice(d, k, replace=False, p=p)
+    return idx
+
+
+def _draw_split(rng, recipe: DatasetRecipe, n: int, w_true: np.ndarray):
+    """n label-folded unit-norm rows with labels from ``w_true``."""
     d, k = recipe.d, recipe.nnz_per_row
-    dense = k >= d
-    w_true = rng.standard_normal(d).astype(np.float32)
-    w_true *= (np.abs(w_true) > 0.6)  # sparse-ish ground truth
-    if dense:
-        raw = rng.standard_normal((n, d)).astype(np.float32)
+    if k >= d:
         idx = np.tile(np.arange(d, dtype=np.int32), (n, 1))
-        val = raw
+        val = rng.standard_normal((n, d)).astype(np.float32)
     else:
-        # zipf-weighted sampling WITHOUT replacement: popularity skew and
-        # no duplicate column ids (duplicates would make ELL row norms
-        # disagree with the densified matrix).
-        probs = _zipf_probs(d)
-        idx = np.empty((n, k), dtype=np.int32)
-        for i in range(n):
-            idx[i] = rng.choice(d, size=k, replace=False, p=probs)
+        # zipf-weighted ids WITHOUT replacement within a row: popularity
+        # skew and no duplicate column ids (duplicates would make ELL row
+        # norms disagree with the densified matrix)
+        idx = _zipf_rows(rng, n, d, k)
         val = rng.standard_normal((n, k)).astype(np.float32)
     # normalize rows to unit norm (R_max = 1)
     norms = np.sqrt((val**2).sum(axis=1, keepdims=True))
     val = val / np.maximum(norms, 1e-8)
     # margins and labels
-    margins = np.zeros(n, dtype=np.float32)
-    for i in range(n):
-        margins[i] = (val[i] * w_true[idx[i]]).sum()
-    y = np.where(margins + recipe.margin * rng.standard_normal(n) > 0, 1.0, -1.0)
+    margins = (val * w_true[idx]).sum(axis=1)
+    y = np.where(margins + recipe.margin * rng.standard_normal(n) > 0,
+                 1.0, -1.0)
     flip = rng.random(n) < recipe.label_noise
     y = np.where(flip, -y, y).astype(np.float32)
     val = val * y[:, None]  # label folding: x_i = y_i * raw_i
-    return EllMatrix(jnp.asarray(idx), jnp.asarray(val), d), w_true
+    return EllMatrix(jnp.asarray(idx), jnp.asarray(val), d)
 
 
 def make_dataset(name: str, seed: int = 0,
                  recipe: Optional[DatasetRecipe] = None) -> SyntheticDataset:
     recipe = recipe or DATASET_RECIPES[name]
     rng = np.random.default_rng(seed)
-    X_train, w_true = _make_split(rng, recipe, recipe.n_train)
-    # test split shares w_true: regenerate with the same truth vector
-    rng2 = np.random.default_rng(seed + 1)
-    d, k = recipe.d, recipe.nnz_per_row
-    n = recipe.n_test
-    dense = k >= d
-    if dense:
-        idx = np.tile(np.arange(d, dtype=np.int32), (n, 1))
-        val = rng2.standard_normal((n, d)).astype(np.float32)
-    else:
-        probs = _zipf_probs(d)
-        idx = np.empty((n, k), dtype=np.int32)
-        for i in range(n):
-            idx[i] = rng2.choice(d, size=k, replace=False, p=probs)
-        val = rng2.standard_normal((n, k)).astype(np.float32)
-    norms = np.sqrt((val**2).sum(axis=1, keepdims=True))
-    val = val / np.maximum(norms, 1e-8)
-    margins = np.array([(val[i] * w_true[idx[i]]).sum() for i in range(n)])
-    y = np.where(margins + recipe.margin * rng2.standard_normal(n) > 0, 1.0, -1.0)
-    flip = rng2.random(n) < recipe.label_noise
-    y = np.where(flip, -y, y).astype(np.float32)
-    val = val * y[:, None]
-    X_test = EllMatrix(jnp.asarray(idx), jnp.asarray(val), d)
+    w_true = rng.standard_normal(recipe.d).astype(np.float32)
+    w_true *= (np.abs(w_true) > 0.6)  # sparse-ish ground truth
+    X_train = _draw_split(rng, recipe, recipe.n_train, w_true)
+    # the test split shares w_true, drawn from its own stream
+    X_test = _draw_split(np.random.default_rng(seed + 1), recipe,
+                         recipe.n_test, w_true)
     return SyntheticDataset(recipe, X_train, X_test, w_true)

@@ -6,11 +6,11 @@ primal vector under different memory models; on an SPMD mesh that
 "memory model" *is* the sharding + collective policy.  This package owns
 that policy for every layer of the repo:
 
-  ``repro.dist.mesh``      production mesh construction, data-parallel
-                           axis helpers, 1-D solver meshes
+  ``repro.dist.mesh``      mesh construction (every axis ``Auto``),
+                           data-parallel axis helpers, solver meshes
   ``repro.dist.sharding``  logical-activation rules (``ShardingRules``),
                            param / batch / cache / optimizer shardings
-  ``repro.dist.compat``    version-compat ``shard_map`` resolution
+  ``repro.dist.compat``    the repo's ``shard_map`` / ``cost_analysis``
 
 Models only *consume* a ``ShardingRules`` object; solvers only consume
 mesh helpers and ``shard_map``.  No other module constructs
@@ -19,8 +19,10 @@ mesh helpers and ``shard_map``.  No other module constructs
 
 from repro.dist.compat import shard_map
 from repro.dist.mesh import (
+    auto_mesh,
     data_axes,
     dp_size,
+    make_mesh,
     make_production_mesh,
     solver_mesh,
     solver_mesh_2d,
@@ -44,12 +46,14 @@ from repro.dist.sharding import (
 __all__ = [
     "NO_RULES",
     "ShardingRules",
+    "auto_mesh",
     "batch_pspec",
     "batch_sharding",
     "cache_shardings",
     "data_axes",
     "dp_size",
     "logits_sharding",
+    "make_mesh",
     "make_production_mesh",
     "named",
     "opt_shardings",

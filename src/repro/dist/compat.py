@@ -1,40 +1,22 @@
-"""Version-compat ``shard_map``.
+"""``shard_map`` and ``cost_analysis`` as the rest of the repo calls them.
 
-jax moved ``shard_map`` from ``jax.experimental.shard_map`` to the
-top-level ``jax`` namespace, and renamed the replication-checking kwarg
-``check_rep`` → ``check_vma`` along the way.  Every shard_map call in
-this repo goes through :func:`shard_map` below so solver code is written
-once against the new spelling and runs on both.
+Every shard_map call goes through :func:`shard_map` below, so solver
+code names one entry point; ``cost_analysis`` flattens a compiled
+program's cost report to a dict.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
 
-def _resolve():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # noqa: F811
-    params = inspect.signature(fn).parameters
-    kwarg = "check_vma" if "check_vma" in params else "check_rep"
-    return fn, kwarg
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the ``check_vma`` kwarg translated to
-    whatever this jax version calls it."""
-    fn, kwarg = _resolve()
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kwarg: check_vma})
+    """``jax.shard_map`` with keyword-only mesh/specs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict: jax<0.5 returns a
-    one-element list of per-program dicts, newer jax the dict itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict (empty when the backend
+    reports nothing)."""
+    return compiled.cost_analysis() or {}

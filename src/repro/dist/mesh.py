@@ -12,6 +12,29 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    jax ≥ 0.7 builds ``Explicit`` axes by default, and under them the
+    solver's un-padding gathers/scatters of mesh-placed arrays cannot
+    resolve an output sharding.  Every mesh in this repo is built here
+    (or normalised by ``auto_mesh``) so the compiler propagates
+    shardings the way the solver was written for."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def auto_mesh(mesh):
+    """The same devices and axis names as ``mesh``, with ``Auto`` axis
+    types — applied at the solver's mouth so a caller-built
+    ``jax.make_mesh(...)`` (Explicit by default) still works."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,7 +44,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     cross-pod (DCN-ish) collectives that the dry-run must prove shard."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def solver_mesh(axis: str = "data", n_devices: int | None = None):
@@ -30,7 +53,7 @@ def solver_mesh(axis: str = "data", n_devices: int | None = None):
     (rows / dual coordinates sharded); ``axis="model"`` is the
     feature-sharded deployment (w sharded, psum per dot product)."""
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return make_mesh((n,), (axis,))
 
 
 def solver_mesh_2d(data: int | None = None, model: int = 1,
@@ -44,7 +67,7 @@ def solver_mesh_2d(data: int | None = None, model: int = 1,
     n = n_devices or len(jax.devices())
     if data is None:
         data = max(n // model, 1)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def solver_mesh_3d(pod: int = 2, data: int | None = None, model: int = 1,
@@ -60,7 +83,7 @@ def solver_mesh_3d(pod: int = 2, data: int | None = None, model: int = 1,
     n = n_devices or len(jax.devices())
     if data is None:
         data = max(n // (pod * model), 1)
-    return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((pod, data, model), ("pod", "data", "model"))
 
 
 def solver_mesh_tasks(task: int = 2, data: int | None = None,
@@ -78,9 +101,8 @@ def solver_mesh_tasks(task: int = 2, data: int | None = None,
     if data is None:
         data = max(n // (task * model), 1)
     if model > 1:
-        return jax.make_mesh((task, data, model),
-                             ("task", "data", "model"))
-    return jax.make_mesh((task, data), ("task", "data"))
+        return make_mesh((task, data, model), ("task", "data", "model"))
+    return make_mesh((task, data), ("task", "data"))
 
 
 def task_axis_policy(n_tasks: int, *, mesh, pipeline: bool = True) -> int:

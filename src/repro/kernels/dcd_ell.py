@@ -13,30 +13,29 @@ with k̃ = k_max lane-padded to a multiple of 128, and holds ≈ 2·n_loc·k̃
 words resident instead of n_loc·d̃ — the VMEM policy is
 ``repro.dist.mesh.dcd_ell_kernel_fits``.
 
-Per update (grid step i, loop step t over the block's row ids):
+The padded primal (d₁ = d+1 lane-padded words; slot d is the *dummy
+slot*) lives in VMEM as a (d₁/128, 128) ref, so feature c sits at
+sublane row c // 128, lane c % 128.  Per update (grid step i, loop step
+t over the block's row ids):
 
-  * gather the row's k̃ (column, value) pairs from the resident shard
-    (two dynamic row slices — same addressing as the dense indexed
-    kernel's row gather);
-  * w·x_i = Σ_k w[cols_k]·vals_k — an O(k̃) lane gather + reduction
-    against the (1, d₁) primal carried in VMEM, where d₁ = d+1
-    lane-padded: slot d is the *dummy slot*, so padded lanes gather
-    w[d] = 0 (times val 0) and contribute nothing;
+  * copy the row's k̃ column ids and values into SMEM (one local DMA
+    each), so the walk below reads them as scalars;
+  * w·x_i = Σ_j w[c_j]·v_j — per nonzero, one dynamic row slice of the
+    primal and a one-hot lane mask, accumulated lane-wise and reduced
+    once; padded entries read the dummy slot (0) times value 0;
   * δ via the same ``loss.delta`` as every other engine
     (``repro.core.duals``: closed forms + logistic Newton);
-  * scatter-add w[cols] += δ·vals — duplicate padding ids all land in
-    the dummy slot and add exact zeros, so w[d] stays 0 forever.
+  * w[c_j] += δ·v_j — the same row slice and mask, written back; padding
+    ids all land in the dummy slot and add exact zeros, so w[d] stays 0.
+
+Mosaic has no lane gather or scatter into a 1-D value, which is why
+each nonzero is a row slice plus a mask rather than ``jnp.take`` /
+``.at[].add`` on the primal.
 
 α and w have constant BlockSpec index_maps and the TPU grid executes
 sequentially, so both carry across grid steps exactly like the dense
 indexed kernel: one pallas_call runs the whole sequence of blocks with
 serial-DCD semantics and zero locking.
-
-Lowering note: the lane gather/scatter (``jnp.take`` / ``.at[].add`` on
-the carried w *value*) is exact in interpret mode (CPU CI) and maps to
-Mosaic's dynamic-gather/scatter path on TPU; rows are gathered via
-``pl.ds`` dynamic slices like the dense kernel, so the only new
-primitive on the compiled path is the lane-indexed gather.
 """
 
 from __future__ import annotations
@@ -46,19 +45,64 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+
+
+def load_row(col_ref, val_ref, i, cbuf, vbuf, sem):
+    """Copy ELL row ``i`` (ids and values) into the SMEM scratch pair."""
+    cps = (pltpu.make_async_copy(col_ref.at[pl.ds(i, 1), :], cbuf, sem.at[0]),
+           pltpu.make_async_copy(val_ref.at[pl.ds(i, 1), :], vbuf, sem.at[1]))
+    for cp in cps:
+        cp.start()
+    for cp in cps:
+        cp.wait()
+
+
+def row_dot(w_ref, cbuf, vbuf):
+    """Σ_j w[c_j]·v_j over the SMEM row against a (d₁/128, 128) primal
+    ref — returns a (1, 1) f32."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def body(j, acc):
+        c = cbuf[0, j]
+        r = w_ref[pl.ds(c // LANES, 1), :]
+        return acc + jnp.where(lane == c % LANES, r, 0.0) * vbuf[0, j]
+
+    acc = jax.lax.fori_loop(0, cbuf.shape[1], body,
+                            jnp.zeros((1, LANES), jnp.float32))
+    return jnp.sum(acc, axis=1, keepdims=True)
+
+
+def row_axpy(w_ref, cbuf, vbuf, scale):
+    """w[c_j] += scale·v_j over the SMEM row; ``scale`` is (1, 1)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def body(j, carry):
+        c = cbuf[0, j]
+        r = pl.ds(c // LANES, 1)
+        w_ref[r, :] = w_ref[r, :] + jnp.where(
+            lane == c % LANES, scale * vbuf[0, j], 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, cbuf.shape[1], body, 0)
 
 
 def _dcd_ell_indexed_kernel(
-    idx_ref,  # (B, 1)  int32 local row ids for this grid step
+    idx_ref,  # (B, 1)  int32 local row ids for this grid step (SMEM)
     col_ref,  # (n, k)  whole shard's column ids, VMEM-resident
     val_ref,  # (n, k)  whole shard's values, VMEM-resident
     alpha_ref,  # (n, 1)  duals — seeds the carried output
     q_ref,  # (n, 1)  row squared norms
     act_ref,  # (n, 1)  active-set mask (f32 0/1; all-ones = no shrinking)
     y_ref,  # (n, 1)  row labels (±1; all-ones = pre-folded rows)
-    w_ref,  # (1, d1) padded primal (dummy slot at d) — seeds the carry
+    w_ref,  # (d1/128, 128) padded primal (dummy slot at d) — seeds the carry
     alpha_out,  # (n, 1)  carried across grid steps
-    w_out,  # (1, d1) carried across grid steps
+    w_out,  # (d1/128, 128) carried across grid steps
+    cbuf,  # (1, k) SMEM scratch: the current row's ids
+    vbuf,  # (1, k) SMEM scratch: the current row's values
+    sem,  # (2,) DMA semaphores
     *,
     loss,
     block_rows: int,
@@ -68,12 +112,11 @@ def _dcd_ell_indexed_kernel(
         alpha_out[...] = alpha_ref[...]
         w_out[...] = w_ref[...]
 
-    def body(t, w):  # w: (1, d1) f32 value, stays in VMEM/registers
+    def body(t, carry):
         i = idx_ref[t, 0]
-        cols = col_ref[pl.ds(i, 1), :][0]  # (k,) int32 row gather
-        vals = val_ref[pl.ds(i, 1), :].astype(jnp.float32)[0]  # (k,)
-        yi = y_ref[pl.ds(i, 1), :][0, 0]  # ±1 — folds the row on read
-        wx = yi * jnp.sum(jnp.take(w[0], cols) * vals)  # O(k) gather
+        load_row(col_ref, val_ref, i, cbuf, vbuf, sem)
+        yi = y_ref[pl.ds(i, 1), :]  # (1, 1) ±1 — folds the row on read
+        wx = yi * row_dot(w_out, cbuf, vbuf)
         a = alpha_out[pl.ds(i, 1), :]  # running α, not the seed
         q = q_ref[pl.ds(i, 1), :]
         # frozen (shrunk) coordinates take the exact zero-delta update —
@@ -82,11 +125,11 @@ def _dcd_ell_indexed_kernel(
             act_ref[pl.ds(i, 1), :] > 0.0, loss.delta(a, wx, q), 0.0
         )
         alpha_out[pl.ds(i, 1), :] = a + delta
-        # rank-1 sparse axpy; padding ids scatter δ·0 into the dummy slot
-        return w.at[0, cols].add((delta[0, 0] * yi) * vals)
+        # rank-1 sparse axpy; padding ids add δ·0 into the dummy slot
+        row_axpy(w_out, cbuf, vbuf, delta * yi)
+        return carry
 
-    w = jax.lax.fori_loop(0, block_rows, body, w_out[...].astype(jnp.float32))
-    w_out[...] = w
+    jax.lax.fori_loop(0, block_rows, body, 0)
 
 
 def dcd_ell_epoch_pallas_call(
@@ -105,8 +148,10 @@ def dcd_ell_epoch_pallas_call(
 ):
     n, k = cols.shape
     d1 = w_pad.shape[0]
+    rows = d1 // LANES
     m = idx.shape[0]
     assert m % block_rows == 0, (m, block_rows)
+    assert d1 % LANES == 0, d1
     grid = (m // block_rows,)
     idx2 = idx.reshape(m, 1).astype(jnp.int32)
     alpha2 = alpha.reshape(n, 1).astype(jnp.float32)
@@ -119,7 +164,7 @@ def dcd_ell_epoch_pallas_call(
         y2 = jnp.ones((n, 1), jnp.float32)
     else:
         y2 = y.reshape(n, 1).astype(jnp.float32)
-    w2 = w_pad.reshape(1, d1).astype(jnp.float32)
+    w2 = w_pad.reshape(rows, LANES).astype(jnp.float32)
     kernel = functools.partial(
         _dcd_ell_indexed_kernel, loss=loss, block_rows=block_rows
     )
@@ -127,22 +172,28 @@ def dcd_ell_epoch_pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),  # idx block
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),  # idx block
             pl.BlockSpec((n, k), lambda i: (0, 0)),  # cols: whole shard
             pl.BlockSpec((n, k), lambda i: (0, 0)),  # vals: whole shard
             pl.BlockSpec((n, 1), lambda i: (0, 0)),  # alpha seed
             pl.BlockSpec((n, 1), lambda i: (0, 0)),  # sq norms
             pl.BlockSpec((n, 1), lambda i: (0, 0)),  # active mask
             pl.BlockSpec((n, 1), lambda i: (0, 0)),  # row labels
-            pl.BlockSpec((1, d1), lambda i: (0, 0)),  # w seed
+            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),  # w seed
         ],
         out_specs=[
             pl.BlockSpec((n, 1), lambda i: (0, 0)),  # carried α
-            pl.BlockSpec((1, d1), lambda i: (0, 0)),  # carried w
+            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),  # carried w
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, d1), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.SMEM((1, k), jnp.int32),
+            pltpu.SMEM((1, k), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
     )(idx2, cols, vals, alpha2, q2, act2, y2, w2)

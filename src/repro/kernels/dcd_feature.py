@@ -16,25 +16,27 @@ over feature shards.  That turns B per-update psums of scalars into ONE
 psum of (B + B²) floats per block, bracketed by two VMEM-resident
 kernels:
 
-  * ``_gram_kernel`` — gathers the block's rows from the resident
-    (cols, vals) slice and computes the *partial* base (B,) and Gram
-    (B, B) for this shard: per row t it scatter-adds x_t into a
-    d₁_loc-word scratch carried as a loop value, takes the O(B·k̃_loc)
-    gather-dot column G[:, t], then subtracts x_t back out (exact in
-    IEEE: v + (−v) = 0 from a zero start), so the scratch never holds
-    more than one row;
+  * ``_gram_kernel`` — stages the block's rows from the resident
+    (cols, vals) slice as a (B, k̃_loc) tile and computes the *partial*
+    base (B,) and Gram (B, B) for this shard: per row t it walks x_t's
+    nonzeros as SMEM scalars (``repro.kernels.dcd_ell``'s lowering) and
+    matches each id against the whole tile, so G[:, t] = x_sᵀx_t for
+    every s comes out of one lane reduction; base_t is the same row
+    walk against the primal shard;
   * caller psums (base, G) over ``model`` — the only collective;
   * ``_update_kernel`` — runs the B-step δ recursion with the same
     ``loss.delta`` family as every other engine (``repro.core.duals``),
     carrying the running α and a δ-history vector: wx_t = base_t +
-    δ·G[:, t] (future slots are still 0), then scatter-adds δ_t·vals
-    into this shard's primal only.  Repeated row ids (a padding-heavy
-    device cycling its valid prefix) are exact: G[s, t] = ‖x‖² feeds the
-    earlier δ back in, and α is read from the carried output.
+    δ·G[:, t] (future slots are still 0), then adds δ_t·vals into this
+    shard's primal only, nonzero by nonzero.  Repeated row ids (a
+    padding-heavy device cycling its valid prefix) are exact: G[s, t] =
+    ‖x‖² feeds the earlier δ back in, and α is read from the carried
+    output.
 
 Both kernels keep the dummy-slot contract of ``repro.kernels.dcd_ell``:
-local padding ids equal d_loc, whose slot in the shard / scratch is
-pinned to 0 by construction.  In exact arithmetic the two-kernel block
+local padding ids equal d_loc, whose slot in the shard is pinned to 0
+by construction (padding ids also match each other in the Gram walk,
+but carry value 0).  In exact arithmetic the two-kernel block
 is identical to the per-update-psum jnp engine
 (``repro.core.sharded._local_block_update_feature``); tests assert
 agreement to atol 1e-5.
@@ -55,86 +57,94 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.dcd_ell import LANES, load_row, row_axpy, row_dot
 
 
 def _gram_kernel(
-    idx_ref,  # (B, 1)  int32 local row ids of this block
+    idx_ref,  # (B, 1)  int32 local row ids of this block (SMEM)
     col_ref,  # (n, k)  shard's local column ids, VMEM-resident
     val_ref,  # (n, k)  shard's values, VMEM-resident
-    w_ref,  # (1, d1) this shard's padded primal slice
+    w_ref,  # (d1/128, 128) this shard's padded primal slice
     base_out,  # (B, 1)  partial w₀ᵀx_t
     gram_out,  # (B, B)  partial Gram x_s·x_t
+    cb,  # (B, k) VMEM scratch: the block's column ids
+    vb,  # (B, k) VMEM scratch: the block's values
+    cbuf,  # (1, k) SMEM scratch: the current row's ids
+    vbuf,  # (1, k) SMEM scratch: the current row's values
+    sem,  # (2,) DMA semaphores
     *,
     block_rows: int,
 ):
-    # gather the block's rows once: (B, k) ids + values as loop values
+    # gather the block's rows once into (B, k) VMEM tiles
     def gather(t, carry):
-        cb, vb = carry
         i = idx_ref[t, 0]
-        cb = cb.at[t].set(col_ref[pl.ds(i, 1), :][0])
-        vb = vb.at[t].set(val_ref[pl.ds(i, 1), :].astype(jnp.float32)[0])
-        return cb, vb
+        cb[pl.ds(t, 1), :] = col_ref[pl.ds(i, 1), :]
+        vb[pl.ds(t, 1), :] = val_ref[pl.ds(i, 1), :].astype(jnp.float32)
+        return carry
 
-    k = col_ref.shape[1]
-    cb, vb = jax.lax.fori_loop(
-        0, block_rows, gather,
-        (jnp.zeros((block_rows, k), jnp.int32),
-         jnp.zeros((block_rows, k), jnp.float32)),
-    )
-    w = w_ref[...].astype(jnp.float32)[0]
-    base_out[...] = jnp.sum(jnp.take(w, cb) * vb, axis=1).reshape(
-        block_rows, 1
-    )
+    jax.lax.fori_loop(0, block_rows, gather, 0)
+    cbv, vbv = cb[...], vb[...]
+    col_t = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 1)
 
-    def gcol(t, carry):
-        scratch, gram = carry
-        ct, vt = cb[t], vb[t]
-        scratch = scratch.at[ct].add(vt)  # padding ids land in slot d_loc
-        col = jnp.sum(jnp.take(scratch, cb) * vb, axis=1)  # x_s·x_t ∀s
-        gram = gram.at[:, t].set(col)
-        return scratch.at[ct].add(-vt), gram  # exact restore to zeros
+    def gcol(t, gram):
+        load_row(cb, vb, t, cbuf, vbuf, sem)
+        base_out[pl.ds(t, 1), :] = row_dot(w_ref, cbuf, vbuf)
 
-    d1 = w_ref.shape[1]
-    _, gram = jax.lax.fori_loop(
+        # x_s·x_t for every s at once: walk x_t's nonzeros and match
+        # them against the whole block tile (padding ids d_loc match
+        # each other, but carry value 0)
+        def match(j, acc):
+            return acc + jnp.where(cbv == cbuf[0, j], vbv, 0.0) * vbuf[0, j]
+
+        acc = jax.lax.fori_loop(0, cbuf.shape[1], match,
+                                jnp.zeros(cbv.shape, jnp.float32))
+        col = jnp.sum(acc, axis=1, keepdims=True)  # (B, 1)
+        return jnp.where(col_t == t, col, gram)
+
+    gram_out[...] = jax.lax.fori_loop(
         0, block_rows, gcol,
-        (jnp.zeros((d1,), jnp.float32),
-         jnp.zeros((block_rows, block_rows), jnp.float32)),
-    )
-    gram_out[...] = gram
+        jnp.zeros((block_rows, block_rows), jnp.float32))
 
 
 def _update_kernel(
-    idx_ref,  # (B, 1)  int32 local row ids
+    idx_ref,  # (B, 1)  int32 local row ids (SMEM)
     col_ref,  # (n, k)  shard's local column ids, VMEM-resident
     val_ref,  # (n, k)  shard's values, VMEM-resident
     alpha_ref,  # (n, 1)  duals — seeds the output
     q_ref,  # (n, 1)  FULL row squared norms (summed over shards)
     act_ref,  # (n, 1)  active-set mask (f32 0/1; all-ones = no shrinking)
     y_ref,  # (n, 1)  row labels (±1; all-ones = pre-folded rows)
-    w_ref,  # (1, d1) this shard's padded primal slice — seeds the output
+    w_ref,  # (d1/128, 128) this shard's padded primal slice — seeds w_out
     base_ref,  # (B, 1)  psummed w₀ᵀx_t (UNfolded — y applied below)
     gram_ref,  # (B, B)  psummed Gram (unfolded x_s·x_t)
     alpha_out,  # (n, 1)
-    w_out,  # (1, d1)
+    w_out,  # (d1/128, 128)
+    cbuf,  # (1, k) SMEM scratch: the current row's ids
+    vbuf,  # (1, k) SMEM scratch: the current row's values
+    sem,  # (2,) DMA semaphores
     *,
     loss,
     block_rows: int,
 ):
     alpha_out[...] = alpha_ref[...]
-    base = base_ref[...]
+    w_out[...] = w_ref[...]
     gram = gram_ref[...]
+    col_t = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_rows), 1)
+    row_t = jax.lax.broadcasted_iota(jnp.int32, (block_rows, 1), 0)
 
-    def body(t, carry):
+    def body(t, deltas):
         # deltas is the FOLDED δ̃_s = δ_s·y_s history (0 ahead): with
         # x̃ = y·x, wᵀx̃_t = y_t·(w₀ᵀx_t + Σ_{s<t} δ_s y_s · x_sᵀx_t),
         # so base and Gram stay unfolded and y enters only here
-        w, deltas = carry  # w: (1, d1), deltas: (B,) δ̃ history
         i = idx_ref[t, 0]
-        cols = col_ref[pl.ds(i, 1), :][0]
-        vals = val_ref[pl.ds(i, 1), :].astype(jnp.float32)[0]
-        yi = y_ref[pl.ds(i, 1), :][0, 0]
-        gcol = jax.lax.dynamic_slice_in_dim(gram, t, 1, axis=1)[:, 0]
-        wx = yi * (base[t, 0] + jnp.sum(deltas * gcol))
+        load_row(col_ref, val_ref, i, cbuf, vbuf, sem)
+        yi = y_ref[pl.ds(i, 1), :]
+        # Σ_s δ̃_s·G[s, t]: column t of the Gram, selected by lane mask
+        hist = jnp.sum(jnp.where(col_t == t, deltas * gram, 0.0),
+                       keepdims=True)
+        wx = yi * (base_ref[pl.ds(t, 1), :] + hist)
         a = alpha_out[pl.ds(i, 1), :]  # running α, not the seed
         q = q_ref[pl.ds(i, 1), :]
         # frozen (shrunk) coordinates take the exact zero-delta update;
@@ -144,39 +154,40 @@ def _update_kernel(
             act_ref[pl.ds(i, 1), :] > 0.0, loss.delta(a, wx, q), 0.0
         )
         alpha_out[pl.ds(i, 1), :] = a + delta
-        dtil = delta[0, 0] * yi
-        w = w.at[0, cols].add(dtil * vals)
-        return w, deltas.at[t].set(dtil)
+        dtil = delta * yi
+        row_axpy(w_out, cbuf, vbuf, dtil)
+        return jnp.where(row_t == t, dtil, deltas)
 
-    w, _ = jax.lax.fori_loop(
-        0, block_rows, body,
-        (w_ref[...].astype(jnp.float32),
-         jnp.zeros((block_rows,), jnp.float32)),
-    )
-    w_out[...] = w
+    jax.lax.fori_loop(0, block_rows, body,
+                      jnp.zeros((block_rows, 1), jnp.float32))
+
+
+def _row_scratch(k):
+    return [pltpu.SMEM((1, k), jnp.int32), pltpu.SMEM((1, k), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,))]
 
 
 def dcd_feature_gram_pallas_call(
     cols,  # (n, k) int32 local ids, padding == d_loc
     vals,  # (n, k) f32, padding == 0
-    w_loc,  # (d1,) this shard's padded primal slice
+    w_loc,  # (d1,) this shard's padded primal slice, d1 % 128 == 0
     idx,  # (B,) int32 row ids of the block
     *,
     interpret: bool = False,
 ):
     """Partial (base, Gram) of one block against this feature shard."""
     n, k = cols.shape
-    d1 = w_loc.shape[0]
+    rows = w_loc.shape[0] // LANES
     b = idx.shape[0]
     kernel = functools.partial(_gram_kernel, block_rows=b)
     base, gram = pl.pallas_call(
         kernel,
         grid=(1,),
         in_specs=[
-            pl.BlockSpec((b, 1), lambda i: (0, 0)),
+            pl.BlockSpec((b, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((n, k), lambda i: (0, 0)),
             pl.BlockSpec((n, k), lambda i: (0, 0)),
-            pl.BlockSpec((1, d1), lambda i: (0, 0)),
+            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((b, 1), lambda i: (0, 0)),
@@ -186,9 +197,11 @@ def dcd_feature_gram_pallas_call(
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, b), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((b, k), jnp.int32),
+                        pltpu.VMEM((b, k), jnp.float32)] + _row_scratch(k),
         interpret=interpret,
     )(idx.reshape(b, 1).astype(jnp.int32), cols, vals,
-      w_loc.reshape(1, d1).astype(jnp.float32))
+      w_loc.reshape(rows, LANES).astype(jnp.float32))
     return base.reshape(b), gram
 
 
@@ -197,7 +210,7 @@ def dcd_feature_update_pallas_call(
     vals,  # (n, k) f32
     alpha,  # (n,)
     sq_norms,  # (n,) FULL row norms
-    w_loc,  # (d1,) this shard's padded primal slice
+    w_loc,  # (d1,) this shard's padded primal slice, d1 % 128 == 0
     idx,  # (B,)
     base,  # (B,)  psummed
     gram,  # (B, B) psummed
@@ -210,6 +223,7 @@ def dcd_feature_update_pallas_call(
     """B sequential δ-recursion updates; scatters only this shard."""
     n, k = cols.shape
     d1 = w_loc.shape[0]
+    rows = d1 // LANES
     b = idx.shape[0]
     if active is None:
         act2 = jnp.ones((n, 1), jnp.float32)
@@ -224,29 +238,30 @@ def dcd_feature_update_pallas_call(
         kernel,
         grid=(1,),
         in_specs=[
-            pl.BlockSpec((b, 1), lambda i: (0, 0)),
+            pl.BlockSpec((b, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((n, k), lambda i: (0, 0)),
             pl.BlockSpec((n, k), lambda i: (0, 0)),
             pl.BlockSpec((n, 1), lambda i: (0, 0)),
             pl.BlockSpec((n, 1), lambda i: (0, 0)),
             pl.BlockSpec((n, 1), lambda i: (0, 0)),
             pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, d1), lambda i: (0, 0)),
+            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),
             pl.BlockSpec((b, 1), lambda i: (0, 0)),
             pl.BlockSpec((b, b), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, d1), lambda i: (0, 0)),
+            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, d1), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
+        scratch_shapes=_row_scratch(k),
         interpret=interpret,
     )(idx.reshape(b, 1).astype(jnp.int32), cols, vals,
       alpha.reshape(n, 1).astype(jnp.float32),
       sq_norms.reshape(n, 1).astype(jnp.float32), act2, y2,
-      w_loc.reshape(1, d1).astype(jnp.float32),
+      w_loc.reshape(rows, LANES).astype(jnp.float32),
       base.reshape(b, 1).astype(jnp.float32), gram)
     return alpha_out.reshape(n), w_out.reshape(d1)

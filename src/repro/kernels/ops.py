@@ -1,6 +1,7 @@
-"""Jitted wrappers for the Pallas DCD kernel with shape canonicalization
-and a CPU ``interpret=True`` fallback (this container is CPU-only; TPU is
-the compile target)."""
+"""Jitted wrappers for the Pallas DCD kernels with shape canonicalization.
+
+On a TPU the kernels compile; elsewhere they run in Pallas
+``interpret=True`` mode, which checks semantics, not speed."""
 
 from __future__ import annotations
 

@@ -4,6 +4,9 @@ import sys
 # tests must see exactly ONE device (the dry-run alone uses 512);
 # keep any user XLA_FLAGS out of the test environment.
 os.environ.pop("XLA_FLAGS", None)
+# the suite is CPU-only: on a host with a chip, the default backend would
+# be the TPU, and the multi-device subprocess tests would contend for it
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

@@ -4,7 +4,7 @@ meshes, the launch-layer re-export shim, and shard_map compat."""
 import jax
 import numpy as np
 
-from repro.dist.compat import _resolve, shard_map
+from repro.dist.compat import shard_map
 from repro.dist.mesh import data_axes, dp_size, solver_mesh
 
 
@@ -47,9 +47,6 @@ def test_launch_mesh_shim_reexports():
 
 
 def test_shard_map_compat_resolves():
-    fn, kwarg = _resolve()
-    assert callable(fn)
-    assert kwarg in ("check_vma", "check_rep")
     # end-to-end: a psum over a 1-device mesh round-trips
     mesh = solver_mesh("data")
     from jax.sharding import PartitionSpec as P
@@ -150,3 +147,31 @@ def test_drift_trip_thresholds():
     assert int(drift_trip(jnp.float32(0.0), jnp.float32(0.06))) == 1
     assert int(drift_trip(jnp.float32(0.0), jnp.float32(0.5),
                           ratio=2.0, floor=0.6)) == 0
+
+
+def test_solver_meshes_are_auto():
+    """Every mesh ``repro.dist`` builds has Auto axes — under Explicit
+    axes (jax's default) the solver's un-padding gathers cannot resolve
+    an output sharding."""
+    from jax.sharding import AxisType
+
+    from repro.dist.mesh import solver_mesh_2d, solver_mesh_3d
+
+    for mesh in (solver_mesh("data"), solver_mesh_2d(),
+                 solver_mesh_3d(pod=1, n_devices=1)):
+        assert all(t == AxisType.Auto for t in mesh.axis_types), mesh
+
+
+def test_auto_mesh_normalises_a_caller_mesh():
+    from jax.sharding import AxisType
+
+    from repro.dist.mesh import auto_mesh, make_mesh
+
+    explicit = jax.make_mesh((1, 1), ("pod", "data"),
+                             axis_types=(AxisType.Explicit,) * 2)
+    mesh = auto_mesh(explicit)
+    assert mesh.axis_names == ("pod", "data")
+    assert list(mesh.devices.flat) == list(explicit.devices.flat)
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
+    auto = make_mesh((1,), ("data",))
+    assert auto_mesh(auto) is auto

@@ -275,10 +275,14 @@ _SUBPROCESS = textwrap.dedent("""
     g_rr = np.asarray(rr.gaps)[1:-1]
     # the gap falls, then RISES again once repack engages — real
     # divergence, recovered only by the final unshrunk pass
-    assert g_rr.max() > 2 * g_rr.min(), g_rr
-    assert np.argmax(g_rr) > np.argmin(g_rr), g_rr
+    post_min = g_rr[np.argmin(g_rr):]
+    assert post_min.max() > post_min[0], g_rr
     ra = sharded_passcode_solve(ell, loss, shrink_every=1, repack=True,
                                 adaptive=True, **kw)
+    # the sticky repack guard ends the adaptive run below the peak the
+    # repack-only run climbs to after its minimum
+    assert float(ra.gaps[-1]) < post_min.max(), (
+        float(ra.gaps[-1]), g_rr)
     dtr = np.asarray(ra.delay)
     # seeded synchronous: the one-way latch never raises asynchrony,
     # so the intervention here is the sticky repack guard (rpok)
