@@ -136,6 +136,14 @@ from repro.kernels.ops import (
     dcd_feature_update_pallas,
 )
 
+# The solver's layers inside the compiled epoch, as ``jax.named_scope``s:
+# each reaches the op_name of every instruction it holds, so a device
+# trace can be reduced per layer (``_epoch_scan``).
+SCOPE_PERM = "passcode.perm"
+SCOPE_UPDATE = "passcode.update"
+SCOPE_MERGE = "passcode.merge"
+SCOPE_GAP = "passcode.gap"
+
 
 class ShardedResult(NamedTuple):
     alpha: jnp.ndarray
@@ -178,7 +186,8 @@ def _local_block_update(X_loc, sq_loc, alpha_loc, w, idx_block, loss,
     alpha_loc, w_new = jax.lax.fori_loop(
         0, idx_block.shape[0], body, (alpha_loc, w)
     )
-    return alpha_loc, w_new - w  # (updated α shard, local Δw)
+    with jax.named_scope(SCOPE_MERGE):
+        return alpha_loc, w_new - w  # (updated α shard, local Δw)
 
 
 def _local_block_update_ell(cols_loc, vals_loc, sq_loc, alpha_loc, w_pad,
@@ -207,7 +216,8 @@ def _local_block_update_ell(cols_loc, vals_loc, sq_loc, alpha_loc, w_pad,
     alpha_loc, w_new = jax.lax.fori_loop(
         0, idx_block.shape[0], body, (alpha_loc, w_pad)
     )
-    return alpha_loc, w_new - w_pad  # (updated α shard, local Δw_pad)
+    with jax.named_scope(SCOPE_MERGE):
+        return alpha_loc, w_new - w_pad  # (updated α shard, local Δw_pad)
 
 
 def _local_block_update_feature(cols_loc, vals_loc, sq_loc, alpha_loc,
@@ -243,7 +253,8 @@ def _local_block_update_feature(cols_loc, vals_loc, sq_loc, alpha_loc,
     alpha_loc, w_new = jax.lax.fori_loop(
         0, idx_block.shape[0], body, (alpha_loc, w_loc)
     )
-    return alpha_loc, w_new - w_loc  # (updated α shard, local Δw shard)
+    with jax.named_scope(SCOPE_MERGE):
+        return alpha_loc, w_new - w_loc  # (updated α shard, local Δw shard)
 
 
 def _resolve_kernel_mode(use_kernel, n_loc: int, d: int,
@@ -408,15 +419,19 @@ def _scan_rounds(block_update, alpha_loc, w_loc, dw_prev, blocks_loc,
         alpha_loc, w_loc, dw_prev = carry
         if delay_rounds > 0:
             # fold in last round's aggregate only now (stale view)
-            w_eff = w_loc + dw_prev
+            with jax.named_scope(SCOPE_MERGE):
+                w_eff = w_loc + dw_prev
         else:
             w_eff = w_loc
-        alpha_loc, dw_local = block_update(alpha_loc, w_eff, idx_block)
-        dw_all = jax.lax.psum(dw_local, "data")
-        if delay_rounds > 0:
-            # defer applying this round's aggregate to next round
-            return (alpha_loc, w_loc + dw_prev, dw_all), ()
-        return (alpha_loc, w_loc + dw_all, dw_prev), ()
+        with jax.named_scope(SCOPE_UPDATE):
+            alpha_loc, dw_local = block_update(alpha_loc, w_eff,
+                                               idx_block)
+        with jax.named_scope(SCOPE_MERGE):
+            dw_all = jax.lax.psum(dw_local, "data")
+            if delay_rounds > 0:
+                # defer applying this round's aggregate to next round
+                return (alpha_loc, w_loc + dw_prev, dw_all), ()
+            return (alpha_loc, w_loc + dw_all, dw_prev), ()
 
     (alpha_loc, w_loc, dw_prev), _ = jax.lax.scan(
         one_round, (alpha_loc, w_loc, dw_prev), blocks_loc
@@ -455,17 +470,21 @@ def _scan_rounds_dyn(block_update, alpha_loc, w_loc, dw_prev, dw_own,
             alpha_loc, w_loc, dw_prev, dw_own = c
             # delayed: peers' last-round aggregate is still in flight —
             # read own last-round updates only (stale by one psum)
-            w_eff = w_loc + jnp.where(delay_on, dw_own, dw_prev)
-            alpha_n, dw_local = block_update(alpha_loc, w_eff, idx_block,
-                                             act)
-            dw_all = jax.lax.psum(dw_local, "data")
-            # last round's aggregate lands now; this round's is applied
-            # eagerly (sync) or kept in flight (delayed)
-            w_new = w_loc + dw_prev + jnp.where(
-                delay_on, jnp.zeros_like(dw_all), dw_all)
-            dw_new = jnp.where(delay_on, dw_all, jnp.zeros_like(dw_all))
-            dwo_new = jnp.where(delay_on, dw_local,
-                                jnp.zeros_like(dw_local))
+            with jax.named_scope(SCOPE_MERGE):
+                w_eff = w_loc + jnp.where(delay_on, dw_own, dw_prev)
+            with jax.named_scope(SCOPE_UPDATE):
+                alpha_n, dw_local = block_update(alpha_loc, w_eff,
+                                                 idx_block, act)
+            with jax.named_scope(SCOPE_MERGE):
+                dw_all = jax.lax.psum(dw_local, "data")
+                # last round's aggregate lands now; this round's is
+                # applied eagerly (sync) or kept in flight (delayed)
+                w_new = w_loc + dw_prev + jnp.where(
+                    delay_on, jnp.zeros_like(dw_all), dw_all)
+                dw_new = jnp.where(delay_on, dw_all,
+                                   jnp.zeros_like(dw_all))
+                dwo_new = jnp.where(delay_on, dw_local,
+                                    jnp.zeros_like(dw_local))
             return alpha_n, w_new, dw_new, dwo_new
 
         carry = jax.lax.cond(r < n_run, run, lambda c: c, carry)
@@ -545,16 +564,20 @@ def _scan_rounds_overlap(gram_fn, corr_fn, update_fn, alpha_loc, w_loc,
     def one_round(carry, blk):
         idx, idx_next = blk
         alpha_loc, w_loc, dw_prev, (base0, gram) = carry
-        w_next = w_loc + dw_prev  # W_{t+1}: known before D_{t+1} lands
-        # issue block t+1's gram/base⁰ + model psum — independent of the
-        # in-flight (base⁰_t, gram_t) psum and of this round's data psum,
-        # so both collectives can hide behind it
-        inflight_n = gram_fn(w_next, idx_next)
-        # repair block t's stale base, consuming the in-flight aggregate
-        base = base0 + corr_fn(dw_prev, idx)
-        alpha_loc, w_upd = update_fn(alpha_loc, w_next, idx, base, gram,
-                                     act)
-        dw_all = jax.lax.psum(w_upd - w_next, "data")
+        with jax.named_scope(SCOPE_MERGE):
+            w_next = w_loc + dw_prev  # W_{t+1}: known before D_{t+1} lands
+        with jax.named_scope(SCOPE_UPDATE):
+            # issue block t+1's gram/base⁰ + model psum — independent of
+            # the in-flight (base⁰_t, gram_t) psum and of this round's
+            # data psum, so both collectives can hide behind it
+            inflight_n = gram_fn(w_next, idx_next)
+            # repair block t's stale base, consuming the in-flight
+            # aggregate
+            base = base0 + corr_fn(dw_prev, idx)
+            alpha_loc, w_upd = update_fn(alpha_loc, w_next, idx, base,
+                                         gram, act)
+        with jax.named_scope(SCOPE_MERGE):
+            dw_all = jax.lax.psum(w_upd - w_next, "data")
         return (alpha_loc, w_next, dw_all, inflight_n), ()
 
     (alpha_loc, w_loc, dw_prev, inflight), _ = jax.lax.scan(
@@ -894,7 +917,10 @@ def _epoch_scan(rounds, gap, carry, draw_perm, *, epochs: int,
     gap (plus the live backward-error, active-fraction and delay-flag
     metrics) into preallocated buffers.  Shared by the 1-D and 2-D
     builders so the PRNG chain and the metric schedule cannot diverge
-    between them.
+    between them.  Its layers carry the operator-facing names
+    ``passcode.perm`` (the draw), ``passcode.update`` (the block engine),
+    ``passcode.merge`` (the round's Δw, psum and fold) and
+    ``passcode.gap`` (the gap and its records) as ``jax.named_scope``s.
 
     The self-tuning extensions (DESIGN.md §12) are all optional and
     compile away when unused:
@@ -981,7 +1007,8 @@ def _epoch_scan(rounds, gap, carry, draw_perm, *, epochs: int,
 
     def epoch_body(carry, e):
         c = dict(carry)
-        key, sub = jax.random.split(c["key"])
+        with jax.named_scope(SCOPE_PERM):
+            key, sub = jax.random.split(c["key"])
         c["key"] = key
         final = e == total_epochs - 1
         if shrink_on:
@@ -1019,11 +1046,13 @@ def _epoch_scan(rounds, gap, carry, draw_perm, *, epochs: int,
                 use_rp = use_rp & (c["rpok"] > 0)
             act_draw = jnp.where(use_rp, c["act"], valid)
             n_run_e = jnp.where(use_rp, c["nrun"], jnp.int32(n_blocks))
-            blocks_loc = draw_perm(sub, act_draw, use_rp)
+            with jax.named_scope(SCOPE_PERM):
+                blocks_loc = draw_perm(sub, act_draw, use_rp)
         else:
             act_run = None
             n_run_e = jnp.int32(n_blocks)
-            blocks_loc = draw_perm(sub)
+            with jax.named_scope(SCOPE_PERM):
+                blocks_loc = draw_perm(sub)
         delay_flag = c["delay"] if adaptive else jnp.int32(delay0)
         if pod_on:
             a0, w0 = c["alpha"], c["w"]
@@ -1065,9 +1094,10 @@ def _epoch_scan(rounds, gap, carry, draw_perm, *, epochs: int,
         elif overlap:
             # peek the next epoch's first block: the next iteration
             # splits the carried key exactly like this
-            _, sub_next = jax.random.split(key)
-            next0 = (draw_perm(sub_next, valid, False) if shrink_on
-                     else draw_perm(sub_next))[0]
+            with jax.named_scope(SCOPE_PERM):
+                _, sub_next = jax.random.split(key)
+                next0 = (draw_perm(sub_next, valid, False) if shrink_on
+                         else draw_perm(sub_next))[0]
             (c["alpha"], c["w"], c["dw"], c["inflight"]) = rounds(
                 c["alpha"], c["w"], c["dw"], blocks_loc, c["inflight"],
                 next0, act_run)
@@ -1084,63 +1114,64 @@ def _epoch_scan(rounds, gap, carry, draw_perm, *, epochs: int,
             c["w"] = c["w"] + jnp.where(e == nan_e, jnp.float32(jnp.nan),
                                         jnp.float32(0.0))
         if record:
-            rec = ((e + 1) % gap_every == 0) | final
-            w_view = c["w"] + c["dw"]
-            g, eps = gap(rec, c["alpha"], w_view)
-            slot = c["slot"]
-            c["gaps"] = jnp.where(rec, c["gaps"].at[slot].set(g),
-                                  c["gaps"])
-            c["epsb"] = jnp.where(rec, c["epsb"].at[slot].set(eps),
-                                  c["epsb"])
-            fr = c["frac"] if shrink_on else jnp.float32(1.0)
-            c["actb"] = jnp.where(rec, c["actb"].at[slot].set(fr),
-                                  c["actb"])
-            c["delayb"] = jnp.where(
-                rec,
-                c["delayb"].at[slot].set(delay_flag.astype(jnp.float32)),
-                c["delayb"])
-            if adaptive:
-                # gap-trend controller: improving ⇒ stay async,
-                # stalling ⇒ go synchronous (both vs the last record)
-                new_flag = adaptive_delay_policy(
-                    c["gapprev"], g, improve_ratio=adaptive_ratio)
-                # one-way latch: the controller only ever *backs off*
-                # asynchrony (seed with delay_rounds=1 to start async).
-                # Re-raising oscillates — a synchronous epoch converges
-                # fast, which reads as "async affordable", whose stale
-                # epoch converges slowly, which reads as "back off" —
-                # and each flip re-pays the staleness tax exactly where
-                # it is most expensive (near the optimum)
-                c["delay"] = jnp.where(
-                    rec, jnp.minimum(delay_flag, new_flag), delay_flag)
-                if shrink_on:
-                    # the repack guard keys on a *hard* stall (the 0.95
-                    # default), not the annealing threshold: a gap that
-                    # merely stops halving is normal near the optimum,
-                    # while a gap that stops moving under repack is the
-                    # τ-concentration signature the guard exists for
-                    stall = adaptive_delay_policy(c["gapprev"], g)
-                    c["rpok"] = jnp.where(rec, c["rpok"] * stall,
-                                          c["rpok"])
-                c["gapprev"] = jnp.where(rec, g, c["gapprev"])
-            if watchdog is not None:
-                # on-device divergence watchdog (DESIGN.md §14): a
-                # NaN/Inf census of (α, ŵ) plus the gap/eps trend test,
-                # folded into a sticky per-segment health code.  The
-                # healthy-baseline pair only advances on clean records,
-                # so a blow-up is judged against the last good state.
-                bad_fn, wd_blowup, wd_floor = watchdog
-                nb = jax.lax.cond(
-                    rec, lambda a: bad_fn(*a),
-                    lambda a: jnp.int32(0), (c["alpha"], w_view))
-                code = watchdog_trip(c["gph"], g, c["eph"], eps, nb,
-                                     blowup=wd_blowup, floor=wd_floor)
-                ok = rec & (code == 0)
-                c["health"] = jnp.where(
-                    rec, jnp.maximum(c["health"], code), c["health"])
-                c["gph"] = jnp.where(ok, g, c["gph"])
-                c["eph"] = jnp.where(ok, eps, c["eph"])
-            c["slot"] = slot + rec.astype(jnp.int32)
+            with jax.named_scope(SCOPE_GAP):
+                rec = ((e + 1) % gap_every == 0) | final
+                w_view = c["w"] + c["dw"]
+                g, eps = gap(rec, c["alpha"], w_view)
+                slot = c["slot"]
+                c["gaps"] = jnp.where(rec, c["gaps"].at[slot].set(g),
+                                      c["gaps"])
+                c["epsb"] = jnp.where(rec, c["epsb"].at[slot].set(eps),
+                                      c["epsb"])
+                fr = c["frac"] if shrink_on else jnp.float32(1.0)
+                c["actb"] = jnp.where(rec, c["actb"].at[slot].set(fr),
+                                      c["actb"])
+                c["delayb"] = jnp.where(
+                    rec,
+                    c["delayb"].at[slot].set(delay_flag.astype(jnp.float32)),
+                    c["delayb"])
+                if adaptive:
+                    # gap-trend controller: improving ⇒ stay async,
+                    # stalling ⇒ go synchronous (both vs the last record)
+                    new_flag = adaptive_delay_policy(
+                        c["gapprev"], g, improve_ratio=adaptive_ratio)
+                    # one-way latch: the controller only ever *backs off*
+                    # asynchrony (seed with delay_rounds=1 to start async).
+                    # Re-raising oscillates — a synchronous epoch converges
+                    # fast, which reads as "async affordable", whose stale
+                    # epoch converges slowly, which reads as "back off" —
+                    # and each flip re-pays the staleness tax exactly where
+                    # it is most expensive (near the optimum)
+                    c["delay"] = jnp.where(
+                        rec, jnp.minimum(delay_flag, new_flag), delay_flag)
+                    if shrink_on:
+                        # the repack guard keys on a *hard* stall (the 0.95
+                        # default), not the annealing threshold: a gap that
+                        # merely stops halving is normal near the optimum,
+                        # while a gap that stops moving under repack is the
+                        # τ-concentration signature the guard exists for
+                        stall = adaptive_delay_policy(c["gapprev"], g)
+                        c["rpok"] = jnp.where(rec, c["rpok"] * stall,
+                                              c["rpok"])
+                    c["gapprev"] = jnp.where(rec, g, c["gapprev"])
+                if watchdog is not None:
+                    # on-device divergence watchdog (DESIGN.md §14): a
+                    # NaN/Inf census of (α, ŵ) plus the gap/eps trend test,
+                    # folded into a sticky per-segment health code.  The
+                    # healthy-baseline pair only advances on clean records,
+                    # so a blow-up is judged against the last good state.
+                    bad_fn, wd_blowup, wd_floor = watchdog
+                    nb = jax.lax.cond(
+                        rec, lambda a: bad_fn(*a),
+                        lambda a: jnp.int32(0), (c["alpha"], w_view))
+                    code = watchdog_trip(c["gph"], g, c["eph"], eps, nb,
+                                         blowup=wd_blowup, floor=wd_floor)
+                    ok = rec & (code == 0)
+                    c["health"] = jnp.where(
+                        rec, jnp.maximum(c["health"], code), c["health"])
+                    c["gph"] = jnp.where(ok, g, c["gph"])
+                    c["eph"] = jnp.where(ok, eps, c["eph"])
+                c["slot"] = slot + rec.astype(jnp.int32)
         return c, ()
 
     out, _ = jax.lax.scan(epoch_body, carry,
@@ -1864,6 +1895,7 @@ def _pad_host(a, shape, fill, dtype, rowmap=None):
     return out if rowmap is None else out[rowmap]
 
 
+@functools.partial(jax.profiler.annotate_function, name="passcode.prepare")
 def prepare_solver(
     X_host,
     loss,
@@ -2183,6 +2215,8 @@ def build_pipeline(setup: SolverSetup, *, epochs: int,
                                  **common)
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name="passcode.init_state")
 def init_pipeline_state(setup: SolverSetup, *, total_epochs: int,
                         watchdog: bool = False, alpha0=None, w0=None,
                         delay_rounds: int | None = None,
@@ -2270,6 +2304,7 @@ def device_put_state(setup: SolverSetup, state: dict) -> dict:
     return {k: place(k, v) for k, v in state.items()}
 
 
+@functools.partial(jax.profiler.annotate_function, name="passcode.finalize")
 def finalize_state(setup: SolverSetup, state: dict,
                    *, epochs: int) -> ShardedResult:
     """``ShardedResult`` out of a segmented run's final ``SolverState``:

@@ -135,7 +135,9 @@ def dcd_block_update_pallas(X, sq_norms, alpha, w, idx, *, loss,
         block_rows=idx.shape[0], interpret=interpret, active=active,
         y=y,
     )
-    return a_new, w_new - w
+    # the full-width Δw is the round merge's (repro.core.sharded)
+    with jax.named_scope("passcode.merge"):
+        return a_new, w_new - w
 
 
 def dcd_ell_block_update_pallas(cols, vals, sq_norms, alpha, w_pad, idx, *,
@@ -159,7 +161,9 @@ def dcd_ell_block_update_pallas(cols, vals, sq_norms, alpha, w_pad, idx, *,
         block_rows=idx.shape[0], interpret=interpret, active=active,
         y=y,
     )
-    return a_new, w_new - w_pad
+    # the full-width Δw is the round merge's (repro.core.sharded)
+    with jax.named_scope("passcode.merge"):
+        return a_new, w_new - w_pad
 
 
 # ------------------- split-phase 2D (data × model) block entry points ----
@@ -246,4 +250,6 @@ def dcd_feature_block_update_pallas(cols, vals, sq_norms, alpha, w_loc, idx,
         cols, vals, sq_norms, alpha, w_loc, idx, base, gram, loss=loss,
         interpret=interpret, active=active, y=y,
     )
-    return a_new, w_new - w_loc
+    # the full-width Δw is the round merge's (repro.core.sharded)
+    with jax.named_scope("passcode.merge"):
+        return a_new, w_new - w_loc
