@@ -132,7 +132,7 @@ def _vmem_frontier(rows):
         k_loc = -(-k // m)
         d_loc = -(-d // m)
         dense_ok = dcd_kernel_fits(n_loc, d)
-        ell_ok = dcd_ell_kernel_fits(n_loc, k, d)
+        ell_ok = dcd_ell_kernel_fits(d)
         feat_ok = dcd_feature_kernel_fits(n_loc, k_loc, d_loc)
         rows.append({
             "name": (f"feature/vmem/{name}/n_loc={n_loc},d={d},"
@@ -143,7 +143,7 @@ def _vmem_frontier(rows):
                 f"feature_fits={feat_ok},"
                 f"density={k / d:.5%},"
                 f"dense_mib={dcd_kernel_vmem_bytes(n_loc, d) / 2**20:.0f},"
-                f"ell_mib={dcd_ell_kernel_vmem_bytes(n_loc, k, d) / 2**20:.1f},"
+                f"ell_mib={dcd_ell_kernel_vmem_bytes(d) / 2**20:.1f},"
                 f"feature_mib="
                 f"{dcd_feature_kernel_vmem_bytes(n_loc, k_loc, d_loc) / 2**20:.1f}"
             ),

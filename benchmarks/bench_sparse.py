@@ -102,15 +102,12 @@ def _bench_profile(rows, name, n, d, k):
     })
 
     # ELL fused engine (interpret mode off-TPU — semantics + host time)
-    kp = lane_pad(k)
-    cols_p = jnp.full((n, kp), d, jnp.int32).at[:, :k].set(cols)
-    vals_p = jnp.zeros((n, kp), jnp.float32).at[:, :k].set(vals)
     d1 = lane_pad(d + 1)
     w1 = jnp.zeros((d1,), jnp.float32)
     carry1 = jnp.zeros((d1,), jnp.float32)
     fn_k = make_sharded_epoch(mesh, loss, ell=True,
                               use_kernel=True)
-    t_fused = timeit(lambda: fn_k((cols_p, vals_p), sq_e, alpha, w1,
+    t_fused = timeit(lambda: fn_k((cols, vals), sq_e, alpha, w1,
                                   blocks, carry1))
     mode = "interpret" if jax.default_backend() != "tpu" else "compiled"
     rows.append({
@@ -134,14 +131,14 @@ def _bench_vmem_frontier(rows):
     )
     for name, n_loc, d, k in cases:
         dense_ok = dcd_kernel_fits(n_loc, d)
-        ell_ok = dcd_ell_kernel_fits(n_loc, k, d)
+        ell_ok = dcd_ell_kernel_fits(d)
         rows.append({
             "name": f"sparse/vmem/{name}/n_loc={n_loc},d={d},k={k}",
             "us_per_call": 0.0,
             "derived": (
                 f"dense_fits={dense_ok},ell_fits={ell_ok},"
                 f"dense_mib={dcd_kernel_vmem_bytes(n_loc, d) / 2**20:.0f},"
-                f"ell_mib={dcd_ell_kernel_vmem_bytes(n_loc, k, d) / 2**20:.1f}"
+                f"ell_mib={dcd_ell_kernel_vmem_bytes(d) / 2**20:.1f}"
             ),
         })
 

@@ -224,7 +224,8 @@ def phase_kernels(ds, *, seed: int):
                              epochs=2, seed=seed)
         say(f"phase 2 {name}: {fused.engine} vs {ref.engine}, "
             f"{time.perf_counter() - t0:.1f} s wall incl. compile")
-        gate("pallas-compiled" in fused.engine,
+        gate("/pallas" in fused.engine
+             and fused.engine.endswith("-compiled"),
              f"phase 2 {name}: kernel compiled, not interpreted")
         gate("tpu_custom_call" in hlo,
              f"phase 2 {name}: tpu_custom_call in the compiled HLO")

@@ -46,12 +46,13 @@ the type of ``X_host`` (dense array vs ``repro.data.sparse.EllMatrix``)
     (``repro.kernels.dcd_block_update_pallas`` dense,
     ``dcd_ell_block_update_pallas`` sparse,
     ``dcd_feature_block_update_pallas`` 2-D — the latter batches the B
-    per-update psums into one (base, Gram) psum per block): the
-    device's whole row shard/slice is VMEM-resident, updates
+    per-update psums into one (base, Gram) psum per block): updates
     gather/scatter by row id inside the kernel (interpret mode on CPU,
-    compiled on TPU).  ``"auto"`` fuses only on TPU when the shard fits
-    VMEM — ``dcd_kernel_fits`` for the dense n_loc·d̃ shard,
-    ``dcd_ell_kernel_fits`` for the ~2·n_loc·k̃ ELL shard,
+    compiled on TPU); the dense shard and the 2-D slice are
+    VMEM-resident, the 1-D ELL shard stays in HBM and each row is
+    streamed in by DMA.  ``"auto"`` fuses only on TPU when what the
+    kernel holds fits VMEM — ``dcd_kernel_fits`` for the dense n_loc·d̃
+    shard, ``dcd_ell_kernel_fits`` for the ELL kernel's 2·d₁ primal,
     ``dcd_feature_kernel_fits`` for the ~2·n_loc·k̃_loc + 2·d/m 2-D
     slice — falling back to pure jnp otherwise.
 
@@ -135,6 +136,7 @@ from repro.kernels.ops import (
     dcd_feature_gram_pallas,
     dcd_feature_update_pallas,
 )
+from repro.kernels.dcd_ell import stream_rows
 
 # The solver's layers inside the compiled epoch, as ``jax.named_scope``s:
 # each reaches the op_name of every instruction it holds, so a device
@@ -257,21 +259,23 @@ def _local_block_update_feature(cols_loc, vals_loc, sq_loc, alpha_loc,
         return alpha_loc, w_new - w_loc  # (updated α shard, local Δw shard)
 
 
-def _resolve_kernel_mode(use_kernel, n_loc: int, d: int,
-                         k_max: int | None = None):
+def _resolve_kernel_mode(use_kernel, n_loc: int, d: int, *,
+                         ell: bool = False, block_size: int = 64):
     """Resolve ``use_kernel`` ∈ {False, True, "auto"} → (fused?, interpret?).
 
-    "auto" fuses only where it pays: compiled on TPU with the row shard
-    VMEM-resident (``dcd_kernel_fits``, or ``dcd_ell_kernel_fits`` when
-    ``k_max`` marks the shard as ELL — the sparse policy admits large-d
-    problems the dense one rejects); everywhere else the pure-jnp block
-    update is kept.  ``True`` forces the kernel — in interpret mode
-    off-TPU, which validates semantics rather than speed.
+    "auto" fuses only where it pays: compiled on TPU with what the
+    kernel holds resident fitting VMEM — the dense row shard
+    (``dcd_kernel_fits``), or for an ELL shard (``ell``) the padded
+    primal alone (``dcd_ell_kernel_fits``: the rows stream from HBM, so
+    any n_loc is admitted); everywhere else the pure-jnp block update is
+    kept.  ``True`` forces the kernel — in interpret mode off-TPU, which
+    validates semantics rather than speed.
     """
     on_tpu = jax.default_backend() == "tpu"
     if use_kernel == "auto":
-        if k_max is not None:
-            use_kernel = on_tpu and dcd_ell_kernel_fits(n_loc, k_max, d)
+        if ell:
+            use_kernel = on_tpu and dcd_ell_kernel_fits(
+                d, block_size=block_size)
         else:
             use_kernel = on_tpu and dcd_kernel_fits(n_loc, d)
     return bool(use_kernel), not on_tpu
@@ -749,35 +753,47 @@ def _make_shrink_2d(loss, cols_loc, vals_loc, shrink_tol: float, valid):
 
 def _block_update_1d(loss, use_kernel: bool, interpret: bool, ell: bool):
     """The per-device block engine for a 1-D mesh, shared by the
-    per-epoch and pipelined builders.  ``act`` (optional (n_loc,) mask)
-    freezes shrunk coordinates — forwarded to the fused kernels as the
-    f32 active operand, to the jnp engines as the bool gate."""
+    per-epoch and pipelined builders: ``(view, block_update)``.
+    ``view(X_loc)`` is what the engine reads, made once per dispatch
+    outside the round loop — the fused ELL kernel streams each row from
+    a lane-aligned copy of the shard (``stream_rows``) and walks the
+    shard's own k_max slots; every other engine reads X as placed.
+    ``act`` (optional (n_loc,) mask) freezes shrunk coordinates —
+    forwarded to the fused kernels as the f32 active operand, to the jnp
+    engines as the bool gate."""
 
-    def block_update(X_loc, sq_loc, alpha_loc, w_eff, idx_block,
+    def view(X_loc):
+        if not (ell and use_kernel):
+            return X_loc
+        cols_loc, vals_loc = X_loc
+        with jax.named_scope(SCOPE_UPDATE):
+            return stream_rows(cols_loc, vals_loc), cols_loc.shape[1]
+
+    def block_update(X_eng, sq_loc, alpha_loc, w_eff, idx_block,
                      act=None, y=None):
+        if ell and use_kernel:
+            rows, k = X_eng
+            return dcd_ell_block_update_pallas(
+                rows, sq_loc, alpha_loc, w_eff, idx_block, k=k, loss=loss,
+                interpret=interpret, active=act, y=y,
+            )
         if ell:
-            cols_loc, vals_loc = X_loc
-            if use_kernel:
-                return dcd_ell_block_update_pallas(
-                    cols_loc, vals_loc, sq_loc, alpha_loc, w_eff,
-                    idx_block, loss=loss, interpret=interpret, active=act,
-                    y=y,
-                )
+            cols_loc, vals_loc = X_eng
             return _local_block_update_ell(
                 cols_loc, vals_loc, sq_loc, alpha_loc, w_eff, idx_block,
                 loss, act=act, y=y,
             )
         if use_kernel:
             return dcd_block_update_pallas(
-                X_loc, sq_loc, alpha_loc, w_eff, idx_block, loss=loss,
+                X_eng, sq_loc, alpha_loc, w_eff, idx_block, loss=loss,
                 interpret=interpret, active=act, y=y,
             )
         return _local_block_update(
-            X_loc, sq_loc, alpha_loc, w_eff, idx_block, loss, act=act,
+            X_eng, sq_loc, alpha_loc, w_eff, idx_block, loss, act=act,
             y=y,
         )
 
-    return block_update
+    return view, block_update
 
 
 def _block_update_2d(loss, use_kernel: bool, interpret: bool):
@@ -818,14 +834,15 @@ def make_sharded_epoch(mesh: Mesh, loss, *, delay_rounds: int = 0,
     axis = "data"
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    block_update = _block_update_1d(loss, use_kernel, interpret, ell)
+    view, block_update = _block_update_1d(loss, use_kernel, interpret, ell)
     x_spec = (P(axis), P(axis)) if ell else P(axis)
 
     def epoch(X, sq_norms, alpha, w, blocks_idx, carry_dw):
         # blocks_idx: (n_blocks, B) *local* row ids per device (sharded).
         def device_fn(X_loc, sq_loc, alpha_loc, w_rep, blocks_loc, dw_prev):
+            X_eng = view(X_loc)
             return _scan_rounds(
-                lambda a, w_eff, idx: block_update(X_loc, sq_loc, a,
+                lambda a, w_eff, idx: block_update(X_eng, sq_loc, a,
                                                    w_eff, idx),
                 alpha_loc, w_rep, dw_prev, blocks_loc, delay_rounds,
             )
@@ -1380,13 +1397,14 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
     dyn = (shrink_on or adaptive) and not pod_on
     fault = _check_pipeline_chaos(record=record, watchdog=watchdog,
                                   fault=fault, pod_on=pod_on)
-    block_update = _block_update_1d(loss, use_kernel, interpret, ell)
+    view, block_update = _block_update_1d(loss, use_kernel, interpret, ell)
     x_spec = (P(row_ax), P(row_ax)) if ell else P(row_ax)
     delay0 = int(pod_delay_rounds > 0) if pod_on else delay_rounds
     pod_fifo = pod_delay_rounds if (pod_on and pod_delay_rounds > 0) else 0
 
     def device_body(X_loc, sq_loc, st, y_loc=None):
         my = jax.lax.axis_index(axis)
+        X_eng = view(X_loc)
         n_loc = st["alpha"].shape[-1]
         d_run = st["w"].shape[-1]
         if pod_on:
@@ -1424,7 +1442,7 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
             else:
                 gap = None
             bu = lambda a, w_eff, idx, act=None: block_update(
-                X_loc, sq_loc, a, w_eff, idx, act, y)
+                X_eng, sq_loc, a, w_eff, idx, act, y)
             if dyn:
                 rounds = functools.partial(_scan_rounds_dyn, bu)
             else:
@@ -2039,7 +2057,6 @@ def prepare_solver(
         n, d, k_max = X_host.n_rows, X_host.n_features, X_host.k_max
     else:
         n, d = X_host.shape
-        k_max = None
     # ceil twice on a pod mesh: each pod's contiguous row shard carries
     # its OWN padded tail (pod_row_layout), then subdivides over "data"
     n_pod_loc = max(-(-n // pods), 1)
@@ -2050,7 +2067,8 @@ def prepare_solver(
         rowmap, _ = pod_row_layout(n, pods, per_pod_rows=p * n_loc)
         rows = rowmap.reshape(-1)  # global id, n = padding
         ridx = jnp.asarray(rows)
-    use_k, interpret = _resolve_kernel_mode(use_kernel, n_loc, d, k_max)
+    use_k, interpret = _resolve_kernel_mode(use_kernel, n_loc, d, ell=is_ell,
+                                            block_size=block_size)
     # a 1-D mesh has no model-axis psum: "auto" resolves to no overlap,
     # an explicit True is an error
     pipeline_overlap(overlap, two_d=False, fused=use_k,
@@ -2060,9 +2078,9 @@ def prepare_solver(
                                  pipeline=pipeline, record=record)
     if is_ell:
         X_gap = X_host  # duality gap always reads the unpadded data
-        # lane-pad k_max to the 128-lane tile when fused; pad rows to
-        # n_pad with all-padding rows (index d, value 0)
-        k_run = lane_pad(k_max) if use_k else k_max
+        # the shard keeps its own width k_max on every engine (the fused
+        # kernel lane-aligns a copy per dispatch, ``stream_rows``); pad
+        # rows to n_pad with all-padding rows (index d, value 0)
         # padded primal with the dummy slot at index d (lane-padded for
         # clean tiling when fused); padding scatter-adds land there
         d_run = lane_pad(d + 1) if use_k else d + 1
@@ -2070,8 +2088,8 @@ def prepare_solver(
         # row appended at global index n — each pod's contiguous shard
         # lands with its own padded tail
         lead = n + 1 if pod_on else n_pad
-        cols = _pad_host(X_host.indices, (lead, k_run), d, np.int32, rows)
-        vals = _pad_host(X_host.values, (lead, k_run), 0.0, np.float32,
+        cols = _pad_host(X_host.indices, (lead, k_max), d, np.int32, rows)
+        vals = _pad_host(X_host.values, (lead, k_max), 0.0, np.float32,
                          rows)
         sq_norms = _pad_host(X_host.row_sq_norms(), (lead,), 1.0,
                              np.float32, rows)
@@ -2320,12 +2338,14 @@ def finalize_state(setup: SolverSetup, state: dict,
 def engine_name(setup: SolverSetup) -> str:
     """``<layout>/<engine>`` of a prepared solve: layout ``dense``,
     ``ell`` or ``feature`` (2-D), engine ``jnp`` or ``pallas`` with its
-    mode — ``compiled`` on TPU, ``interpret`` elsewhere."""
+    mode — ``compiled`` on TPU, ``interpret`` elsewhere; the 1-D ELL
+    kernel, which streams its rows from HBM, is ``pallas-stream``."""
     layout = "feature" if setup.two_d else ("ell" if setup.ell else "dense")
     if not setup.use_k:
         return f"{layout}/jnp"
+    kernel = "pallas-stream" if layout == "ell" else "pallas"
     mode = "interpret" if setup.interpret else "compiled"
-    return f"{layout}/pallas-{mode}"
+    return f"{layout}/{kernel}-{mode}"
 
 
 def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,

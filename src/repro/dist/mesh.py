@@ -266,40 +266,37 @@ def dcd_kernel_fits(n_loc: int, d: int, *, vmem_bytes: int = VMEM_BYTES,
         headroom * vmem_bytes)
 
 
-def dcd_ell_kernel_vmem_bytes(n_loc: int, k_max: int, d: int, *,
-                              itemsize: int = 4,
-                              n_tasks: int = 1) -> int:
-    """Resident working set of the fused *ELL* indexed-block round
-    (DESIGN.md §9): the (n_loc, k̃) column-id and value shards
-    (2·n_loc·k̃ words, k̃ = k_max lane-padded), the padded primal in/out
-    (2·d₁ with d₁ = lane_pad(d+1) for the dummy slot), α in/out + q +
-    the active-set mask (4·n_loc f32) and the int32 index block (n_loc
-    upper bound).
+def dcd_ell_kernel_vmem_bytes(d: int, *, block_size: int = 64,
+                              itemsize: int = 4) -> int:
+    """Resident working set of the fused *ELL* block kernel (DESIGN.md
+    §9), which streams the row shard from HBM: the padded primal in and
+    out (2·d₁ with d₁ = lane_pad(d+1) for the dummy slot) and the block's
+    per-step operands — α seed, ‖x‖², active mask and label in, α out —
+    five (B, 1) columns of one lane tile each (5·B·128 words).  The
+    row's ids and values sit in SMEM, double-buffered, and nothing grows
+    with n_loc or k_max: admission depends on d alone.  A multi-task
+    solve runs the kernel over a task grid, one head's primal at a time,
+    in the same working set.
 
-    Independent of d except through the 2·d₁ primal term — this is what
-    admits the large-d problems (rcv1 d≈47k, news20 d≈1.3M at paper
-    scale) whose dense n_loc·d̃ shard ``dcd_kernel_fits`` rejects.
-
-    ``n_tasks > 1`` multiplies the per-task operands (primal in/out,
-    α in/out, mask/label word) like ``dcd_kernel_vmem_bytes``; the ELL
-    shard, q, and the index block stay shared."""
-    kp = lane_pad(k_max)
-    d1 = lane_pad(d + 1)
-    K = max(int(n_tasks), 1)
-    return (itemsize * (2 * n_loc * kp + n_loc + K * (2 * d1 + 3 * n_loc))
-            + 4 * n_loc)
+    Tied to the compiler: for a described v5e, under Mosaic's default
+    scoped VMEM limit (16 MiB, = ``VMEM_BYTES``), the kernel compiles up
+    to d ≈ 2.096M, where 2·d₁ words are 16.77 MB, alone and vmapped over
+    8 heads; ``tests/test_tpu_compile.py`` compiles it at this policy's
+    frontier."""
+    words = 2 * lane_pad(d + 1) + 5 * block_size * 128
+    return itemsize * words
 
 
-def dcd_ell_kernel_fits(n_loc: int, k_max: int, d: int, *,
-                        vmem_bytes: int = VMEM_BYTES,
-                        headroom: float = 0.9, n_tasks: int = 1) -> bool:
-    """True when a device's ELL row shard can stay VMEM-resident for the
-    fused sparse kernel; otherwise
+def dcd_ell_kernel_fits(d: int, *, vmem_bytes: int = VMEM_BYTES,
+                        headroom: float = 0.9,
+                        block_size: int = 64) -> bool:
+    """True when the fused sparse kernel's resident primal fits VMEM —
+    rcv1 (d 47,236: 0.5 MB) and news20 (d 1,355,191: 11 MB) do, webspam
+    (d 16.6M) does not; otherwise
     ``sharded_passcode_solve(use_kernel="auto")`` keeps the unfused jnp
     ELL block update."""
-    return dcd_ell_kernel_vmem_bytes(n_loc, k_max, d, n_tasks=n_tasks) <= (
-        headroom * vmem_bytes
-    )
+    return dcd_ell_kernel_vmem_bytes(d, block_size=block_size) <= (
+        headroom * vmem_bytes)
 
 
 def dcd_feature_kernel_vmem_bytes(n_loc: int, k_loc: int, d_loc: int, *,

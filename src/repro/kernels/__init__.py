@@ -10,8 +10,8 @@ carries w across blocks, so a whole epoch is ONE pallas_call.
   dcd_block.py — the dense kernels (contiguous-tile + indexed/gather
                  modes, pl.pallas_call + BlockSpec)
   dcd_ell.py   — the sparse (ELL) indexed kernel: O(k_max) gather /
-                 dummy-slot scatter per update against a 2·n_loc·k̃-word
-                 resident shard (DESIGN.md §9)
+                 dummy-slot scatter per update, each row streamed from
+                 HBM by DMA against a VMEM-resident primal (DESIGN.md §9)
   dcd_feature.py — the 2D (data × model) feature-sharded block kernels:
                  per-shard partial (base, Gram) + δ-recursion/scatter
                  against a d₁_loc-word primal *shard*, one psum per

@@ -3,23 +3,27 @@
 Sparse sibling of ``repro.kernels.dcd_block``'s indexed mode (DESIGN.md
 §9).  PASSCoDe's datasets are 0.03–1% dense, so the dense kernel's
 per-update O(d) dot/axpy and O(n_loc·d) VMEM residency are both ~1000×
-larger than the work actually performed.  This kernel keeps the device's
-row shard in the ELL layout of ``repro.data.sparse.EllMatrix``:
+larger than the work actually performed.  This kernel runs one block of
+B sequential updates against the device's row shard in the ELL layout of
+``repro.data.sparse.EllMatrix``:
 
-  cols: (n_loc, k̃) int32 column ids, padding == d (one past the end)
-  vals: (n_loc, k̃) f32 values, padding == 0.0
+  cols: (n_loc, k) int32 column ids, padding == d (one past the end)
+  vals: (n_loc, k) f32 values, padding == 0.0
 
-with k̃ = k_max lane-padded to a multiple of 128, and holds ≈ 2·n_loc·k̃
-words resident instead of n_loc·d̃ — the VMEM policy is
+which the solver keeps at the shard's own width k = k_max.  For the
+kernel the shard is copied once per dispatch to an (n_loc, 1, k̃)
+layout, k̃ = k lane-padded (``stream_rows``), in which each row is one
+lane tile that one DMA moves.  That copy stays in HBM: each row of the
+block is streamed into SMEM, the next row's copy in flight while the
+current one is walked, and only its k slots are walked — so what is
+resident grows with neither n_loc nor k; the VMEM policy is
 ``repro.dist.mesh.dcd_ell_kernel_fits``.
 
 The padded primal (d₁ = d+1 lane-padded words; slot d is the *dummy
 slot*) lives in VMEM as a (d₁/128, 128) ref, so feature c sits at
-sublane row c // 128, lane c % 128.  Per update (grid step i, loop step
-t over the block's row ids):
+sublane row c // 128, lane c % 128.  Per update t of the block:
 
-  * copy the row's k̃ column ids and values into SMEM (one local DMA
-    each), so the walk below reads them as scalars;
+  * wait for row t's ids and values in SMEM and start row t+1's copy;
   * w·x_i = Σ_j w[c_j]·v_j — per nonzero, one dynamic row slice of the
     primal and a one-hot lane mask, accumulated lane-wise and reduced
     once; padded entries read the dummy slot (0) times value 0;
@@ -32,10 +36,12 @@ Mosaic has no lane gather or scatter into a 1-D value, which is why
 each nonzero is a row slice plus a mask rather than ``jnp.take`` /
 ``.at[].add`` on the primal.
 
-α and w have constant BlockSpec index_maps and the TPU grid executes
-sequentially, so both carry across grid steps exactly like the dense
-indexed kernel: one pallas_call runs the whole sequence of blocks with
-serial-DCD semantics and zero locking.
+The per-row scalars — α_i, ‖x_i‖², the active mask and the label — are
+gathered for the block's B steps by the caller (B words each, not n_loc)
+and the block's α come back per step.  A row the block visits twice
+reads the α its earlier visit wrote: ``src[t]`` names the step whose
+output holds row t's current α (t itself on a first visit), so the
+block keeps the serial-DCD semantics of the jnp engine exactly.
 """
 
 from __future__ import annotations
@@ -48,6 +54,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+# Nonzeros walked per loop iteration.  Mosaic lowers a fori_loop either
+# rolled or fully unrolled, so the walks unroll by hand.  On a TPU v5e a
+# row walk costs 55 ns per nonzero and walk rolled, 11.5 ns unrolled by
+# 8; a whole update at rcv1's width takes 2.73, 2.04 and 1.73 µs at 4, 8
+# and 16, at news20's 15.5, 11.3 and 9.4 µs (PERF.md §6).
+UNROLL = 16
+
+
+def _walk(n: int, body, carry):
+    """``carry = body(j, carry)`` for j in [0, n), ``UNROLL`` steps per
+    loop iteration and the remainder unrolled after the loop."""
+    def step(jj, carry):
+        for u in range(UNROLL):
+            carry = body(jj * UNROLL + u, carry)
+        return carry
+
+    carry = jax.lax.fori_loop(0, n // UNROLL, step, carry)
+    for j in range(n - n % UNROLL, n):
+        carry = body(j, carry)
+    return carry
 
 
 def load_row(col_ref, val_ref, i, cbuf, vbuf, sem):
@@ -60,9 +86,10 @@ def load_row(col_ref, val_ref, i, cbuf, vbuf, sem):
         cp.wait()
 
 
-def row_dot(w_ref, cbuf, vbuf):
-    """Σ_j w[c_j]·v_j over the SMEM row against a (d₁/128, 128) primal
-    ref — returns a (1, 1) f32."""
+def row_dot(w_ref, cbuf, vbuf, k=None):
+    """Σ_j w[c_j]·v_j over the first ``k`` entries (all by default) of
+    the (1, k̃) SMEM row pair against a (d₁/128, 128) primal ref —
+    returns a (1, 1) f32."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
     def body(j, acc):
@@ -70,13 +97,14 @@ def row_dot(w_ref, cbuf, vbuf):
         r = w_ref[pl.ds(c // LANES, 1), :]
         return acc + jnp.where(lane == c % LANES, r, 0.0) * vbuf[0, j]
 
-    acc = jax.lax.fori_loop(0, cbuf.shape[1], body,
-                            jnp.zeros((1, LANES), jnp.float32))
+    acc = _walk(cbuf.shape[1] if k is None else k, body,
+                jnp.zeros((1, LANES), jnp.float32))
     return jnp.sum(acc, axis=1, keepdims=True)
 
 
-def row_axpy(w_ref, cbuf, vbuf, scale):
-    """w[c_j] += scale·v_j over the SMEM row; ``scale`` is (1, 1)."""
+def row_axpy(w_ref, cbuf, vbuf, scale, k=None):
+    """w[c_j] += scale·v_j over the first ``k`` entries of the SMEM row
+    pair; ``scale`` is (1, 1)."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
     def body(j, carry):
@@ -86,115 +114,134 @@ def row_axpy(w_ref, cbuf, vbuf, scale):
             lane == c % LANES, scale * vbuf[0, j], 0.0)
         return carry
 
-    jax.lax.fori_loop(0, cbuf.shape[1], body, 0)
+    _walk(cbuf.shape[1] if k is None else k, body, 0)
 
 
-def _dcd_ell_indexed_kernel(
-    idx_ref,  # (B, 1)  int32 local row ids for this grid step (SMEM)
-    col_ref,  # (n, k)  whole shard's column ids, VMEM-resident
-    val_ref,  # (n, k)  whole shard's values, VMEM-resident
-    alpha_ref,  # (n, 1)  duals — seeds the carried output
-    q_ref,  # (n, 1)  row squared norms
-    act_ref,  # (n, 1)  active-set mask (f32 0/1; all-ones = no shrinking)
-    y_ref,  # (n, 1)  row labels (±1; all-ones = pre-folded rows)
-    w_ref,  # (d1/128, 128) padded primal (dummy slot at d) — seeds the carry
-    alpha_out,  # (n, 1)  carried across grid steps
-    w_out,  # (d1/128, 128) carried across grid steps
-    cbuf,  # (1, k) SMEM scratch: the current row's ids
-    vbuf,  # (1, k) SMEM scratch: the current row's values
-    sem,  # (2,) DMA semaphores
+def stream_rows(cols, vals):
+    """The shard as the streamed kernel reads it: (n, 1, k̃), k̃ = k
+    lane-padded, so that each row is one whole lane tile and one DMA
+    (the padding past k is never walked).  Made once per dispatch,
+    outside the round loop; the solver's other readers keep the
+    unpadded (n, k) shard."""
+    n, k = cols.shape
+    pad = ((0, 0), (0, -k % LANES))
+    return (jnp.pad(cols, pad).reshape(n, 1, -1),
+            jnp.pad(vals, pad).reshape(n, 1, -1))
+
+
+def _row_copies(col_hbm, val_hbm, idx_ref, t, cbuf, vbuf, sem):
+    """The DMAs that bring step ``t``'s row into SMEM slot t % 2."""
+    i, slot = idx_ref[t], t % 2
+    return (pltpu.make_async_copy(col_hbm.at[i], cbuf.at[slot],
+                                  sem.at[0, slot]),
+            pltpu.make_async_copy(val_hbm.at[i], vbuf.at[slot],
+                                  sem.at[1, slot]))
+
+
+def _dcd_ell_stream_kernel(
+    idx_ref,  # (B,)  int32 local row ids of the block's steps (SMEM)
+    src_ref,  # (B,)  int32 step whose α output holds row t's α (SMEM)
+    row_ref,  # (4, B, 1) per step: α seed, ‖x‖², active (0/1), label ±1
+    col_hbm,  # (n, 1, k̃) int32 shard's column ids, left in HBM
+    val_hbm,  # (n, 1, k̃) f32 shard's values, left in HBM
+    w_ref,  # (d1/128, 128) padded primal (dummy slot at d)
+    alpha_out,  # (B, 1) α of step t's row after its update
+    w_out,  # (d1/128, 128) updated primal
+    cbuf,  # (2, 1, k̃) SMEM: row ids, double-buffered
+    vbuf,  # (2, 1, k̃) SMEM: row values, double-buffered
+    sem,  # (2, 2) DMA semaphores: (ids|values, slot)
     *,
     loss,
+    k: int,
     block_rows: int,
 ):
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        alpha_out[...] = alpha_ref[...]
-        w_out[...] = w_ref[...]
+    for cp in _row_copies(col_hbm, val_hbm, idx_ref, 0, cbuf, vbuf, sem):
+        cp.start()
+    alpha_out[...] = row_ref[0]
+    w_out[...] = w_ref[...]
 
     def body(t, carry):
-        i = idx_ref[t, 0]
-        load_row(col_ref, val_ref, i, cbuf, vbuf, sem)
-        yi = y_ref[pl.ds(i, 1), :]  # (1, 1) ±1 — folds the row on read
-        wx = yi * row_dot(w_out, cbuf, vbuf)
-        a = alpha_out[pl.ds(i, 1), :]  # running α, not the seed
-        q = q_ref[pl.ds(i, 1), :]
+        for cp in _row_copies(col_hbm, val_hbm, idx_ref, t, cbuf, vbuf,
+                              sem):
+            cp.wait()
+
+        @pl.when(t + 1 < block_rows)
+        def _prefetch():
+            for cp in _row_copies(col_hbm, val_hbm, idx_ref, t + 1, cbuf,
+                                  vbuf, sem):
+                cp.start()
+
+        cb, vb = cbuf.at[t % 2], vbuf.at[t % 2]
+        step = pl.ds(t, 1)
+        yi = row_ref[3, step, :]  # (1, 1) ±1 — folds the row on read
+        wx = yi * row_dot(w_out, cb, vb, k)
+        a = alpha_out[pl.ds(src_ref[t], 1), :]  # running α, not the seed
         # frozen (shrunk) coordinates take the exact zero-delta update —
         # same gate as the serial reference's masked epoch
-        delta = jnp.where(
-            act_ref[pl.ds(i, 1), :] > 0.0, loss.delta(a, wx, q), 0.0
-        )
-        alpha_out[pl.ds(i, 1), :] = a + delta
+        delta = jnp.where(row_ref[2, step, :] > 0.0,
+                          loss.delta(a, wx, row_ref[1, step, :]), 0.0)
+        alpha_out[step, :] = a + delta
         # rank-1 sparse axpy; padding ids add δ·0 into the dummy slot
-        row_axpy(w_out, cbuf, vbuf, delta * yi)
+        row_axpy(w_out, cb, vb, delta * yi, k)
         return carry
 
     jax.lax.fori_loop(0, block_rows, body, 0)
 
 
-def dcd_ell_epoch_pallas_call(
-    cols,  # (n, k) int32, k % 128 == 0; padding ids == d (dummy slot)
-    vals,  # (n, k) f32, padding == 0
+def dcd_ell_block_pallas_call(
+    rows,  # stream_rows(cols, vals): (n, 1, k̃) ids (padding == d), values
     alpha,  # (n,)
     w_pad,  # (d1,) padded primal, d1 % 128 == 0, slot d and above == 0
     sq_norms,  # (n,)
+    idx,  # (B,) int32 local row ids of the block, repeats allowed
     *,
+    k: int,  # slots walked per row: the shard's k_max
     loss,
-    idx,  # (m,) int32 row ids, m % block_rows == 0
-    block_rows: int = 256,
     interpret: bool = False,
     active=None,  # (n,) 0/1 active-set mask; None = all active
     y=None,  # (n,) ±1 labels folded on read; None = pre-folded rows
 ):
-    n, k = cols.shape
+    """B sequential DCD updates in ``idx`` order; returns (α, w_pad)."""
+    cols, vals = rows
+    kp = cols.shape[-1]
     d1 = w_pad.shape[0]
-    rows = d1 // LANES
-    m = idx.shape[0]
-    assert m % block_rows == 0, (m, block_rows)
-    assert d1 % LANES == 0, d1
-    grid = (m // block_rows,)
-    idx2 = idx.reshape(m, 1).astype(jnp.int32)
-    alpha2 = alpha.reshape(n, 1).astype(jnp.float32)
-    q2 = sq_norms.reshape(n, 1).astype(jnp.float32)
-    if active is None:
-        act2 = jnp.ones((n, 1), jnp.float32)
-    else:
-        act2 = active.reshape(n, 1).astype(jnp.float32)
-    if y is None:
-        y2 = jnp.ones((n, 1), jnp.float32)
-    else:
-        y2 = y.reshape(n, 1).astype(jnp.float32)
-    w2 = w_pad.reshape(rows, LANES).astype(jnp.float32)
-    kernel = functools.partial(
-        _dcd_ell_indexed_kernel, loss=loss, block_rows=block_rows
-    )
-    alpha_out, w_out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0),
-                         memory_space=pltpu.SMEM),  # idx block
-            pl.BlockSpec((n, k), lambda i: (0, 0)),  # cols: whole shard
-            pl.BlockSpec((n, k), lambda i: (0, 0)),  # vals: whole shard
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),  # alpha seed
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),  # sq norms
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),  # active mask
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),  # row labels
-            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),  # w seed
-        ],
-        out_specs=[
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),  # carried α
-            pl.BlockSpec((rows, LANES), lambda i: (0, 0)),  # carried w
-        ],
+    b = idx.shape[0]
+    assert d1 % LANES == 0 and kp % LANES == 0 and k <= kp, (d1, kp, k)
+    idx = idx.astype(jnp.int32)
+    # the step of each row's previous visit in the block (itself on a
+    # first visit) and of its last visit, whose α is the block's answer
+    step = jnp.arange(b)
+    same = idx[:, None] == idx[None, :]
+    prev = jnp.max(jnp.where(same & (step[None, :] < step[:, None]),
+                             step[None, :], -1), axis=1)
+    src = jnp.where(prev < 0, step, prev)
+    last = jnp.max(jnp.where(same, step[None, :], 0), axis=1)
+    ones = jnp.ones((b,), jnp.float32)
+    per_step = jnp.stack([
+        alpha[idx], sq_norms[idx],
+        ones if active is None else active[idx].astype(jnp.float32),
+        ones if y is None else y[idx].astype(jnp.float32),
+    ]).astype(jnp.float32)[:, :, None]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    a_steps, w_out = pl.pallas_call(
+        functools.partial(_dcd_ell_stream_kernel, loss=loss, k=k,
+                          block_rows=b),
+        in_specs=[smem, smem, vmem, hbm, hbm, vmem],
+        out_specs=[vmem, vmem],
         out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((d1 // LANES, LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.SMEM((1, k), jnp.int32),
-            pltpu.SMEM((1, k), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2, 1, kp), jnp.int32),
+            pltpu.SMEM((2, 1, kp), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,
-    )(idx2, cols, vals, alpha2, q2, act2, y2, w2)
-    return alpha_out.reshape(n), w_out.reshape(d1)
+    )(idx, src.astype(jnp.int32), per_step, cols, vals,
+      w_pad.reshape(-1, LANES).astype(jnp.float32))
+    # a row visited twice gets the same (last) α from both visits
+    alpha = alpha.at[idx].set(a_steps[last, 0])
+    return alpha, w_out.reshape(d1)

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.dcd_block import dcd_epoch_pallas_call
-from repro.kernels.dcd_ell import dcd_ell_epoch_pallas_call
+from repro.kernels.dcd_ell import dcd_ell_block_pallas_call
 from repro.kernels.dcd_feature import (
     dcd_feature_gram_pallas_call,
     dcd_feature_update_pallas_call,
@@ -140,26 +140,26 @@ def dcd_block_update_pallas(X, sq_norms, alpha, w, idx, *, loss,
         return a_new, w_new - w
 
 
-def dcd_ell_block_update_pallas(cols, vals, sq_norms, alpha, w_pad, idx, *,
+def dcd_ell_block_update_pallas(rows, sq_norms, alpha, w_pad, idx, *, k,
                                 loss, interpret: bool = False,
                                 active=None, y=None):
     """One indexed block of B sequential DCD updates on an ELL shard —
     the fused equivalent of ``repro.core.sharded._local_block_update_ell``.
 
     Traced (not jitted) so it can run inside a ``shard_map`` body:
-    ``cols``/``vals`` are this device's (n_loc, k̃) ELL shard with k̃
-    already lane-padded to 128 by the caller, ``w_pad`` the (d₁,) padded
-    primal (dummy slot at index d, d₁ a multiple of 128), ``idx`` the
-    (B,) local row ids of the block.  ``active`` (optional (n_loc,) 0/1
-    mask) freezes shrunk coordinates to zero-delta updates; ``y``
-    (optional (n_loc,) ±1 labels) folds rows on read.  Returns
-    (updated α shard, local Δw_pad) exactly like the dense block
-    engine — the padding slots of Δw_pad are identically zero.
+    ``rows`` is this device's ELL shard as ``stream_rows`` lays it out
+    (left in HBM and streamed row by row; ``k`` the shard's k_max, the
+    slots walked per row), ``w_pad`` the (d₁,) padded primal (dummy
+    slot at index d, d₁ a multiple of 128), ``idx`` the (B,) local row
+    ids of the block.  ``active`` (optional (n_loc,) 0/1 mask) freezes
+    shrunk coordinates to zero-delta updates; ``y`` (optional (n_loc,)
+    ±1 labels) folds rows on read.  Returns (updated α shard, local
+    Δw_pad) exactly like the dense block engine — the padding slots of
+    Δw_pad are identically zero.
     """
-    a_new, w_new = dcd_ell_epoch_pallas_call(
-        cols, vals, alpha, w_pad, sq_norms, loss=loss, idx=idx,
-        block_rows=idx.shape[0], interpret=interpret, active=active,
-        y=y,
+    a_new, w_new = dcd_ell_block_pallas_call(
+        rows, alpha, w_pad, sq_norms, idx, k=k, loss=loss,
+        interpret=interpret, active=active, y=y,
     )
     # the full-width Δw is the round merge's (repro.core.sharded)
     with jax.named_scope("passcode.merge"):

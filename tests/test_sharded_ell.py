@@ -4,6 +4,9 @@ ELL in interpret mode, and the dense reference) must agree to atol 1e-5
 for every loss in the family and for delayed (stale-τ) rounds, the tail
 rows of a non-p-divisible n must be trained rather than dropped, and
 ``dense_to_ell``/``to_dense`` must round-trip on ragged-row matrices.
+The fused kernel streams each row from HBM: one block of it matches the
+jnp engine's block at a width that is not a multiple of 128, on a block
+that revisits rows, and with the active-mask and label operands.
 
 Multi-device agreement (including the masked tail padding) is covered by
 an 8-host-device subprocess, same pattern as tests/test_sharded_kernel.py.
@@ -22,9 +25,19 @@ from hypothesis import strategies as st
 
 from repro.core import duality_gap, sharded_passcode_solve
 from repro.core.duals import Hinge, Logistic, SquaredHinge
-from repro.core.sharded import _resolve_kernel_mode
-from repro.data.sparse import dense_to_ell
-from repro.dist.mesh import dcd_ell_kernel_fits, dcd_kernel_fits
+from repro.core.sharded import _local_block_update_ell, _resolve_kernel_mode
+from repro.data.sparse import EllMatrix, dense_to_ell
+from repro.dist.mesh import (
+    dcd_ell_kernel_fits,
+    dcd_ell_kernel_vmem_bytes,
+    dcd_kernel_fits,
+    lane_pad,
+)
+from repro.kernels.dcd_ell import stream_rows
+from repro.kernels.ops import dcd_ell_block_update_pallas
+
+LOSSES = [Hinge(C=1.0), SquaredHinge(C=1.0), Logistic(C=1.0)]
+LOSS_IDS = ["hinge", "sq", "logistic"]
 
 
 @pytest.fixture(scope="module")
@@ -33,10 +46,7 @@ def tiny_ell(tiny):
 
 
 @pytest.mark.parametrize("delay_rounds", [0, 1])
-@pytest.mark.parametrize(
-    "loss", [Hinge(C=1.0), SquaredHinge(C=1.0), Logistic(C=1.0)],
-    ids=["hinge", "sq", "logistic"],
-)
+@pytest.mark.parametrize("loss", LOSSES, ids=LOSS_IDS)
 def test_ell_engine_equivalence(tiny_ell, tiny_dense, loss, delay_rounds):
     """dense jnp == ELL jnp == ELL Pallas, same blocks, atol 1e-5."""
     kw = dict(epochs=2, block_size=32, delay_rounds=delay_rounds,
@@ -61,7 +71,7 @@ def test_ell_converges(tiny_ell, hinge):
 
 
 def test_ell_auto_mode_falls_back_on_cpu(tiny_ell, hinge):
-    use_k, interpret = _resolve_kernel_mode("auto", 128, 80, 16)
+    use_k, interpret = _resolve_kernel_mode("auto", 128, 80, ell=True)
     assert use_k is False and interpret is True
     r = sharded_passcode_solve(tiny_ell, hinge, epochs=3, block_size=32,
                                use_kernel="auto", record=False)
@@ -69,17 +79,85 @@ def test_ell_auto_mode_falls_back_on_cpu(tiny_ell, hinge):
 
 
 def test_ell_vmem_policy_admits_what_dense_rejects():
-    """The reason the sparse path exists: paper-scale d (rcv1 ≈ 47k at
-    ~0.16% density) blows the dense n_loc·d̃ VMEM budget but the
-    2·n_loc·k̃ ELL shard fits comfortably."""
-    n_loc, d, k_max = 4096, 47_236, 80
-    assert not dcd_kernel_fits(n_loc, d)
-    assert dcd_ell_kernel_fits(n_loc, k_max, d)
-    # news20-scale d=1.3M is VMEM-infeasible densely even for one row
+    """The reason the sparse path exists: paper-scale d blows the dense
+    n_loc·d̃ VMEM budget, while the streamed ELL kernel keeps only the
+    padded primal resident — so both paper shards are admitted at any
+    row count (rcv1 d≈47k in 0.5 MB, news20 d≈1.36M in 11 MB), and a
+    primal over budget (webspam d≈16.6M) is still rejected."""
+    for n_loc, d in ((677_399, 47_236), (16_000, 1_355_191)):
+        assert not dcd_kernel_fits(n_loc, d)
+        assert dcd_ell_kernel_fits(d)
+    assert dcd_ell_kernel_vmem_bytes(47_236) < 2**20
     assert not dcd_kernel_fits(8, 1_355_191)
-    assert dcd_ell_kernel_fits(2048, 128, 1_355_191)
-    # ELL must still reject a genuinely oversized shard
-    assert not dcd_ell_kernel_fits(200_000, 4096, 1_355_191)
+    assert not dcd_ell_kernel_fits(16_609_143)
+
+
+def _stream_case(case: str, seed: int = 7):
+    """One block's operands for the streamed kernel and the jnp engine:
+    rows of 73 nonzeros over d = 300 (k and d₁ off the 128-lane tile),
+    a padding row, and a primal and duals away from zero."""
+    rng = np.random.default_rng(seed)
+    n, k, d, b = (40, 73, 300, 64) if case == "revisit" else (150, 73, 300, 64)
+    ids = np.stack([np.sort(rng.choice(d, k, replace=False))
+                    for _ in range(n)]).astype(np.int32)
+    vals = rng.standard_normal((n, k)).astype(np.float32)
+    vals /= np.linalg.norm(vals, axis=1, keepdims=True)
+    ids[-1], vals[-1] = d, 0.0  # an all-padding row, as the tail pads
+    sq = np.maximum((vals * vals).sum(1), 1e-12).astype(np.float32)
+    sq[-1] = 1.0
+    if case == "revisit":
+        # n_loc < B: a whole pass, then the prefix again (the tail block),
+        # and a few rows twice in a row
+        idx = np.concatenate([rng.permutation(n), np.arange(b - n - 4),
+                              [3, 3, 17, 17]])
+    else:
+        idx = rng.permutation(n)[:b]
+    w = np.zeros(lane_pad(d + 1), np.float32)
+    w[:d] = 0.2 * rng.standard_normal(d)
+    alpha = rng.uniform(0.0, 1.0, n).astype(np.float32)
+    act = y = None
+    if case == "act_y":
+        act = jnp.asarray(rng.random(n) > 0.3)
+        y = jnp.asarray(rng.choice([-1.0, 1.0], n).astype(np.float32))
+    return (jnp.asarray(ids), jnp.asarray(vals), jnp.asarray(sq),
+            jnp.asarray(alpha), jnp.asarray(w),
+            jnp.asarray(idx.astype(np.int32)), act, y)
+
+
+@pytest.mark.parametrize("case", ["k73", "revisit", "act_y"])
+@pytest.mark.parametrize("loss", LOSSES, ids=LOSS_IDS)
+def test_streamed_block_matches_jnp_engine(loss, case):
+    """One block of the streamed kernel (interpret mode) against the jnp
+    ELL engine on the same operands: same α and Δw to atol 1e-5."""
+    cols, vals, sq, alpha, w, idx, act, y = _stream_case(case)
+    a_ref, dw_ref = _local_block_update_ell(cols, vals, sq, alpha, w, idx,
+                                            loss, act=act, y=y)
+    a_k, dw_k = dcd_ell_block_update_pallas(
+        stream_rows(cols, vals), sq, alpha, w, idx, k=cols.shape[1],
+        loss=loss, interpret=True, active=act, y=y)
+    np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_ref),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(dw_k), np.asarray(dw_ref),
+                               rtol=1e-5, atol=1e-5)
+    assert np.all(np.asarray(dw_k)[300:] == 0.0)  # dummy and lane slots
+
+
+@pytest.mark.parametrize("delay_rounds", [0, 1])
+@pytest.mark.parametrize("loss", LOSSES, ids=LOSS_IDS)
+def test_streamed_engine_small_shard(loss, delay_rounds):
+    """A shard smaller than one block (n_loc = 40 < B = 64): every round
+    revisits rows, and the solve agrees with the jnp engine."""
+    cols, vals, *_ = _stream_case("revisit")
+    X = EllMatrix(cols, vals, 300)
+    kw = dict(epochs=2, block_size=64, delay_rounds=delay_rounds,
+              record=False)
+    r_ell = sharded_passcode_solve(X, loss, **kw)
+    r_fused = sharded_passcode_solve(X, loss, use_kernel=True, **kw)
+    assert r_fused.engine == "ell/pallas-stream-interpret"
+    np.testing.assert_allclose(np.asarray(r_fused.alpha),
+                               np.asarray(r_ell.alpha), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(r_fused.w_hat),
+                               np.asarray(r_ell.w_hat), rtol=1e-5, atol=1e-5)
 
 
 def test_gap_every_subsamples_and_matches(tiny_ell, hinge):

@@ -161,7 +161,7 @@ def test_feature_vmem_policy_admits_webspam_scale():
     k_loc = -(-k // m)
     d_loc = -(-d // m)
     assert not dcd_kernel_fits(n_loc, d)
-    assert not dcd_ell_kernel_fits(n_loc, k, d)
+    assert not dcd_ell_kernel_fits(d)
     assert dcd_feature_kernel_fits(n_loc, k_loc, d_loc)
     # kddb-scale d≈29.9M needs one more doubling of the model axis
     d_kddb = 29_890_095

@@ -2,9 +2,11 @@
 attached: Mosaic refuses here what it would refuse on the chip.
 
 Each kernel family compiles at the shape ``chip_smoke.py`` runs on the
-chip and at the largest row count its VMEM policy (``dcd_*_kernel_fits``)
-admits for those widths, so the policy cannot admit a shard the
-compiler rejects.  The topology is described inside a fixture (never at
+chip and at the largest shape its VMEM policy (``dcd_*_kernel_fits``)
+admits — a row count for the VMEM-resident shards, the primal's width d
+for the ELL kernel, which streams its rows from HBM — so the policy
+cannot admit a shard the compiler rejects.  The ELL kernel also
+compiles at both benchmark cells' full shapes.  The topology is described inside a fixture (never at
 import), which keeps xdist workers collecting the same tests.
 """
 
@@ -23,7 +25,7 @@ from repro.dist.mesh import (
     lane_pad,
 )
 from repro.kernels.dcd_block import dcd_epoch_pallas_call
-from repro.kernels.dcd_ell import dcd_ell_epoch_pallas_call
+from repro.kernels.dcd_ell import dcd_ell_block_pallas_call
 from repro.kernels.dcd_feature import (
     dcd_feature_gram_pallas_call,
     dcd_feature_update_pallas_call,
@@ -33,6 +35,9 @@ B = 64  # the solver's default block size
 D_RCV1 = 47_236  # rcv1 width (paper Table 3)
 K_RCV1 = 128  # rcv1's 73 nonzeros per row, lane-padded
 DENSE_D = 1024
+# (n, k_max, d) of the paper's Table 3 problems, as the benchmark runs them
+RCV1_FULL = (677_399, 73, 47_236)
+NEWS20_FULL = (16_000, 455, 1_355_191)
 
 
 def _frontier(fits) -> int:
@@ -46,7 +51,7 @@ def _frontier(fits) -> int:
 
 
 N_DENSE = _frontier(lambda n: dcd_kernel_fits(n, DENSE_D))
-N_ELL = _frontier(lambda n: dcd_ell_kernel_fits(n, K_RCV1, D_RCV1))
+D_ELL = _frontier(dcd_ell_kernel_fits)  # the ELL policy bounds d alone
 N_FEAT = _frontier(lambda n: dcd_feature_kernel_fits(
     n, K_RCV1, D_RCV1, block_size=B))
 
@@ -91,16 +96,22 @@ def test_dcd_block_compiles(chip, n):
     assert "tpu_custom_call" in hlo
 
 
+def _ell_block(loss, k):
+    def f(c, v, a, w, q, i):
+        return dcd_ell_block_pallas_call((c, v), a, w, q, i, k=k, loss=loss)
+    return f
+
+
 @pytest.mark.parametrize("loss", [Hinge(C=1.0), Logistic(C=1.0)],
                          ids=["hinge", "logistic"])
-@pytest.mark.parametrize("n", [8192, N_ELL], ids=["smoke", "frontier"])
-def test_dcd_ell_compiles(chip, n, loss):
-    d1 = lane_pad(D_RCV1 + 1)
+@pytest.mark.parametrize("n,k,d", [RCV1_FULL, NEWS20_FULL, (8192, 73, D_ELL)],
+                         ids=["rcv1", "news20", "frontier"])
+def test_dcd_ell_compiles(chip, n, k, d, loss):
+    d1 = lane_pad(d + 1)
+    kp = lane_pad(k)
     hlo = _compile_text(
-        chip,
-        lambda c, v, a, w, q, i: dcd_ell_epoch_pallas_call(
-            c, v, a, w, q, loss=loss, idx=i, block_rows=B),
-        ((n, K_RCV1), I32), ((n, K_RCV1), F32), ((n,), F32), ((d1,), F32),
+        chip, _ell_block(loss, k),
+        ((n, 1, kp), I32), ((n, 1, kp), F32), ((n,), F32), ((d1,), F32),
         ((n,), F32), ((B,), I32))
     assert "tpu_custom_call" in hlo
 
@@ -131,13 +142,9 @@ def test_kernels_batch_over_tasks(chip):
     """The multi-task pipeline vmaps the kernels over K heads."""
     n, K = 8192, 4
     d1 = lane_pad(D_RCV1 + 1)
-
-    def ell(c, v, a, w, q, i):
-        return dcd_ell_epoch_pallas_call(c, v, a, w, q, loss=Hinge(C=1.0),
-                                         idx=i, block_rows=B)
-
+    ell = _ell_block(Hinge(C=1.0), 73)
     hlo = _compile_text(
         chip, jax.vmap(ell, in_axes=(None, None, 0, 0, None, None)),
-        ((n, K_RCV1), I32), ((n, K_RCV1), F32), ((K, n), F32),
+        ((n, 1, K_RCV1), I32), ((n, 1, K_RCV1), F32), ((K, n), F32),
         ((K, d1), F32), ((n,), F32), ((B,), I32))
     assert "tpu_custom_call" in hlo
