@@ -4,7 +4,9 @@ The inner loop maintains w(α) = Σ α_i x_i so one update costs O(nnz/n)
 (sparse) / O(d) (dense).  Index order is a random permutation per epoch
 (paper §3.3 "Random Permutation": sampling without replacement).
 
-Supports dense (n, d) arrays and ``EllMatrix``.  The dense path is the
+Supports dense (n, d) arrays, ``EllMatrix`` and ``CsrMatrix`` (ragged
+rows: the epoch runs them padded to the longest, which is fine at the
+sizes an oracle runs; the gap reads the rows unpadded).  The dense path is the
 readable reference; the ELL path is what the distributed/Pallas layers
 build on.
 """
@@ -18,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.objective import duality_gap, f32_dot, w_of_alpha
-from repro.data.sparse import EllMatrix, pad_primal, unpad_primal
+from repro.data.sparse import CsrMatrix, EllMatrix, pad_primal, unpad_primal
 
 
 class DcdState(NamedTuple):
@@ -89,6 +91,9 @@ def dcd_solve(
     record_gap: bool = True,
 ) -> DcdResult:
     """Run serial DCD for `epochs` epochs (early-stop on duality gap ≤ tol)."""
+    X_gap = X
+    if isinstance(X, CsrMatrix):
+        X = X.to_ell()
     n = X.n_rows if isinstance(X, EllMatrix) else X.shape[0]
     d = X.n_features if isinstance(X, EllMatrix) else X.shape[1]
     sq_norms = (
@@ -108,7 +113,7 @@ def dcd_solve(
         state = dcd_epoch(X, sq_norms, state, perm, loss)
         done = e + 1
         if record_gap:
-            g = float(duality_gap(state.alpha, X, loss))
+            g = float(duality_gap(state.alpha, X_gap, loss))
             gaps.append(g)
             if tol > 0 and g <= tol:
                 break

@@ -1,11 +1,12 @@
 """Primal/dual objectives, duality gap, prediction accuracy.
 
-Works on dense (n, d) data or ``EllMatrix``. Since rows are label-folded
-(x_i = y_i·ẋ_i), classification is correct iff wᵀx_i > 0, so binary
-accuracy needs no separate label vector.  The multiclass helpers
-(``predict_multiclass``/``multiclass_accuracy``) instead take a (K, d)
-one-vs-rest weight stack over *unfolded* rows and integer class ids —
-the shapes the multi-task solver path produces (DESIGN.md §16).
+Works on dense (n, d) data, ``EllMatrix`` or ``CsrMatrix``. Since rows
+are label-folded (x_i = y_i·ẋ_i), classification is correct iff
+wᵀx_i > 0, so binary accuracy needs no separate label vector.  The
+multiclass helpers (``predict_multiclass``/``multiclass_accuracy``)
+instead take a (K, d) one-vs-rest weight stack over *unfolded* rows and
+integer class ids — the shapes the multi-task solver path produces
+(DESIGN.md §16).
 """
 
 from __future__ import annotations
@@ -13,7 +14,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.data.sparse import EllMatrix, ell_matvec, ell_rmatvec
+from repro.data.sparse import (
+    CsrMatrix,
+    EllMatrix,
+    csr_matvec,
+    csr_rmatvec,
+    ell_matvec,
+    ell_rmatvec,
+)
 
 # Every f32 dot/matvec of the solvers runs at full f32 precision.  On CPU
 # that is what a dot does anyway; on TPU the default precision rounds
@@ -30,12 +38,16 @@ def f32_dot(a, b):
 def _matvec(X, w):
     if isinstance(X, EllMatrix):
         return ell_matvec(X, w)
+    if isinstance(X, CsrMatrix):
+        return csr_matvec(X, w)
     return f32_dot(X, w)
 
 
 def _rmatvec(X, alpha):
     if isinstance(X, EllMatrix):
         return ell_rmatvec(X, alpha)
+    if isinstance(X, CsrMatrix):
+        return csr_rmatvec(X, alpha)
     return f32_dot(X.T, alpha)
 
 

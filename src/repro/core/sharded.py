@@ -32,25 +32,31 @@ atomic adds), and each device scatter-adds only its own shard — no
 replicated primal exists anywhere.
 
 The per-device block of B locally-sequential updates — the hot loop —
-has six interchangeable engines, selected by the mesh (1-D vs 2-D) ×
-the type of ``X_host`` (dense array vs ``repro.data.sparse.EllMatrix``)
-× ``use_kernel`` (DESIGN.md §6, §9, §10):
+has eight interchangeable engines, selected by the mesh (1-D vs 2-D) ×
+the type of ``X_host`` (dense array, ``repro.data.sparse.EllMatrix``, or
+``CsrMatrix`` for rows of unequal length) × ``use_kernel`` (DESIGN.md
+§6, §9, §10):
 
   * ``_local_block_update`` — unfused ``fori_loop`` of dense jnp ops;
   * ``_local_block_update_ell`` — unfused ELL engine: O(k_max) gather /
     dot / dummy-slot scatter per update against a (d+1)-padded primal;
+  * ``_local_block_update_ragged`` — unfused engine over packed ragged
+    rows (``pack_ragged``): each update walks its own row, 16 slots at a
+    time, so the work follows the row's length, not the longest row's;
   * ``_local_block_update_feature`` — unfused 2-D engine: O(k_loc)
     local gather-dot, per-update psum of the partial wᵀx_i over
     ``model``, O(k_loc) scatter into this device's primal shard;
   * ``use_kernel=True`` — the fused Pallas indexed-block kernels
     (``repro.kernels.dcd_block_update_pallas`` dense,
     ``dcd_ell_block_update_pallas`` sparse,
+    ``dcd_ragged_block_update_pallas`` packed ragged rows,
     ``dcd_feature_block_update_pallas`` 2-D — the latter batches the B
     per-update psums into one (base, Gram) psum per block): updates
     gather/scatter by row id inside the kernel (interpret mode on CPU,
     compiled on TPU); the dense shard and the 2-D slice are
-    VMEM-resident, the 1-D ELL shard stays in HBM and each row is
-    streamed in by DMA.  ``"auto"`` fuses only on TPU when what the
+    VMEM-resident, the 1-D ELL and ragged shards stay in HBM and each
+    row is streamed in by DMA (a ragged row only the tiles it spans, in
+    chunks when it is long).  ``"auto"`` fuses only on TPU when what the
     kernel holds fits VMEM — ``dcd_kernel_fits`` for the dense n_loc·d̃
     shard, ``dcd_ell_kernel_fits`` for the ELL kernel's 2·d₁ primal,
     ``dcd_feature_kernel_fits`` for the ~2·n_loc·k̃_loc + 2·d/m 2-D
@@ -101,10 +107,12 @@ from jax.sharding import PartitionSpec as P
 from repro.core.objective import duality_gap, f32_dot
 from repro.core.shrinking import active_mask_from_w
 from repro.data.sparse import (
+    CsrMatrix,
     EllMatrix,
     active_row_remap,
     dense_to_ell,
     ell_column_split,
+    pack_ragged,
     pod_row_layout,
 )
 from repro.dist.compat import shard_map
@@ -115,6 +123,7 @@ from repro.dist.mesh import (
     dcd_ell_kernel_fits,
     dcd_feature_kernel_fits,
     dcd_kernel_fits,
+    dcd_ragged_kernel_fits,
     lane_pad,
     make_mesh,
     pipeline_overlap,
@@ -135,8 +144,15 @@ from repro.kernels.ops import (
     dcd_feature_block_update_pallas,
     dcd_feature_gram_pallas,
     dcd_feature_update_pallas,
+    dcd_ragged_block_update_pallas,
 )
-from repro.kernels.dcd_ell import stream_rows
+from repro.kernels.dcd_ell import (
+    CHUNK_TILES,
+    GRAIN,
+    LANES,
+    ragged_stream_rows,
+    stream_rows,
+)
 
 # The solver's layers inside the compiled epoch, as ``jax.named_scope``s:
 # each reaches the op_name of every instruction it holds, so a device
@@ -222,6 +238,51 @@ def _local_block_update_ell(cols_loc, vals_loc, sq_loc, alpha_loc, w_pad,
         return alpha_loc, w_new - w_pad  # (updated α shard, local Δw_pad)
 
 
+def _local_block_update_ragged(rows_loc, sq_loc, alpha_loc, w_pad,
+                               idx_block, loss, act=None, y=None):
+    """``_local_block_update_ell`` over packed ragged rows
+    (``pack_ragged``): ``rows_loc`` = (cols, vals, ptr, wid), the packed
+    slots and each local row's first slot and slot count.  Each update
+    walks its own row, ``GRAIN`` slots at a time, so the work follows
+    the row's length, not the longest row's."""
+    cols_loc, vals_loc, ptr_loc, wid_loc = rows_loc
+
+    def body(t, carry):
+        alpha_loc, w_loc = carry
+        i = idx_block[t]
+        p0, n_groups = ptr_loc[i], wid_loc[i] // GRAIN
+
+        def group(g):
+            at = (p0 + g * GRAIN,)
+            return (jax.lax.dynamic_slice(cols_loc, at, (GRAIN,)),
+                    jax.lax.dynamic_slice(vals_loc, at, (GRAIN,)))
+
+        def dot(g, acc):
+            c, v = group(g)
+            return acc + jnp.sum(w_loc[c] * v)
+
+        wx = jax.lax.fori_loop(0, n_groups, dot, jnp.float32(0.0))
+        if y is not None:
+            wx = y[i] * wx
+        delta = loss.delta(alpha_loc[i], wx, sq_loc[i])
+        if act is not None:
+            delta = jnp.where(act[i], delta, 0.0)
+        dscale = delta if y is None else delta * y[i]
+
+        def axpy(g, w):
+            c, v = group(g)
+            return w.at[c].add(dscale * v)
+
+        w_loc = jax.lax.fori_loop(0, n_groups, axpy, w_loc)
+        return alpha_loc.at[i].add(delta), w_loc
+
+    alpha_loc, w_new = jax.lax.fori_loop(
+        0, idx_block.shape[0], body, (alpha_loc, w_pad)
+    )
+    with jax.named_scope(SCOPE_MERGE):
+        return alpha_loc, w_new - w_pad  # (updated α shard, local Δw_pad)
+
+
 def _local_block_update_feature(cols_loc, vals_loc, sq_loc, alpha_loc,
                                 w_loc, idx_block, loss, act=None, y=None):
     """B sequential DCD updates on this device's (row-block × feature-
@@ -260,20 +321,26 @@ def _local_block_update_feature(cols_loc, vals_loc, sq_loc, alpha_loc,
 
 
 def _resolve_kernel_mode(use_kernel, n_loc: int, d: int, *,
-                         ell: bool = False, block_size: int = 64):
+                         ell: bool = False, block_size: int = 64,
+                         ragged: bool = False):
     """Resolve ``use_kernel`` ∈ {False, True, "auto"} → (fused?, interpret?).
 
     "auto" fuses only where it pays: compiled on TPU with what the
     kernel holds resident fitting VMEM — the dense row shard
     (``dcd_kernel_fits``), or for an ELL shard (``ell``) the padded
     primal alone (``dcd_ell_kernel_fits``: the rows stream from HBM, so
-    any n_loc is admitted); everywhere else the pure-jnp block update is
-    kept.  ``True`` forces the kernel — in interpret mode off-TPU, which
-    validates semantics rather than speed.
+    any n_loc is admitted), for packed ragged rows (``ragged``) that and
+    the fixed SMEM row buffer (``dcd_ragged_kernel_fits``); everywhere
+    else the pure-jnp block update is kept.  ``True`` forces the kernel
+    — in interpret mode off-TPU, which validates semantics rather than
+    speed.
     """
     on_tpu = jax.default_backend() == "tpu"
     if use_kernel == "auto":
-        if ell:
+        if ragged:
+            use_kernel = on_tpu and dcd_ragged_kernel_fits(
+                d, CHUNK_TILES, block_size=block_size)
+        elif ell:
             use_kernel = on_tpu and dcd_ell_kernel_fits(
                 d, block_size=block_size)
         else:
@@ -639,6 +706,36 @@ def _make_gap_1d(loss, X_loc, ell: bool, axes=("data",)):
         def mv(wa):
             return f32_dot(X_loc, wa)
 
+    return _gap_1d_from(loss, rmv, mv, axes)
+
+
+def _make_gap_ragged(loss, X_loc, axes=("data",)):
+    """``_make_gap_1d`` over packed ragged rows (X_loc = (cols, vals,
+    gseg, ptr, wid), ``pack_ragged``): both products read the packed
+    slots once — w(α) scatters the α of each slot's row, and the margins
+    sum each group of ``GRAIN`` slots and add the groups into their rows
+    (``gseg``, sorted) — so the work follows the true nonzeros, not the
+    longest row."""
+    cols_loc, vals_loc, gseg_loc, ptr_loc, _ = X_loc
+    n_loc = ptr_loc.shape[0]
+
+    def rmv(am, d_run):
+        contrib = am[gseg_loc][:, None] * vals_loc.reshape(-1, GRAIN)
+        return jnp.zeros((d_run,), jnp.float32).at[cols_loc].add(
+            contrib.reshape(-1))
+
+    def mv(wa):
+        part = jnp.sum((wa[cols_loc] * vals_loc).reshape(-1, GRAIN), axis=1)
+        return jax.ops.segment_sum(part, gseg_loc, n_loc,
+                                   indices_are_sorted=True)
+
+    return _gap_1d_from(loss, rmv, mv, axes)
+
+
+def _gap_1d_from(loss, rmv, mv, axes):
+    """The 1-D gap and backward error over a shard's products ``rmv``
+    (Xᵀa into a d_run-word primal) and ``mv`` (X·w)."""
+
     def gap(rec, alpha_loc, mask, d_run, w_view, y=None):
         am = jnp.where(mask, alpha_loc, 0.0)
 
@@ -751,18 +848,28 @@ def _make_shrink_2d(loss, cols_loc, vals_loc, shrink_tol: float, valid):
 # ------------------------------------------------------ epoch builders ----
 
 
-def _block_update_1d(loss, use_kernel: bool, interpret: bool, ell: bool):
+def _block_update_1d(loss, use_kernel: bool, interpret: bool, ell: bool,
+                     ragged: bool = False):
     """The per-device block engine for a 1-D mesh, shared by the
     per-epoch and pipelined builders: ``(view, block_update)``.
     ``view(X_loc)`` is what the engine reads, made once per dispatch
     outside the round loop — the fused ELL kernel streams each row from
     a lane-aligned copy of the shard (``stream_rows``) and walks the
-    shard's own k_max slots; every other engine reads X as placed.
+    shard's own k_max slots; the ragged kernel views the packed slots
+    as lane tiles (``ragged_stream_rows``) and walks each row's own
+    slots; every other engine reads X as placed.
     ``act`` (optional (n_loc,) mask) freezes shrunk coordinates —
     forwarded to the fused kernels as the f32 active operand, to the jnp
     engines as the bool gate."""
 
     def view(X_loc):
+        if ragged:
+            cols_loc, vals_loc, _, ptr_loc, wid_loc = X_loc
+            if not use_kernel:
+                return cols_loc, vals_loc, ptr_loc, wid_loc
+            with jax.named_scope(SCOPE_UPDATE):
+                return (ragged_stream_rows(cols_loc, vals_loc), ptr_loc,
+                        wid_loc)
         if not (ell and use_kernel):
             return X_loc
         cols_loc, vals_loc = X_loc
@@ -771,6 +878,17 @@ def _block_update_1d(loss, use_kernel: bool, interpret: bool, ell: bool):
 
     def block_update(X_eng, sq_loc, alpha_loc, w_eff, idx_block,
                      act=None, y=None):
+        if ragged and use_kernel:
+            rows, ptr_loc, wid_loc = X_eng
+            return dcd_ragged_block_update_pallas(
+                rows, ptr_loc, wid_loc, sq_loc, alpha_loc, w_eff,
+                idx_block, loss=loss, interpret=interpret, active=act, y=y,
+            )
+        if ragged:
+            return _local_block_update_ragged(
+                X_eng, sq_loc, alpha_loc, w_eff, idx_block, loss, act=act,
+                y=y,
+            )
         if ell and use_kernel:
             rows, k = X_eng
             return dcd_ell_block_update_pallas(
@@ -1317,6 +1435,7 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
                           block_size: int, n_blocks: int, n_rows: int,
                           delay_rounds: int = 0, use_kernel: bool = False,
                           interpret: bool | None = None, ell: bool = False,
+                          ragged: bool = False,
                           record: bool = True, gap_every: int = 1,
                           shrink_every: int = 0, shrink_tol: float = 1e-3,
                           repack_threshold: float | None = None,
@@ -1367,6 +1486,10 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
     pod_merge_policy`` before calling; ``adaptive`` then latches the
     *pod* FIFO, not the inner delayed psum).
 
+    ``ragged`` takes X as the five row-sharded arrays of packed ragged
+    rows (``pack_ragged``: cols, vals, gseg, ptr, wid) in place of the
+    ELL pair; the engine and the gap then read each row's own slots.
+
     Returns ``fn(X, sq_norms, alpha, w, key, carry_dw) → (alpha, w,
     carry_dw, gaps, eps, active, delay)``; with ``delay_rounds > 0`` (or
     any self-tuning mode, or ``pod_delay_rounds > 0``) the caller
@@ -1397,8 +1520,10 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
     dyn = (shrink_on or adaptive) and not pod_on
     fault = _check_pipeline_chaos(record=record, watchdog=watchdog,
                                   fault=fault, pod_on=pod_on)
-    view, block_update = _block_update_1d(loss, use_kernel, interpret, ell)
-    x_spec = (P(row_ax), P(row_ax)) if ell else P(row_ax)
+    view, block_update = _block_update_1d(loss, use_kernel, interpret, ell,
+                                          ragged)
+    x_spec = ((P(row_ax),) * 5 if ragged
+              else (P(row_ax), P(row_ax)) if ell else P(row_ax))
     delay0 = int(pod_delay_rounds > 0) if pod_on else delay_rounds
     pod_fifo = pod_delay_rounds if (pod_on and pod_delay_rounds > 0) else 0
 
@@ -1436,7 +1561,9 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
         # unbatched (conds stay conds under the vmap).
         def run_task(carry, y):
             if record:
-                gap_fn = _make_gap_1d(loss, X_loc, ell, axes=gap_axes)
+                gap_fn = (_make_gap_ragged(loss, X_loc, axes=gap_axes)
+                          if ragged else
+                          _make_gap_1d(loss, X_loc, ell, axes=gap_axes))
                 gap = lambda rec, a, wv: gap_fn(rec, a, valid, d_run,
                                                 wv, y)
             else:
@@ -1839,6 +1966,19 @@ def _drive_epochs(epoch_fn, X, sq_norms, alpha, w, carry_dw, *, p, n_loc,
     return alpha, w, gaps_arr
 
 
+class RowLayout(NamedTuple):
+    """Counters of packed ragged rows (``pack_ragged``), set once at
+    ``prepare_solver``: the true nonzeros, the slots one pass over every
+    row walks (each row rounded up to ``GRAIN``), the distinct row
+    widths, and the rows too long for one SMEM chunk of the streamed
+    kernel (streamed in chunks)."""
+
+    nnz: int
+    slots_walked: int
+    buckets: int
+    chunked_rows: int
+
+
 class SolverSetup(NamedTuple):
     """The resolved-and-placed half of a solve (DESIGN.md §14): mesh +
     admission policies + the padded, device-resident dataset — i.e.
@@ -1882,6 +2022,8 @@ class SolverSetup(NamedTuple):
     seed: int
     n_tasks: int = 0     # multi-task K (0 = binary, DESIGN.md §16)
     Y: object = None     # placed (K, n_pad) ±1 label matrix (None = binary)
+    ragged: bool = False  # X is packed ragged rows (a CsrMatrix input)
+    layout: object = None  # RowLayout of the packed ragged rows
 
 
 def _place_labels(mesh, y, *, n, n_pad, ridx, pod_on):
@@ -1899,6 +2041,47 @@ def _place_labels(mesh, y, *, n, n_pad, ridx, pod_on):
         Yp = jnp.ones((K, n_pad), jnp.float32).at[:, :n].set(Y)
     tax = "task" if "task" in mesh.axis_names else None
     return K, jax.device_put(Yp, named(mesh, tax, data_axes(mesh)))
+
+
+def _refuse_ragged(mesh, *, y, shrink_every, pipeline):
+    """A ``CsrMatrix`` runs the pipelined 1-D solve only: the other
+    paths take fixed-width rows, and padding heavy-tailed rows to the
+    longest is what the ragged layout exists to avoid."""
+    why = None
+    if "model" in mesh.axis_names:
+        why = "the 2-D feature split (a 'model' mesh axis)"
+    elif "pod" in mesh.axis_names:
+        why = "the pod solver (a 'pod' mesh axis)"
+    elif y is not None or "task" in mesh.axis_names:
+        why = "the task axis (a (K, n) label matrix)"
+    elif shrink_every:
+        why = "shrinking and its repack (shrink_every > 0)"
+    elif not pipeline:
+        why = "the host-driven epoch loop (pipeline=False)"
+    if why is not None:
+        raise ValueError(
+            f"ragged rows (CsrMatrix) are not supported by {why}; they "
+            f"run on the pipelined 1-D 'data' mesh only, and are never "
+            f"padded to the longest row for another path")
+
+
+def _pack_ragged_1d(mesh, X_host: CsrMatrix, *, n_loc: int, n_pad: int):
+    """Pack ragged rows for a 1-D mesh (host, ``passcode.pack``) and place
+    them: X = (cols, vals, gseg, ptr, wid), each sharded over ``data``;
+    also the padded ‖x‖² (host) and the ``RowLayout`` counters."""
+    p = mesh.shape["data"]
+    with jax.profiler.TraceAnnotation("passcode.pack"):
+        packed = pack_ragged(X_host, p, n_loc, grain=GRAIN)
+        sq = _pad_host(X_host.row_sq_norms(), (n_pad,), 1.0, np.float32)
+        tiles = -(-(packed.ptr % LANES + packed.wid) // LANES)
+        layout = RowLayout(
+            nnz=packed.nnz, slots_walked=packed.slots,
+            buckets=int(np.unique(packed.wid[packed.wid > 0]).size),
+            chunked_rows=int(np.sum(tiles > CHUNK_TILES)))
+    data_sh = named(mesh, data_axes(mesh))
+    X = tuple(jax.device_put(a, data_sh) for a in (
+        packed.cols, packed.vals, packed.gseg, packed.ptr, packed.wid))
+    return X, sq, layout
 
 
 def _pad_host(a, shape, fill, dtype, rowmap=None):
@@ -1983,6 +2166,10 @@ def prepare_solver(
         raise ValueError(
             "a 'task' mesh axis needs a (K, n) label matrix y "
             "(DESIGN.md §16)")
+    ragged = isinstance(X_host, CsrMatrix)
+    if ragged:
+        _refuse_ragged(mesh, y=y, shrink_every=shrink_every,
+                       pipeline=pipeline)
     two_d = "model" in mesh.axis_names
     gap_every = max(int(gap_every), 1)
     p = mesh.shape["data"]
@@ -2055,6 +2242,8 @@ def prepare_solver(
     is_ell = isinstance(X_host, EllMatrix)
     if is_ell:
         n, d, k_max = X_host.n_rows, X_host.n_features, X_host.k_max
+    elif ragged:
+        n, d = X_host.n_rows, X_host.n_features
     else:
         n, d = X_host.shape
     # ceil twice on a pod mesh: each pod's contiguous row shard carries
@@ -2068,7 +2257,8 @@ def prepare_solver(
         rows = rowmap.reshape(-1)  # global id, n = padding
         ridx = jnp.asarray(rows)
     use_k, interpret = _resolve_kernel_mode(use_kernel, n_loc, d, ell=is_ell,
-                                            block_size=block_size)
+                                            block_size=block_size,
+                                            ragged=ragged)
     # a 1-D mesh has no model-axis psum: "auto" resolves to no overlap,
     # an explicit True is an error
     pipeline_overlap(overlap, two_d=False, fused=use_k,
@@ -2076,7 +2266,15 @@ def prepare_solver(
     tuning = resolve_self_tuning(shrink_every, repack, adaptive,
                                  overlap_knob=overlap, overlap_on=False,
                                  pipeline=pipeline, record=record)
-    if is_ell:
+    layout = None
+    if ragged:
+        # the packed slots, padded only to the walk's granule; the primal
+        # as on the ELL path (dummy slot at d, lane-padded when fused)
+        X_gap = X_host
+        d_run = lane_pad(d + 1) if use_k else d + 1
+        X, sq_norms, layout = _pack_ragged_1d(mesh, X_host, n_loc=n_loc,
+                                              n_pad=n_pad)
+    elif is_ell:
         X_gap = X_host  # duality gap always reads the unpadded data
         # the shard keeps its own width k_max on every engine (the fused
         # kernel lane-aligns a copy per dispatch, ``stream_rows``); pad
@@ -2129,7 +2327,7 @@ def prepare_solver(
         gap_every=gap_every, record=record, tuning=tuning,
         shrink_tol=shrink_tol, repack_threshold=repack_threshold,
         adaptive_ratio=adaptive_ratio, seed=seed,
-        n_tasks=n_tasks, Y=Y)
+        n_tasks=n_tasks, Y=Y, ragged=ragged, layout=layout)
 
 
 def _init_alpha_w(setup: SolverSetup, alpha0=None, w0=None):
@@ -2230,7 +2428,7 @@ def build_pipeline(setup: SolverSetup, *, epochs: int,
         return make_sharded_pipeline_2d(setup.mesh, setup.loss,
                                         overlap=ov, **common)
     return make_sharded_pipeline(setup.mesh, setup.loss, ell=setup.ell,
-                                 **common)
+                                 ragged=setup.ragged, **common)
 
 
 @functools.partial(jax.profiler.annotate_function,
@@ -2337,13 +2535,15 @@ def finalize_state(setup: SolverSetup, state: dict,
 
 def engine_name(setup: SolverSetup) -> str:
     """``<layout>/<engine>`` of a prepared solve: layout ``dense``,
-    ``ell`` or ``feature`` (2-D), engine ``jnp`` or ``pallas`` with its
-    mode — ``compiled`` on TPU, ``interpret`` elsewhere; the 1-D ELL
-    kernel, which streams its rows from HBM, is ``pallas-stream``."""
-    layout = "feature" if setup.two_d else ("ell" if setup.ell else "dense")
+    ``ell``, ``ragged`` (packed ragged rows) or ``feature`` (2-D), engine
+    ``jnp`` or ``pallas`` with its mode — ``compiled`` on TPU,
+    ``interpret`` elsewhere; the 1-D ELL and ragged kernels, which
+    stream their rows from HBM, are ``pallas-stream``."""
+    layout = ("feature" if setup.two_d else "ragged" if setup.ragged
+              else "ell" if setup.ell else "dense")
     if not setup.use_k:
         return f"{layout}/jnp"
-    kernel = "pallas-stream" if layout == "ell" else "pallas"
+    kernel = "pallas-stream" if layout in ("ell", "ragged") else "pallas"
     mode = "interpret" if setup.interpret else "compiled"
     return f"{layout}/{kernel}-{mode}"
 
@@ -2397,13 +2597,14 @@ def _validate_solver_inputs(X_host, y, loss):
     C = getattr(loss, "C", None)
     if C is not None and not float(C) > 0:
         raise ValueError(f"loss.C must be positive, got {C!r}")
-    vals = X_host.values if isinstance(X_host, EllMatrix) else X_host
+    vals = (X_host.values if isinstance(X_host, (EllMatrix, CsrMatrix))
+            else X_host)
     if not np.all(np.isfinite(np.asarray(vals))):
         raise ValueError("X contains non-finite entries (NaN/Inf)")
     if y is None:
         return X_host
     y = np.asarray(jax.device_get(y), np.float32).reshape(-1)
-    n = (X_host.n_rows if isinstance(X_host, EllMatrix)
+    n = (X_host.n_rows if isinstance(X_host, (EllMatrix, CsrMatrix))
          else X_host.shape[0])
     if y.shape[0] != n:
         raise ValueError(f"y has {y.shape[0]} labels for {n} rows")
@@ -2417,6 +2618,9 @@ def _validate_solver_inputs(X_host, y, loss):
         return EllMatrix(X_host.indices,
                          np.asarray(X_host.values) * y[:, None],
                          X_host.n_features)
+    if isinstance(X_host, CsrMatrix):
+        return X_host._replace(values=np.asarray(X_host.values, np.float32)
+                               * y[X_host.row_of_entry()])
     return np.asarray(X_host) * y[:, None]
 
 
@@ -2431,7 +2635,7 @@ def _validate_multitask_labels(X_host, Y):
         raise ValueError(
             f"multi-task labels must be a (K, n) matrix, got shape "
             f"{Y.shape}")
-    n = (X_host.n_rows if isinstance(X_host, EllMatrix)
+    n = (X_host.n_rows if isinstance(X_host, (EllMatrix, CsrMatrix))
          else X_host.shape[0])
     if Y.shape[1] != n:
         raise ValueError(
@@ -2471,10 +2675,13 @@ def sharded_passcode_solve(
     adaptive: bool = False,
     adaptive_ratio: float = 0.95,
 ) -> ShardedResult:
-    """Distributed PASSCoDe-Atomic.  ``X_host``: dense (n, d) array or an
+    """Distributed PASSCoDe-Atomic.  ``X_host``: dense (n, d) array, an
     ``EllMatrix`` (the sparse fast path — per-update work drops from
-    O(d) to O(k_max)); rows are sharded across the mesh's ``data`` axis,
-    padded to p-divisibility with masked zero rows (never dropped).
+    O(d) to O(k_max)) or a ``CsrMatrix`` (rows of unequal length, packed
+    to each row's own length: per-update work O(nnz_i); pipelined 1-D
+    mesh only, DESIGN.md §9); rows are sharded across the mesh's
+    ``data`` axis, padded to p-divisibility with masked zero rows (never
+    dropped).
 
     ``mesh_axes=("data", "model")`` (or passing a mesh that carries a
     ``model`` axis) selects the 2-D feature-sharded engine for

@@ -7,6 +7,12 @@ padded to ``k_max`` nonzeros.  Padding entries use ``index == n_features``
 scratch vector so padded scatter-adds land in a dummy slot and padded
 gathers multiply by zero.  This is also the layout the Pallas DCD kernel
 tiles into VMEM (see ``repro/kernels/dcd_block.py``).
+
+Rows whose lengths are heavy-tailed (text corpora) come as a
+``CsrMatrix`` instead: no padding, and the 1-D solver packs each device's
+rows end to end, each padded only to the walk's granule
+(``pack_ragged``).  The paths that take fixed-width rows alone refuse a
+``CsrMatrix`` (``refuse_ragged``) rather than pad it.
 """
 
 from __future__ import annotations
@@ -74,12 +80,208 @@ def dense_to_ell(dense, k_max: int | None = None) -> EllMatrix:
     return EllMatrix(jnp.asarray(indices), jnp.asarray(values), d)
 
 
+# --------------------------------------------------- ragged rows (CSR) --
+
+
+class CsrMatrix(NamedTuple):
+    """Rows of unequal length in compressed sparse row form, label-folded
+    (x_i = y_i·ẋ_i) like ``EllMatrix`` but with no padding: row i holds
+    ``indices[indptr[i]:indptr[i+1]]`` and the values beside them.
+
+    Text corpora have heavy-tailed row lengths, so padding every row to
+    the longest one (ELL) stores and walks many times the true nonzeros;
+    the 1-D solver packs a ``CsrMatrix`` into its own layout instead
+    (``pack_ragged``).
+
+    Attributes:
+        indices: (nnz,) int32 column ids in [0, n_features).
+        values:  (nnz,) float32.
+        indptr:  (n_rows + 1,) row offsets, indptr[0] == 0.
+        n_features: static int, true feature dimension d.
+    """
+
+    indices: np.ndarray
+    values: np.ndarray
+    indptr: np.ndarray
+    n_features: int
+
+    @property
+    def n_rows(self) -> int:
+        return len(self.indptr) - 1
+
+    @property
+    def nnz(self) -> int:
+        return int(np.asarray(self.indptr)[-1])
+
+    def row_lengths(self) -> np.ndarray:
+        return np.diff(np.asarray(self.indptr))
+
+    def row_of_entry(self) -> np.ndarray:
+        """(nnz,) int32 the row each stored entry belongs to."""
+        return np.repeat(np.arange(self.n_rows, dtype=np.int32),
+                         self.row_lengths())
+
+    def row_sq_norms(self) -> np.ndarray:
+        """‖x_i‖² for every row, float32 (host)."""
+        v = np.asarray(self.values, np.float32)
+        out = np.zeros(self.n_rows, np.float32)
+        np.add.at(out, self.row_of_entry(), v * v)
+        return out
+
+    def to_ell(self, k_max: int | None = None) -> EllMatrix:
+        """The same rows padded to ``k_max`` (default: the longest row,
+        ≥ 1) — an ``EllMatrix`` for the paths that take only fixed-width
+        rows, at test sizes; a smaller ``k_max`` raises."""
+        lens = self.row_lengths()
+        need = max(int(lens.max()) if self.n_rows else 0, 1)
+        if k_max is None:
+            k_max = need
+        elif k_max < need:
+            raise ValueError(f"k_max={k_max} < max per-row nnz {need}")
+        d, n = self.n_features, self.n_rows
+        indices = np.full((n, k_max), d, np.int32)
+        values = np.zeros((n, k_max), np.float32)
+        row = self.row_of_entry()
+        col = np.arange(self.nnz) - np.asarray(self.indptr)[:-1][row]
+        indices[row, col] = np.asarray(self.indices)
+        values[row, col] = np.asarray(self.values)
+        return EllMatrix(jnp.asarray(indices), jnp.asarray(values), d)
+
+    def to_dense(self) -> np.ndarray:
+        dense = np.zeros((self.n_rows, self.n_features), np.float32)
+        np.add.at(dense, (self.row_of_entry(), np.asarray(self.indices)),
+                  np.asarray(self.values, np.float32))
+        return dense
+
+
+def csr_from_rows(rows, d: int) -> CsrMatrix:
+    """Pack ``[(cols, vals), ...]`` into a ``CsrMatrix`` (host-side); ids
+    must lie in [0, d)."""
+    d = int(d)
+    cols, vals, lens = [], [], []
+    for i, (c, v) in enumerate(rows):
+        c = np.asarray(c, np.int64).reshape(-1)
+        v = np.asarray(v, np.float32).reshape(-1)
+        if c.shape[0] != v.shape[0]:
+            raise ValueError(
+                f"row {i}: {c.shape[0]} ids vs {v.shape[0]} values")
+        if c.size and (c.min() < 0 or c.max() >= d):
+            raise ValueError(f"row {i}: column id out of range [0, {d})")
+        cols.append(c)
+        vals.append(v)
+        lens.append(c.shape[0])
+    indptr = np.concatenate([[0], np.cumsum(lens, dtype=np.int64)])
+    return CsrMatrix(
+        np.concatenate(cols).astype(np.int32) if cols
+        else np.zeros(0, np.int32),
+        np.concatenate(vals) if vals else np.zeros(0, np.float32),
+        indptr.astype(np.int32), d)
+
+
+def csr_matvec(mat: CsrMatrix, w: jnp.ndarray) -> jnp.ndarray:
+    """X @ w for a (d,) vector, O(nnz). Returns (n_rows,)."""
+    row = jnp.asarray(mat.row_of_entry())
+    prod = w[jnp.asarray(mat.indices)] * jnp.asarray(mat.values)
+    return jnp.zeros((mat.n_rows,), prod.dtype).at[row].add(prod)
+
+
+def csr_rmatvec(mat: CsrMatrix, alpha: jnp.ndarray) -> jnp.ndarray:
+    """Xᵀ @ alpha, O(nnz). Returns (d,)."""
+    row = jnp.asarray(mat.row_of_entry())
+    vals = jnp.asarray(mat.values)
+    return jnp.zeros((mat.n_features,), vals.dtype).at[
+        jnp.asarray(mat.indices)].add(alpha[row] * vals)
+
+
+class PackedRows(NamedTuple):
+    """A ``CsrMatrix`` as the 1-D solver holds it on a ``data`` mesh of
+    ``n_shards`` devices (host numpy, before placement).  Device j owns
+    rows [j·n_loc, (j+1)·n_loc); its rows sit end to end in a segment of
+    ``s_loc`` slots, each row padded to a multiple of ``grain`` slots
+    (id d, value 0) and the segment to a multiple of ``lanes``, so that
+    every device's segment has the same length (SPMD) and a walk of
+    ``grain`` slots never crosses into another row.
+
+    Attributes:
+        cols: (n_shards·s_loc,) int32 packed column ids; padding == d.
+        vals: (n_shards·s_loc,) float32 packed values; padding == 0.
+        gseg: (n_shards·s_loc/grain,) int32 the shard-local row of each
+            group of ``grain`` slots (the segment's tail: its last row),
+            non-decreasing within a segment.
+        ptr:  (n_shards·n_loc,) int32 each row's first slot in its
+            segment.
+        wid:  (n_shards·n_loc,) int32 each row's slots, a multiple of
+            ``grain`` (0 for an empty or padding row).
+        nnz:  true nonzeros.
+    """
+
+    cols: np.ndarray
+    vals: np.ndarray
+    gseg: np.ndarray
+    ptr: np.ndarray
+    wid: np.ndarray
+    nnz: int
+
+    @property
+    def slots(self) -> int:
+        """Slots the rows span: what a pass over every row walks."""
+        return int(self.wid.sum())
+
+
+def pack_ragged(mat: CsrMatrix, n_shards: int, n_loc: int, *,
+                grain: int = 16, lanes: int = 128) -> PackedRows:
+    """Pack ``mat`` into ``PackedRows`` for ``n_shards`` devices of
+    ``n_loc`` rows each (n_shards·n_loc ≥ n_rows; the rows past n_rows
+    are empty), host-side and vectorised: each entry is copied once."""
+    n, d = mat.n_rows, mat.n_features
+    n_pad = int(n_shards) * int(n_loc)
+    if n_pad < n:
+        raise ValueError(f"{n_shards} shards of {n_loc} rows < {n} rows")
+    lens = np.zeros(n_pad, np.int64)
+    lens[:n] = mat.row_lengths()
+    wid = -(-lens // grain) * grain
+    per = wid.reshape(n_shards, n_loc)
+    ptr = np.cumsum(per, axis=1) - per  # exclusive, per segment
+    seg_len = per.sum(axis=1)
+    s_loc = max(-(-int(seg_len.max()) // lanes) * lanes, lanes)
+    start = (ptr + np.arange(n_shards)[:, None] * s_loc).reshape(-1)
+    indptr = np.asarray(mat.indptr, np.int64)
+    nnz = int(indptr[-1])
+    dest = np.arange(nnz, dtype=np.int64) + np.repeat(
+        start[:n] - indptr[:-1], lens[:n])
+    cols = np.full(n_shards * s_loc, d, np.int32)
+    vals = np.zeros(n_shards * s_loc, np.float32)
+    cols[dest] = np.asarray(mat.indices)
+    vals[dest] = np.asarray(mat.values)
+    groups = s_loc // grain
+    gseg = np.empty((n_shards, groups), np.int32)
+    for j in range(n_shards):
+        g = np.repeat(np.arange(n_loc, dtype=np.int32), per[j] // grain)
+        gseg[j, :g.size] = g
+        gseg[j, g.size:] = n_loc - 1
+    return PackedRows(cols, vals, gseg.reshape(-1),
+                      ptr.reshape(-1).astype(np.int32),
+                      wid.astype(np.int32), nnz)
+
+
 # ---------------------------------------------- streaming row append ---
 
 
 def ell_row_nnz(mat: EllMatrix) -> np.ndarray:
     """Per-row count of real (non-padding) entries, host numpy."""
     return (np.asarray(mat.indices) < mat.n_features).sum(axis=1)
+
+
+def refuse_ragged(mat, what: str) -> None:
+    """Raise if ``mat`` is a ``CsrMatrix``: ``what`` takes fixed-width
+    ``EllMatrix`` rows only, and padding heavy-tailed rows to the
+    longest one is what the ragged layout exists to avoid."""
+    if isinstance(mat, CsrMatrix):
+        raise TypeError(
+            f"{what} takes fixed-width EllMatrix rows, not a CsrMatrix of "
+            f"ragged rows; only the 1-D solve path (prepare_solver on a "
+            f"'data' mesh) packs ragged rows without padding them to the "
+            f"longest")
 
 
 def ell_repack(mat: EllMatrix, k_max: int) -> EllMatrix:
@@ -92,6 +294,7 @@ def ell_repack(mat: EllMatrix, k_max: int) -> EllMatrix:
     ``dense_to_ell``, shrinking below a row's nonzero count raises —
     truncation would silently corrupt X.
     """
+    refuse_ragged(mat, "ell_repack")
     idx = np.asarray(mat.indices)
     val = np.asarray(mat.values)
     n, k = idx.shape
@@ -124,6 +327,8 @@ def ell_append(mat: EllMatrix, rows: EllMatrix,
     ``max(mat.k_max, rows.k_max)`` — never lossy; forcing it smaller
     raises inside ``ell_repack`` if any row would truncate.
     """
+    refuse_ragged(mat, "ell_append")
+    refuse_ragged(rows, "ell_append")
     if rows.n_features != mat.n_features:
         raise ValueError(
             f"n_features mismatch: have {mat.n_features}, "

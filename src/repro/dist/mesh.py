@@ -228,6 +228,9 @@ def dp_size(mesh) -> int:
 # than in the kernel package.  DESIGN.md §6.
 
 VMEM_BYTES = 16 * 2**20  # per-TensorCore VMEM (v4/v5-class parts)
+# per-TensorCore scalar memory: a described-v5e compile of the ragged
+# ELL kernel refuses a row buffer past 1 MiB of SMEM in all
+SMEM_BYTES = 2**20
 
 
 def lane_pad(d: int, lanes: int = 128) -> int:
@@ -298,6 +301,31 @@ def dcd_ell_kernel_fits(d: int, *, vmem_bytes: int = VMEM_BYTES,
     return dcd_ell_kernel_vmem_bytes(d, block_size=block_size) <= (
         headroom * vmem_bytes)
 
+
+def dcd_ragged_kernel_smem_bytes(chunk_tiles: int, *, block_size: int = 64,
+                                 itemsize: int = 4) -> int:
+    """SMEM of the fused kernel for packed ragged rows: two slots of
+    ``chunk_tiles`` lane tiles of ids and of values (the row buffer,
+    fixed whatever the rows' lengths, since a longer row streams in
+    chunks) and the block's per-step first tile, offset, width and
+    source step (4·B words)."""
+    return itemsize * (2 * 2 * chunk_tiles * 128 + 4 * block_size)
+
+
+def dcd_ragged_kernel_fits(d: int, chunk_tiles: int, *,
+                           block_size: int = 64,
+                           vmem_bytes: int = VMEM_BYTES,
+                           smem_bytes: int = SMEM_BYTES,
+                           headroom: float = 0.9) -> bool:
+    """Admission of the ragged kernel: the ELL kernel's VMEM policy (the
+    primal and the per-step columns; the rows stream from HBM, so
+    neither n_loc nor any row length enters) and its fixed SMEM row
+    buffer."""
+    return (dcd_ell_kernel_fits(d, vmem_bytes=vmem_bytes,
+                                headroom=headroom, block_size=block_size)
+            and dcd_ragged_kernel_smem_bytes(
+                chunk_tiles, block_size=block_size)
+            <= headroom * smem_bytes)
 
 def dcd_feature_kernel_vmem_bytes(n_loc: int, k_loc: int, d_loc: int, *,
                                   block_size: int = 256,

@@ -11,7 +11,9 @@ carries w across blocks, so a whole epoch is ONE pallas_call.
                  modes, pl.pallas_call + BlockSpec)
   dcd_ell.py   — the sparse (ELL) indexed kernel: O(k_max) gather /
                  dummy-slot scatter per update, each row streamed from
-                 HBM by DMA against a VMEM-resident primal (DESIGN.md §9)
+                 HBM by DMA against a VMEM-resident primal (DESIGN.md §9),
+                 and its sibling for packed ragged rows, which DMAs and
+                 walks each row's own length, long rows in chunks
   dcd_feature.py — the 2D (data × model) feature-sharded block kernels:
                  per-shard partial (base, Gram) + δ-recursion/scatter
                  against a d₁_loc-word primal *shard*, one psum per
@@ -36,6 +38,7 @@ from repro.kernels.ops import (
     dcd_feature_block_update_pallas,
     dcd_feature_gram_pallas,
     dcd_feature_update_pallas,
+    dcd_ragged_block_update_pallas,
 )
 from repro.kernels.ref import dcd_epoch_ref
 
@@ -48,4 +51,5 @@ __all__ = [
     "dcd_feature_block_update_pallas",
     "dcd_feature_gram_pallas",
     "dcd_feature_update_pallas",
+    "dcd_ragged_block_update_pallas",
 ]

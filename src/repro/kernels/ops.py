@@ -11,7 +11,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.dcd_block import dcd_epoch_pallas_call
-from repro.kernels.dcd_ell import dcd_ell_block_pallas_call
+from repro.kernels.dcd_ell import (
+    dcd_ell_block_pallas_call,
+    dcd_ragged_block_pallas_call,
+)
 from repro.kernels.dcd_feature import (
     dcd_feature_gram_pallas_call,
     dcd_feature_update_pallas_call,
@@ -165,6 +168,22 @@ def dcd_ell_block_update_pallas(rows, sq_norms, alpha, w_pad, idx, *, k,
     with jax.named_scope("passcode.merge"):
         return a_new, w_new - w_pad
 
+
+
+def dcd_ragged_block_update_pallas(rows, ptr, wid, sq_norms, alpha, w_pad,
+                                   idx, *, loss, interpret: bool = False,
+                                   active=None, y=None):
+    """``dcd_ell_block_update_pallas`` for packed ragged rows
+    (``repro.data.sparse.pack_ragged``): ``rows`` is the packed shard as
+    ``ragged_stream_rows`` views it, ``ptr``/``wid`` each local row's
+    first slot and slot count.  Each update DMAs and walks only its own
+    row.  Returns (updated α shard, local Δw_pad)."""
+    a_new, w_new = dcd_ragged_block_pallas_call(
+        rows, ptr, wid, alpha, w_pad, sq_norms, idx, loss=loss,
+        interpret=interpret, active=active, y=y,
+    )
+    with jax.named_scope("passcode.merge"):
+        return a_new, w_new - w_pad
 
 # ------------------- split-phase 2D (data × model) block entry points ----
 # The fused feature-sharded block round is two Pallas kernels bracketing

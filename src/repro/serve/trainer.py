@@ -28,7 +28,7 @@ from typing import Optional
 import numpy as np
 
 from repro.data.labels import ovr_labels
-from repro.data.sparse import EllMatrix, ell_append
+from repro.data.sparse import EllMatrix, ell_append, refuse_ragged
 from repro.dist.mesh import drift_trip
 from repro.resilience import FaultPlan, SolverDiverged, solve_segmented
 
@@ -95,6 +95,7 @@ class IncrementalTrainer:
                  backoff_s: float = 0.05,
                  fault_plan: Optional[FaultPlan] = None,
                  solver_kwargs: Optional[dict] = None):
+        refuse_ragged(X0, "IncrementalTrainer (ServeEngine's re-solves)")
         self.X = X0
         self.loss = loss
         self.n_classes = int(n_classes)
@@ -139,6 +140,7 @@ class IncrementalTrainer:
         label-folded.  Multiclass: rows stay raw and the integer ids
         buffer alongside (folding happens on read inside the solver).
         Returns the pending count."""
+        refuse_ragged(rows, "IncrementalTrainer.add_labeled")
         if rows.n_features != self.X.n_features:
             raise ValueError(
                 f"n_features mismatch: have {self.X.n_features}, "

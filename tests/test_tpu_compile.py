@@ -148,3 +148,41 @@ def test_kernels_batch_over_tasks(chip):
         ((n, 1, K_RCV1), I32), ((n, 1, K_RCV1), F32), ((K, n), F32),
         ((K, d1), F32), ((n,), F32), ((B,), I32))
     assert "tpu_custom_call" in hlo
+
+
+# rcv1-tail (bench/configs/rcv1-tail.json): rcv1's 677,399 rows with
+# log-normal lengths, packed to each row's length rounded up to 16 slots
+# (54,642,784 slots at seed 2^33 + 5).  The longest row the law allows
+# (4,096 ids, from any offset in its first lane tile) spans 9 SMEM chunks;
+# the chunk loop's trip count is read at run time, so one compile covers
+# every row length.
+RCV1_TAIL = (677_399, 54_642_784, 47_236)
+
+
+def _ragged_block(loss):
+    from repro.kernels.dcd_ell import (
+        dcd_ragged_block_pallas_call,
+        ragged_stream_rows,
+    )
+
+    def f(c, v, p, wid, a, w, q, i):
+        return dcd_ragged_block_pallas_call(
+            ragged_stream_rows(c, v), p, wid, a, w, q, i, loss=loss)
+    return f
+
+
+@pytest.mark.parametrize("loss", [Hinge(C=1.0), Logistic(C=1.0)],
+                         ids=["hinge", "logistic"])
+def test_dcd_ragged_compiles(chip, loss):
+    from repro.kernels.dcd_ell import CHUNK_TILES, GRAIN, LANES
+
+    n, slots, d = RCV1_TAIL
+    longest = 4096
+    assert -(-(LANES - GRAIN + longest) // (CHUNK_TILES * LANES)) == 9
+    s = lane_pad(slots)
+    d1 = lane_pad(d + 1)
+    hlo = _compile_text(
+        chip, _ragged_block(loss),
+        ((s,), I32), ((s,), F32), ((n,), I32), ((n,), I32), ((n,), F32),
+        ((d1,), F32), ((n,), F32), ((B,), I32))
+    assert "tpu_custom_call" in hlo
