@@ -14,7 +14,9 @@ generated from ``--seed``:
      primal–dual invariant ‖ŵ − Σ αᵢxᵢ‖/‖ŵ‖ ≤ 1e-3 on the host in f64;
   2. kernels — each Pallas engine (dense, ELL, 2-D feature) compiled on
      the chip against its jnp engine at a shape its policy admits:
-     ``tpu_custom_call`` in the HLO and (α, ŵ) equal to atol 1e-5;
+     ``tpu_custom_call`` in the HLO and (α, ŵ) equal to atol 1e-5; on
+     the ELL shard the gap's one-hot pass is compiled and records the
+     XLA path's gaps and backward errors to 1e-5 of g(0) = C·n and ‖ŵ‖;
   3. serving — ``ServeEngine`` on the phase-1 snapshot scores 256 test
      rows, each equal to the host f64 margin to 1e-4 relative;
   4. segmented — ``solve_segmented`` in two checkpointed segments,
@@ -152,7 +154,8 @@ def timed_solve(clock, dev, fn, *, epochs: int, label: str):
     jax.block_until_ready((res.alpha, res.w_hat, res.gaps))
     wall = time.perf_counter() - t0
     c1, h1 = clock.read()
-    say(f"{label}: engine {res.engine}; wall {wall:.2f} s, of which "
+    say(f"{label}: engine {res.engine}, gap {res.gap_engine}; wall "
+        f"{wall:.2f} s, of which "
         f"compile {c1 - c0:.2f} s ({h1 - h0} persistent-cache hits); "
         f"smoke timing {(wall - (c1 - c0)) / epochs:.3f} s/epoch "
         f"(not a metric); peak device memory {peak_bytes(dev)} B")
@@ -209,20 +212,23 @@ def phase_kernels(ds, *, seed: int):
         "dense", n, 16, d, d, 1.0)).dense_train()
     rows = EllMatrix(ds.X_train.indices[:KERNEL_ROWS],
                      ds.X_train.values[:KERNEL_ROWS], RCV1["d"])
+    # (name, matrix, mesh, whether the gap's one-hot pass runs: rcv1's
+    # width on the 1-D ELL pipeline)
     cases = (
-        (f"dense {n}x{d}", dense, solver_mesh("data")),
+        (f"dense {n}x{d}", dense, solver_mesh("data"), False),
         (f"ell rcv1 rows n_loc={KERNEL_ROWS} d={RCV1['d']}", rows,
-         solver_mesh("data")),
+         solver_mesh("data"), True),
         (f"feature (1,1) mesh, rcv1 rows n_loc={KERNEL_ROWS}", rows,
-         solver_mesh_2d(data=1, model=1)),
+         solver_mesh_2d(data=1, model=1), False),
     )
-    for name, X, mesh in cases:
+    for name, X, mesh, onehot in cases:
         t0 = time.perf_counter()
         fused, hlo = _engine_run(X, loss, mesh=mesh, use_kernel=True,
                                  epochs=2, seed=seed)
         ref, _ = _engine_run(X, loss, mesh=mesh, use_kernel=False,
                              epochs=2, seed=seed)
-        say(f"phase 2 {name}: {fused.engine} vs {ref.engine}, "
+        say(f"phase 2 {name}: {fused.engine} (gap {fused.gap_engine}) vs "
+            f"{ref.engine} (gap {ref.gap_engine}), "
             f"{time.perf_counter() - t0:.1f} s wall incl. compile")
         gate("/pallas" in fused.engine
              and fused.engine.endswith("-compiled"),
@@ -235,6 +241,26 @@ def phase_kernels(ds, *, seed: int):
                           - np.asarray(ref.w_hat)).max())
         gate(max(da, dw) <= 1e-5,
              f"phase 2 {name}: |d alpha| {da:.3g}, |d w| {dw:.3g} <= 1e-5")
+        if not onehot:
+            continue
+        # the gap's one-hot MXU pass against XLA's scatter and gather:
+        # exact to f32 on the chip, which interpret mode cannot show
+        gate(fused.gap_engine == "pallas-onehot-compiled"
+             and ref.gap_engine == "xla",
+             f"phase 2 {name}: gap engines {fused.gap_engine} vs "
+             f"{ref.gap_engine}")
+        dg = float(np.abs(np.asarray(fused.gaps)
+                          - np.asarray(ref.gaps)).max())
+        de = float(np.abs(np.asarray(fused.eps)
+                          - np.asarray(ref.eps)).max())
+        g0 = loss.C * X.n_rows  # the zero-start gap
+        w_norm = float(np.linalg.norm(np.asarray(ref.w_hat)))
+        say(f"phase 2 {name}: recorded gaps differ by {dg:.3g} "
+            f"(g(0) = {g0:.6g}), backward errors by {de:.3g} "
+            f"(|w| = {w_norm:.6g})")
+        gate(dg <= 1e-5 * g0 and de <= 1e-5 * w_norm,
+             f"phase 2 {name}: gaps and backward errors of the one-hot "
+             f"pass within 1e-5 of g(0) and |w| of XLA's")
 
 
 def phase_serve(ds, res):

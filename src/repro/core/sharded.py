@@ -153,6 +153,7 @@ from repro.kernels.dcd_ell import (
     ragged_stream_rows,
     stream_rows,
 )
+from repro.kernels.gap_onehot import gap_onehot_fits, onehot_mv, onehot_rmv
 
 # The solver's layers inside the compiled epoch, as ``jax.named_scope``s:
 # each reaches the op_name of every instruction it holds, so a device
@@ -175,6 +176,7 @@ class ShardedResult(NamedTuple):
     active: jnp.ndarray | None = None  # active-set fraction (shrinking)
     delay: jnp.ndarray | None = None  # effective delay flag (adaptive)
     engine: str | None = None  # the block engine that ran (engine_name)
+    gap_engine: str | None = None  # the gap's products (SolverSetup's)
 
 
 def _local_block_update(X_loc, sq_loc, alpha_loc, w, idx_block, loss,
@@ -669,7 +671,17 @@ def _gap_slots(epochs: int, gap_every: int) -> int:
                if (e + 1) % gap_every == 0 or e == epochs - 1)
 
 
-def _make_gap_1d(loss, X_loc, ell: bool, axes=("data",)):
+def _gap_onehot(use_k: bool, sparse: bool, d_run: int) -> bool:
+    """Whether the 1-D gap's two products run as the one-hot MXU pass
+    (``repro.kernels.gap_onehot``, DESIGN.md §17): wherever the fused
+    block engine runs a sparse shard and the primal is narrow enough
+    for the pass to beat XLA's scatter and gather (``gap_onehot_fits``);
+    those XLA ops everywhere else."""
+    return bool(use_k and sparse and gap_onehot_fits(d_run))
+
+
+def _make_gap_1d(loss, X_loc, ell: bool, axes=("data",), onehot=False,
+                 interpret=False):
     """Per-device duality-gap contribution for the pipelined 1-D solve:
     gap(α) = ‖w(α)‖² + Σ_i [ℓ(w(α)ᵀx_i) + ℓ*(−α_i)] computed from the
     padded shards — padding rows are masked out of both sums and
@@ -689,10 +701,21 @@ def _make_gap_1d(loss, X_loc, ell: bool, axes=("data",)):
     mesh, ``("pod", "data")`` on a pod mesh, where w(α) and the loss
     sums reduce over the whole fleet while ``w_view`` is the pod's
     (possibly stale) read view, making the recorded backward error the
-    pod-staleness distance (DESIGN.md §13)."""
+    pod-staleness distance (DESIGN.md §13).
+
+    With ``onehot`` (``_gap_onehot``) an ELL shard's two products run
+    as the one-hot MXU pass, in interpret mode with ``interpret``."""
     if ell:
         cols_loc, vals_loc = X_loc
+    if ell and onehot:
+        def rmv(am, d_run):
+            return onehot_rmv(cols_loc, vals_loc, d_run, am,
+                              interpret=interpret)
 
+        def mv(wa):
+            return onehot_mv(cols_loc, vals_loc, wa, row_sum=True,
+                             interpret=interpret)
+    elif ell:
         def rmv(am, d_run):
             return jnp.zeros((d_run,), jnp.float32).at[cols_loc].add(
                 am[:, None] * vals_loc)
@@ -709,23 +732,43 @@ def _make_gap_1d(loss, X_loc, ell: bool, axes=("data",)):
     return _gap_1d_from(loss, rmv, mv, axes)
 
 
-def _make_gap_ragged(loss, X_loc, axes=("data",)):
+def _make_gap_ragged(loss, X_loc, axes=("data",), onehot=False,
+                     interpret=False):
     """``_make_gap_1d`` over packed ragged rows (X_loc = (cols, vals,
     gseg, ptr, wid), ``pack_ragged``): both products read the packed
     slots once — w(α) scatters the α of each slot's row, and the margins
     sum each group of ``GRAIN`` slots and add the groups into their rows
     (``gseg``, sorted) — so the work follows the true nonzeros, not the
-    longest row."""
+    longest row.  With ``onehot`` the scatter and the gather of the
+    slots, viewed as (slots / 128, 128), run as the one-hot MXU pass."""
     cols_loc, vals_loc, gseg_loc, ptr_loc, _ = X_loc
     n_loc = ptr_loc.shape[0]
+    if onehot:
+        c_view, v_view = (cols_loc.reshape(-1, LANES),
+                          vals_loc.reshape(-1, LANES))
+
+        def slot_rmv(ag, d_run):  # each group's α scales its slots
+            return onehot_rmv(c_view, v_view, d_run,
+                              ag.reshape(-1, LANES // GRAIN),
+                              interpret=interpret)
+
+        def slot_mv(wa):
+            return onehot_mv(c_view, v_view, wa, row_sum=False,
+                             interpret=interpret)
+    else:
+        def slot_rmv(ag, d_run):
+            contrib = ag[:, None] * vals_loc.reshape(-1, GRAIN)
+            return jnp.zeros((d_run,), jnp.float32).at[cols_loc].add(
+                contrib.reshape(-1))
+
+        def slot_mv(wa):
+            return wa[cols_loc] * vals_loc
 
     def rmv(am, d_run):
-        contrib = am[gseg_loc][:, None] * vals_loc.reshape(-1, GRAIN)
-        return jnp.zeros((d_run,), jnp.float32).at[cols_loc].add(
-            contrib.reshape(-1))
+        return slot_rmv(am[gseg_loc], d_run)
 
     def mv(wa):
-        part = jnp.sum((wa[cols_loc] * vals_loc).reshape(-1, GRAIN), axis=1)
+        part = jnp.sum(slot_mv(wa).reshape(-1, GRAIN), axis=1)
         return jax.ops.segment_sum(part, gseg_loc, n_loc,
                                    indices_are_sorted=True)
 
@@ -1561,9 +1604,13 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
         # unbatched (conds stay conds under the vmap).
         def run_task(carry, y):
             if record:
-                gap_fn = (_make_gap_ragged(loss, X_loc, axes=gap_axes)
+                onehot = _gap_onehot(use_kernel, ell or ragged, d_run)
+                gap_fn = (_make_gap_ragged(loss, X_loc, axes=gap_axes,
+                                           onehot=onehot,
+                                           interpret=interpret)
                           if ragged else
-                          _make_gap_1d(loss, X_loc, ell, axes=gap_axes))
+                          _make_gap_1d(loss, X_loc, ell, axes=gap_axes,
+                                       onehot=onehot, interpret=interpret))
                 gap = lambda rec, a, wv: gap_fn(rec, a, valid, d_run,
                                                 wv, y)
             else:
@@ -2024,6 +2071,7 @@ class SolverSetup(NamedTuple):
     Y: object = None     # placed (K, n_pad) ±1 label matrix (None = binary)
     ragged: bool = False  # X is packed ragged rows (a CsrMatrix input)
     layout: object = None  # RowLayout of the packed ragged rows
+    gap_engine: str = "xla"  # the 1-D gap's products (_gap_onehot)
 
 
 def _place_labels(mesh, y, *, n, n_pad, ridx, pod_on):
@@ -2327,7 +2375,11 @@ def prepare_solver(
         gap_every=gap_every, record=record, tuning=tuning,
         shrink_tol=shrink_tol, repack_threshold=repack_threshold,
         adaptive_ratio=adaptive_ratio, seed=seed,
-        n_tasks=n_tasks, Y=Y, ragged=ragged, layout=layout)
+        n_tasks=n_tasks, Y=Y, ragged=ragged, layout=layout,
+        gap_engine=("pallas-onehot-" + ("interpret" if interpret
+                                         else "compiled")
+                    if pipeline and _gap_onehot(use_k, is_ell or ragged,
+                                                d_run) else "xla"))
 
 
 def _init_alpha_w(setup: SolverSetup, alpha0=None, w0=None):
@@ -2557,7 +2609,7 @@ def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
     over the trailing axes of the (K, …) stacks, so the result carries
     (K, n) duals / (K, d) weights / (K, n_gaps) records."""
     n, d = setup.n, setup.d
-    eng = engine_name(setup)
+    eng, gap_eng = engine_name(setup), setup.gap_engine
     if setup.n_tasks:
         if setup.pod_on:
             alpha = jax.vmap(
@@ -2571,9 +2623,9 @@ def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
             w = w[:, :d]
         if eps_arr is None:
             return ShardedResult(alpha[:, :n], w, gaps_arr, epochs,
-                                 engine=eng)
+                                 engine=eng, gap_engine=gap_eng)
         return ShardedResult(alpha[:, :n], w, gaps_arr, epochs, eps_arr,
-                             act_arr, delay_arr, eng)
+                             act_arr, delay_arr, eng, gap_eng)
     if setup.pod_on:
         alpha = jnp.zeros((n + 1,), jnp.float32).at[setup.ridx].set(alpha)
     if setup.two_d:
@@ -2582,9 +2634,10 @@ def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
     else:
         w = w[:d]
     if eps_arr is None:
-        return ShardedResult(alpha[:n], w, gaps_arr, epochs, engine=eng)
+        return ShardedResult(alpha[:n], w, gaps_arr, epochs, engine=eng,
+                             gap_engine=gap_eng)
     return ShardedResult(alpha[:n], w, gaps_arr, epochs, eps_arr,
-                         act_arr, delay_arr, eng)
+                         act_arr, delay_arr, eng, gap_eng)
 
 
 def _validate_solver_inputs(X_host, y, loss):

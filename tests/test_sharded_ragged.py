@@ -256,12 +256,13 @@ def test_ragged_kernel_admission():
 
 
 # sha256 of the one-epoch pipeline's jaxpr for a fixed-width EllMatrix
-# (source locations stripped), as the program compiled before ragged
-# rows existed — the jnp engine and the streamed kernel in interpret
-# mode; JAX 0.9.0.  A fixed-width matrix takes exactly the old path.
+# (source locations stripped) — the jnp engine as the program compiled
+# before ragged rows existed, and the streamed kernel in interpret mode
+# with the gap's products as the one-hot MXU pass (kernels/gap_onehot.py);
+# JAX 0.9.0.  A fixed-width matrix takes exactly the ELL path.
 FIXED_JAXPR_SHA256 = {
     False: "60a2abe75fa494f7",
-    True: "7af9ad076796ba7f",
+    True: "3858bcfeb8fa81dc",
 }
 
 
