@@ -22,9 +22,11 @@ generated from ``--seed``:
   4. segmented — ``solve_segmented`` in two checkpointed segments,
      bit-identical to the phase-1 solve.
 
-``--chips 4`` runs only the multi-chip path: a data=4 pipeline against
-the host-driver path, pod meshes against the ``cocoa_pod_solve`` oracle,
-and the rcv1 solve on data=4 under the phase-1 gates.
+``--chips 4`` runs only the multi-chip path: the data=4 solve against
+the same solve on a (data=4, model=1) mesh, which runs the same update
+sequence through the 2-D engine, pod meshes against the
+``cocoa_pod_solve`` oracle, and the rcv1 solve on data=4 under the
+phase-1 gates.
 
 Timings printed here are smoke timings of one run, not benchmark
 metrics.  The last line of stdout is the JSON result.
@@ -345,11 +347,12 @@ def phase_four_chips(ds, clock, devs, *, epochs: int, seed: int):
     loss = Hinge(C=1.0)
     data4 = solver_mesh("data")
     kw = dict(epochs=3, block_size=8)
-    r0 = sharded_passcode_solve(ell, loss, mesh=data4, pipeline=False, **kw)
-    r1 = sharded_passcode_solve(ell, loss, mesh=data4, pipeline=True, **kw)
+    data4_2d = make_mesh((4, 1), ("data", "model"), devices=devs[:4])
+    r0 = sharded_passcode_solve(ell, loss, mesh=data4_2d, **kw)
+    r1 = sharded_passcode_solve(ell, loss, mesh=data4, **kw)
     d1 = max(float(np.abs(A(r0.alpha) - A(r1.alpha)).max()),
              float(np.abs(A(r0.w_hat) - A(r1.w_hat)).max()))
-    gate(d1 < 1e-5, f"data=4 pipeline vs host driver: {d1:.3g} < 1e-5")
+    gate(d1 < 1e-5, f"data=4 vs (data=4, model=1): {d1:.3g} < 1e-5")
     gate(float(np.abs(A(r1.alpha)[96:]).sum()) > 0,
          "data=4: the padded row tail is trained")
 

@@ -12,8 +12,7 @@ Fidelity note: the original updates α_i ← Π(α_i − γ·∇_i D(α)/Q_ii) w
 round of ``n_threads`` updates (a *stale* read for every thread in the
 round — same staleness model as our PASSCoDe engine).  This is charitable
 to AsySCD by a factor ≤ n_threads in cost yet it still loses badly, which
-reproduces the paper's qualitative claim.  ``benchmarks/bench_scaling``
-additionally reports the honest per-update O(nnz) cost model.
+reproduces the paper's qualitative claim.
 """
 
 from __future__ import annotations

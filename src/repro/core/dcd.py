@@ -91,7 +91,7 @@ def dcd_solve(
     record_gap: bool = True,
 ) -> DcdResult:
     """Run serial DCD for `epochs` epochs (early-stop on duality gap ≤ tol)."""
-    X_gap = X
+    X_rows = X  # the gap reads the rows unpadded
     if isinstance(X, CsrMatrix):
         X = X.to_ell()
     n = X.n_rows if isinstance(X, EllMatrix) else X.shape[0]
@@ -113,7 +113,7 @@ def dcd_solve(
         state = dcd_epoch(X, sq_norms, state, perm, loss)
         done = e + 1
         if record_gap:
-            g = float(duality_gap(state.alpha, X_gap, loss))
+            g = float(duality_gap(state.alpha, X_rows, loss))
             gaps.append(g)
             if tol > 0 and g <= tol:
                 break

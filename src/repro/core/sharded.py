@@ -62,25 +62,25 @@ the type of ``X_host`` (dense array, ``repro.data.sparse.EllMatrix``, or
     ``dcd_feature_kernel_fits`` for the ~2·n_loc·k̃_loc + 2·d/m 2-D
     slice — falling back to pure jnp otherwise.
 
-**Execution pipeline** (DESIGN.md §11): by default the whole multi-epoch
-solve is ONE jitted dispatch (``make_sharded_pipeline`` /
+**Execution pipeline** (DESIGN.md §11): the whole multi-epoch solve is
+ONE jitted dispatch (``make_sharded_pipeline`` /
 ``make_sharded_pipeline_2d``) — each device draws its own masked block
 permutations *inside* the shard_map body from per-device PRNG keys
-(bit-matching the host driver's ``_masked_block_perms``), every epoch
-and block round runs inside a single ``lax.scan``, and duality gaps
-accumulate into a preallocated on-device buffer honoring ``gap_every``.
-``pipeline=False`` keeps the legacy host loop (``_drive_epochs``: one
-dispatch + one ``device_put`` per epoch) as the reference.  On the 2-D
+(``_device_block_perm``), every epoch and block round runs inside a
+single ``lax.scan``, and duality gaps accumulate into a preallocated
+on-device buffer honoring ``gap_every``.  On the 2-D
 fused path with ``delay_rounds ≥ 1``, ``overlap`` additionally
 double-buffers the block round (``_scan_rounds_overlap``): the
 ``model``-axis (base, Gram) psum of block t is carried in flight across
 the round boundary and overlaps the gram kernel of block t+1, the base
 staleness being repaired exactly by ``dcd_feature_base_correction``.
 
-All engines compute the identical update sequence; tests assert
-agreement to atol 1e-5 across hinge / squared-hinge / logistic and
-delay_rounds (``tests/test_sharded_kernel.py``,
-``tests/test_sharded_ell.py``, ``tests/test_sharded_feature.py``,
+All engines compute the identical update sequence, and the serial
+oracle replays it: ``repro.core.dcd.dcd_epoch`` fed the same per-device
+draws, one round at a time (on one device, plain serial DCD in the
+solver's order).  Tests assert agreement to atol 1e-5 across hinge /
+squared-hinge / logistic and delay_rounds (``tests/test_sharded_kernel.
+py``, ``tests/test_sharded_ell.py``, ``tests/test_sharded_feature.py``,
 ``tests/test_sharded_pipeline.py``).
 
 Rows whose count is not divisible by the device count are no longer
@@ -104,7 +104,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core.objective import duality_gap, f32_dot
+from repro.core.objective import f32_dot
 from repro.core.shrinking import active_mask_from_w
 from repro.data.sparse import (
     CsrMatrix,
@@ -169,14 +169,13 @@ class ShardedResult(NamedTuple):
     w_hat: jnp.ndarray
     gaps: jnp.ndarray
     rounds: int
-    # live per-record metrics of the pipelined solve (None on the
-    # pipeline=False driver path), aligned with ``gaps``:
-    eps: jnp.ndarray | None = None  # ‖w(α) − ŵ‖, the perturbed-
-    #   regularizer distance of core/backward_error.py (paper §4.2)
-    active: jnp.ndarray | None = None  # active-set fraction (shrinking)
-    delay: jnp.ndarray | None = None  # effective delay flag (adaptive)
-    engine: str | None = None  # the block engine that ran (engine_name)
-    gap_engine: str | None = None  # the gap's products (SolverSetup's)
+    # live per-record metrics of the solve, aligned with ``gaps``:
+    eps: jnp.ndarray  # ‖w(α) − ŵ‖, the perturbed-regularizer distance
+    #   of core/backward_error.py (paper §4.2)
+    active: jnp.ndarray  # active-set fraction (shrinking)
+    delay: jnp.ndarray  # effective delay flag (adaptive)
+    engine: str  # the block engine that ran (engine_name)
+    gap_engine: str  # the gap's products (SolverSetup's)
 
 
 def _local_block_update(X_loc, sq_loc, alpha_loc, w, idx_block, loss,
@@ -389,11 +388,9 @@ def _device_block_perm(sub, my, p: int, n_loc: int, n_rows: int,
     (possible when n_rows < (p−1)·n_loc): it repeatedly selects local
     row 0, a zero row with q←1 whose update cannot move w.
 
-    Returns (n_blocks, B).  ``_masked_block_perms`` (the host driver's
-    all-device draw) is defined as the vmap of this function, so the
-    pipelined and host-driven solves run bit-identical update sequences
-    by construction (also asserted in ``tests/test_sharded_pipeline.
-    py``)."""
+    Returns (n_blocks, B).  The serial oracle of the tests replays this
+    draw per device and epoch, so the solve and the oracle run one
+    update sequence (``tests/test_sharded_pipeline.py``)."""
     v = jnp.clip(n_rows - my * n_loc, 1, n_loc)
     return _device_block_perm_v(sub, my, p, n_loc, v, n_blocks,
                                 block_size)
@@ -417,18 +414,6 @@ def _device_block_perm_v(sub, my, p: int, n_loc: int, v, n_blocks: int,
     order = jnp.argsort(perm >= v)  # stable: valid ids first, in order
     sel = perm[order][jnp.arange(m) % v]
     return sel.reshape(n_blocks, block_size)
-
-
-def _masked_block_perms(key, p: int, n_loc: int, n_rows: int,
-                        n_blocks: int, block_size: int):
-    """All devices' masked block permutations for one epoch, drawn on
-    the host (the ``pipeline=False`` driver path) — row ``my`` IS
-    ``_device_block_perm(key, my, ...)``, structurally.  Returns
-    (p, n_blocks·B)."""
-    return jax.vmap(
-        lambda my: _device_block_perm(key, my, p, n_loc, n_rows,
-                                      n_blocks, block_size).reshape(-1)
-    )(jnp.arange(p))
 
 
 def _device_block_perm_masked(sub, my, p: int, n_loc: int, n_blocks: int,
@@ -626,11 +611,9 @@ def _scan_rounds_overlap(gram_fn, corr_fn, update_fn, alpha_loc, w_loc,
     the deterministic PRNG chain — so the prologue gram that used to be
     recomputed (and one gram wasted on a wrapped dummy block) every
     epoch is paid once per *solve* instead (the carry out of the final
-    epoch is the only discard).  The per-epoch driver path passes
-    ``next0 = blocks_loc[0]``, reproducing the old wrapped schedule
-    exactly.  ``act`` gates shrunk coordinates in the update kernel
-    (the gram needs no mask — a frozen row's δ = 0 contributes nothing
-    through the recursion or the scatter).
+    epoch is the only discard).  ``act`` gates shrunk coordinates in the
+    update kernel (the gram needs no mask — a frozen row's δ = 0
+    contributes nothing through the recursion or the scatter).
     """
     nxt = jnp.concatenate([blocks_loc[1:], next0[None]], axis=0)
 
@@ -665,7 +648,7 @@ def _scan_rounds_overlap(gram_fn, corr_fn, update_fn, alpha_loc, w_loc,
 
 def _gap_slots(epochs: int, gap_every: int) -> int:
     """How many duality gaps the solve records — every ``gap_every``-th
-    epoch plus the final one (the host driver's schedule exactly)."""
+    epoch plus the final one."""
     gap_every = max(int(gap_every), 1)
     return sum(1 for e in range(epochs)
                if (e + 1) % gap_every == 0 or e == epochs - 1)
@@ -685,8 +668,8 @@ def _make_gap_1d(loss, X_loc, ell: bool, axes=("data",), onehot=False,
     """Per-device duality-gap contribution for the pipelined 1-D solve:
     gap(α) = ‖w(α)‖² + Σ_i [ℓ(w(α)ᵀx_i) + ℓ*(−α_i)] computed from the
     padded shards — padding rows are masked out of both sums and
-    contribute zero columns to w(α), so the value matches the host
-    driver's ``duality_gap(alpha[:n], X, loss)`` up to reduction order.
+    contribute zero columns to w(α), so the value matches
+    ``duality_gap(alpha[:n], X, loss)`` up to reduction order.
     Alongside the gap it returns the live backward-error metric
     ‖w(α) − ŵ‖ against the maintained primal view ``w_view`` — the
     perturbed-regularizer distance of ``core/backward_error.py`` (paper
@@ -977,109 +960,6 @@ def _block_update_2d(loss, use_kernel: bool, interpret: bool):
     return block_update
 
 
-def make_sharded_epoch(mesh: Mesh, loss, *, delay_rounds: int = 0,
-                       use_kernel: bool = False,
-                       interpret: bool | None = None, ell: bool = False):
-    """Build the jitted shard_map epoch function for a given mesh — one
-    dispatch per epoch, blocks drawn by the host (the ``pipeline=False``
-    reference path; see ``make_sharded_pipeline`` for the default).
-
-    ``use_kernel`` swaps the per-device block engine for the fused Pallas
-    indexed-block kernel; callers must then lane-pad d to a multiple of
-    128 (``sharded_passcode_solve`` does).  ``ell`` selects the sparse
-    engines: ``X`` becomes a ``(cols, vals)`` pair of row-sharded ELL
-    arrays and ``w`` the (d₁,) padded primal with the dummy slot at
-    index d (lane-padded when fused).  ``interpret`` defaults to True
-    off-TPU.
-    """
-    axis = "data"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    view, block_update = _block_update_1d(loss, use_kernel, interpret, ell)
-    x_spec = (P(axis), P(axis)) if ell else P(axis)
-
-    def epoch(X, sq_norms, alpha, w, blocks_idx, carry_dw):
-        # blocks_idx: (n_blocks, B) *local* row ids per device (sharded).
-        def device_fn(X_loc, sq_loc, alpha_loc, w_rep, blocks_loc, dw_prev):
-            X_eng = view(X_loc)
-            return _scan_rounds(
-                lambda a, w_eff, idx: block_update(X_eng, sq_loc, a,
-                                                   w_eff, idx),
-                alpha_loc, w_rep, dw_prev, blocks_loc, delay_rounds,
-            )
-
-        return shard_map(
-            device_fn,
-            mesh=mesh,
-            in_specs=(x_spec, P(axis), P(axis), P(), P(axis), P()),
-            out_specs=(P(axis), P(), P()),
-            check_vma=False,  # carries flip replicated→varying across psum
-        )(X, sq_norms, alpha, w, blocks_idx, carry_dw)
-
-    return jax.jit(epoch)
-
-
-def make_sharded_epoch_2d(mesh: Mesh, loss, *, delay_rounds: int = 0,
-                          use_kernel: bool = False,
-                          interpret: bool | None = None,
-                          overlap: bool | str = False):
-    """Build the jitted shard_map epoch function for a 2-D
-    ``("data", "model")`` mesh (DESIGN.md §10) — the ``pipeline=False``
-    reference path.
-
-    ``X`` is a ``(cols, vals)`` pair of (n, m, k) arrays — per-row,
-    per-feature-shard local ELL slices (``repro.data.sparse.
-    ell_column_split`` layout) sharded ``P("data", "model")`` — and
-    ``w`` the (m·d₁_loc,) concatenation of per-shard padded primal
-    slices sharded ``P("model")``.  α / sq_norms / blocks shard along
-    ``data`` only (replicated over ``model``: every feature shard of a
-    data block computes identical δs).  ``use_kernel`` swaps the
-    per-device engine for the fused Pallas pair (callers must then
-    lane-pad k_loc and d_loc+1 to multiples of 128).  ``overlap``
-    double-buffers the fused block round (``_scan_rounds_overlap``;
-    needs ``use_kernel`` and ``delay_rounds ≥ 1``)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    overlap = pipeline_overlap(overlap, two_d=True, fused=use_kernel,
-                               delay_rounds=delay_rounds)
-    block_update = _block_update_2d(loss, use_kernel, interpret)
-
-    def epoch(X, sq_norms, alpha, w, blocks_idx, carry_dw):
-        def device_fn(cols_loc, vals_loc, sq_loc, alpha_loc, w_loc,
-                      blocks_loc, dw_prev):
-            cols_loc = cols_loc[:, 0]  # (n_loc, 1, k) → (n_loc, k)
-            vals_loc = vals_loc[:, 0]
-            if overlap:
-                gram_fn, corr_fn, update_fn = _overlap_round_fns(
-                    cols_loc, vals_loc, sq_loc, loss, interpret)
-                # per-epoch driver: prologue gram each dispatch, wrapped
-                # next0 — the pre-carry schedule (the pipelined path
-                # threads the aggregate across epochs instead)
-                inflight = gram_fn(w_loc, blocks_loc[0])
-                alpha_loc, w_loc, dw_prev, _ = _scan_rounds_overlap(
-                    gram_fn, corr_fn, update_fn, alpha_loc, w_loc,
-                    dw_prev, blocks_loc, inflight, blocks_loc[0],
-                )
-                return alpha_loc, w_loc, dw_prev
-            return _scan_rounds(
-                lambda a, w_eff, idx: block_update(cols_loc, vals_loc,
-                                                   sq_loc, a, w_eff, idx),
-                alpha_loc, w_loc, dw_prev, blocks_loc, delay_rounds,
-            )
-
-        cols, vals = X
-        return shard_map(
-            device_fn,
-            mesh=mesh,
-            in_specs=(P("data", "model"), P("data", "model"), P("data"),
-                      P("data"), P("model"), P("data"), P("model")),
-            out_specs=(P("data"), P("model"), P("model")),
-            check_vma=False,  # carries flip replicated→varying across psum
-        )(cols, vals, sq_norms, alpha, w, blocks_idx, carry_dw)
-
-    return jax.jit(epoch)
-
-
 # --------------------------------------------------- pipeline builders ----
 
 
@@ -1090,10 +970,10 @@ def _epoch_scan(rounds, gap, carry, draw_perm, *, epochs: int,
                 delay0: int = 0, overlap: bool = False, pod=None,
                 watchdog=None, fault=None):
     """The epoch loop every pipelined device body runs: split the PRNG
-    chain exactly like the host driver, draw this device's masked block
-    permutation, run the round scan, and ``cond``-record the duality
-    gap (plus the live backward-error, active-fraction and delay-flag
-    metrics) into preallocated buffers.  Shared by the 1-D and 2-D
+    chain (``key, sub = split(key)`` per epoch), draw this device's
+    masked block permutation, run the round scan, and ``cond``-record
+    the duality gap (plus the live backward-error, active-fraction and
+    delay-flag metrics) into preallocated buffers.  Shared by the 1-D and 2-D
     builders so the PRNG chain and the metric schedule cannot diverge
     between them.  Its layers carry the operator-facing names
     ``passcode.perm`` (the draw), ``passcode.update`` (the block engine),
@@ -1498,11 +1378,11 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
     per-epoch ``device_put`` of permutations, no host sync before the
     solve returns.
 
-    Each device splits the carried PRNG key exactly like the host driver
-    (``key, sub = split(key)`` per epoch) and draws its own masked block
-    permutation from ``sub`` and its ``data``-axis index
-    (``_device_block_perm`` — bit-matching ``_masked_block_perms``), so
-    ``pipeline=True/False`` run identical update sequences.  Gaps land
+    Each device splits the carried PRNG key (``key, sub = split(key)``
+    per epoch) and draws its own masked block permutation from ``sub``
+    and its ``data``-axis index (``_device_block_perm``), so the serial
+    oracle that replays those draws runs the identical update sequence
+    (``tests/test_sharded_pipeline.py``).  Gaps land
     in a preallocated (n_gaps,) on-device buffer honoring ``gap_every``
     — the whole gap computation, collectives included, is
     ``cond``-gated to recorded epochs (the predicate is uniform across
@@ -1536,8 +1416,7 @@ def make_sharded_pipeline(mesh: Mesh, loss, *, epochs: int,
     Returns ``fn(X, sq_norms, alpha, w, key, carry_dw) → (alpha, w,
     carry_dw, gaps, eps, active, delay)``; with ``delay_rounds > 0`` (or
     any self-tuning mode, or ``pod_delay_rounds > 0``) the caller
-    flushes the final in-flight aggregate (``w + carry_dw``) exactly
-    like the host driver.
+    flushes the final in-flight aggregate (``w + carry_dw``).
 
     Resilience mode (DESIGN.md §14): with ``segmented=True`` the built
     function is instead ``fn(X, sq_norms, st) → st`` over the full
@@ -1976,43 +1855,6 @@ def make_sharded_pipeline_2d(mesh: Mesh, loss, *, epochs: int,
     return jax.jit(solve)
 
 
-def _drive_epochs(epoch_fn, X, sq_norms, alpha, w, carry_dw, *, p, n_loc,
-                  n, n_blocks, block_size, epochs, key, record, gap_every,
-                  delay_rounds, blocks_sharding, gap_fn):
-    """The host-side per-epoch driver (the ``pipeline=False`` reference
-    path): draw the per-device masked block permutations, dispatch the
-    jitted epoch, record duality gaps on-device every ``gap_every``
-    epochs (plus the final one — host sync only after the solve), and
-    flush the deferred aggregate when delayed.  ``key`` is the same
-    PRNG key the pipelined solve consumes — one key, one chain, so the
-    documented bit-match between the two paths is structural, not a
-    call-site convention.  Returns (alpha, w, gaps)."""
-    gap_every = max(int(gap_every), 1)
-    gaps = []
-    for e in range(epochs):
-        key, sub = jax.random.split(key)
-        # per-device local permutation over *valid* rows only → (p,
-        # n_blocks·B); identical to permutation(n_loc)[:n_blocks*B]
-        # when nothing is padded.  shard_map expects the leading axis
-        # sharded: (p*n_blocks, B) with device i owning rows
-        # [i*n_blocks, (i+1)*n_blocks)
-        local_perms = _masked_block_perms(sub, p, n_loc, n, n_blocks,
-                                          block_size)
-        blocks = jax.device_put(
-            local_perms.reshape(p * n_blocks, block_size), blocks_sharding
-        )
-        alpha, w, carry_dw = epoch_fn(X, sq_norms, alpha, w, blocks,
-                                      carry_dw)
-        if record and ((e + 1) % gap_every == 0 or e == epochs - 1):
-            # device scalar — converted to host floats only after the
-            # final epoch, so epochs dispatch back-to-back
-            gaps.append(gap_fn(alpha))
-    if delay_rounds > 0:
-        w = w + carry_dw  # flush in-flight aggregate
-    gaps_arr = jnp.stack(gaps) if gaps else jnp.zeros((0,), jnp.float32)
-    return alpha, w, gaps_arr
-
-
 class RowLayout(NamedTuple):
     """Counters of packed ragged rows (``pack_ragged``), set once at
     ``prepare_solver``: the true nonzeros, the slots one pass over every
@@ -2055,7 +1897,6 @@ class SolverSetup(NamedTuple):
     use_k: bool
     interpret: bool
     X: object
-    X_gap: object
     sq_norms: object
     ridx: object  # pod rowmap gather indices (None off-pod)
     delay_rounds: int
@@ -2091,7 +1932,7 @@ def _place_labels(mesh, y, *, n, n_pad, ridx, pod_on):
     return K, jax.device_put(Yp, named(mesh, tax, data_axes(mesh)))
 
 
-def _refuse_ragged(mesh, *, y, shrink_every, pipeline):
+def _refuse_ragged(mesh, *, y, shrink_every):
     """A ``CsrMatrix`` runs the pipelined 1-D solve only: the other
     paths take fixed-width rows, and padding heavy-tailed rows to the
     longest is what the ragged layout exists to avoid."""
@@ -2104,8 +1945,6 @@ def _refuse_ragged(mesh, *, y, shrink_every, pipeline):
         why = "the task axis (a (K, n) label matrix)"
     elif shrink_every:
         why = "shrinking and its repack (shrink_every > 0)"
-    elif not pipeline:
-        why = "the host-driven epoch loop (pipeline=False)"
     if why is not None:
         raise ValueError(
             f"ragged rows (CsrMatrix) are not supported by {why}; they "
@@ -2159,7 +1998,6 @@ def prepare_solver(
     record: bool = True,
     use_kernel: bool | str = False,
     gap_every: int = 1,
-    pipeline: bool = True,
     overlap: bool | str = "auto",
     shrink_every: int = 0,
     shrink_tol: float = 1e-3,
@@ -2201,23 +2039,21 @@ def prepare_solver(
     pod_on = "pod" in mesh.axis_names
     if pod_on:
         pod_merge_policy(pod_delay_rounds, n_pods=mesh.shape["pod"],
-                         pipeline=pipeline, record=record,
+                         record=record,
                          shrink_every=shrink_every, adaptive=adaptive,
                          overlap=overlap)
     elif pod_delay_rounds:
         raise ValueError(
             "pod_delay_rounds needs a mesh with a 'pod' axis")
     if y is not None:
-        task_axis_policy(jnp.asarray(y).shape[0], mesh=mesh,
-                         pipeline=pipeline)
+        task_axis_policy(jnp.asarray(y).shape[0], mesh=mesh)
     elif "task" in mesh.axis_names:
         raise ValueError(
             "a 'task' mesh axis needs a (K, n) label matrix y "
             "(DESIGN.md §16)")
     ragged = isinstance(X_host, CsrMatrix)
     if ragged:
-        _refuse_ragged(mesh, y=y, shrink_every=shrink_every,
-                       pipeline=pipeline)
+        _refuse_ragged(mesh, y=y, shrink_every=shrink_every)
     two_d = "model" in mesh.axis_names
     gap_every = max(int(gap_every), 1)
     p = mesh.shape["data"]
@@ -2229,7 +2065,6 @@ def prepare_solver(
         m = mesh.shape["model"]
         is_ell = isinstance(X_host, EllMatrix)
         ell = X_host if is_ell else dense_to_ell(X_host)
-        X_gap = X_host if is_ell else jnp.asarray(X_host)
         n, d = ell.n_rows, ell.n_features
         fse = ell_column_split(ell, m)
         d_loc, k_loc = fse.d_loc, fse.k_loc
@@ -2253,7 +2088,7 @@ def prepare_solver(
         tuning = resolve_self_tuning(shrink_every, repack, adaptive,
                                      overlap_knob=overlap,
                                      overlap_on=overlap_on,
-                                     pipeline=pipeline, record=record)
+                                     record=record)
         # lane-pad k_loc and the per-shard padded primal when fused;
         # pad rows to n_pad with all-padding rows (local id d_loc, 0)
         k_run = lane_pad(k_loc) if use_k else k_loc
@@ -2279,7 +2114,7 @@ def prepare_solver(
             p=p, m=m, n=n, d=d, n_loc=n_loc, n_pad=n_pad,
             n_blocks=_n_blocks(n_loc, block_size), block_size=block_size,
             w_len=m * d1_loc, d_loc=d_loc, d1_loc=d1_loc, ell=True,
-            use_k=use_k, interpret=interpret, X=X, X_gap=X_gap,
+            use_k=use_k, interpret=interpret, X=X,
             sq_norms=jax.device_put(sq_norms, data_sh), ridx=ridx,
             delay_rounds=delay_rounds, pod_delay_rounds=pod_delay_rounds,
             gap_every=gap_every, record=record, tuning=tuning,
@@ -2313,17 +2148,15 @@ def prepare_solver(
                      delay_rounds=delay_rounds)
     tuning = resolve_self_tuning(shrink_every, repack, adaptive,
                                  overlap_knob=overlap, overlap_on=False,
-                                 pipeline=pipeline, record=record)
+                                 record=record)
     layout = None
     if ragged:
         # the packed slots, padded only to the walk's granule; the primal
         # as on the ELL path (dummy slot at d, lane-padded when fused)
-        X_gap = X_host
         d_run = lane_pad(d + 1) if use_k else d + 1
         X, sq_norms, layout = _pack_ragged_1d(mesh, X_host, n_loc=n_loc,
                                               n_pad=n_pad)
     elif is_ell:
-        X_gap = X_host  # duality gap always reads the unpadded data
         # the shard keeps its own width k_max on every engine (the fused
         # kernel lane-aligns a copy per dispatch, ``stream_rows``); pad
         # rows to n_pad with all-padding rows (index d, value 0)
@@ -2345,7 +2178,6 @@ def prepare_solver(
         )
     else:
         X = jnp.asarray(X_host)
-        X_gap = X  # duality gap always reads the unpadded data
         # the kernel wants clean (8, 128) f32 tiling: lane-pad d with
         # zero columns (inert in every dot product; sliced off the
         # returned w); row padding is all-zero rows with q set to 1 so
@@ -2369,7 +2201,7 @@ def prepare_solver(
         p=p, m=1, n=n, d=d, n_loc=n_loc, n_pad=n_pad,
         n_blocks=_n_blocks(n_loc, block_size), block_size=block_size,
         w_len=d_run, d_loc=0, d1_loc=0, ell=is_ell, use_k=use_k,
-        interpret=interpret, X=X, X_gap=X_gap,
+        interpret=interpret, X=X,
         sq_norms=jax.device_put(sq_norms, data_sh), ridx=ridx,
         delay_rounds=delay_rounds, pod_delay_rounds=pod_delay_rounds,
         gap_every=gap_every, record=record, tuning=tuning,
@@ -2378,8 +2210,8 @@ def prepare_solver(
         n_tasks=n_tasks, Y=Y, ragged=ragged, layout=layout,
         gap_engine=("pallas-onehot-" + ("interpret" if interpret
                                          else "compiled")
-                    if pipeline and _gap_onehot(use_k, is_ell or ragged,
-                                                d_run) else "xla"))
+                    if _gap_onehot(use_k, is_ell or ragged, d_run)
+                    else "xla"))
 
 
 def _init_alpha_w(setup: SolverSetup, alpha0=None, w0=None):
@@ -2601,7 +2433,7 @@ def engine_name(setup: SolverSetup) -> str:
 
 
 def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
-              eps_arr=None, act_arr=None, delay_arr=None):
+              eps_arr, act_arr, delay_arr):
     """Un-pad a finished solve back to user coordinates: invert the pod
     rowmap gather (padding slots all land on the sliced-off index n),
     stitch the true primal out of the 2-D per-shard padded slices, and
@@ -2621,9 +2453,6 @@ def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
             w = w.reshape(setup.n_tasks, -1)[:, :d]
         else:
             w = w[:, :d]
-        if eps_arr is None:
-            return ShardedResult(alpha[:, :n], w, gaps_arr, epochs,
-                                 engine=eng, gap_engine=gap_eng)
         return ShardedResult(alpha[:, :n], w, gaps_arr, epochs, eps_arr,
                              act_arr, delay_arr, eng, gap_eng)
     if setup.pod_on:
@@ -2633,9 +2462,6 @@ def _finalize(setup: SolverSetup, alpha, w, gaps_arr, epochs,
         w = w.reshape(-1)[:d]
     else:
         w = w[:d]
-    if eps_arr is None:
-        return ShardedResult(alpha[:n], w, gaps_arr, epochs, engine=eng,
-                             gap_engine=gap_eng)
     return ShardedResult(alpha[:n], w, gaps_arr, epochs, eps_arr,
                          act_arr, delay_arr, eng, gap_eng)
 
@@ -2719,7 +2545,6 @@ def sharded_passcode_solve(
     y=None,
     use_kernel: bool | str = False,
     gap_every: int = 1,
-    pipeline: bool = True,
     overlap: bool | str = "auto",
     shrink_every: int = 0,
     shrink_tol: float = 1e-3,
@@ -2731,7 +2556,7 @@ def sharded_passcode_solve(
     """Distributed PASSCoDe-Atomic.  ``X_host``: dense (n, d) array, an
     ``EllMatrix`` (the sparse fast path — per-update work drops from
     O(d) to O(k_max)) or a ``CsrMatrix`` (rows of unequal length, packed
-    to each row's own length: per-update work O(nnz_i); pipelined 1-D
+    to each row's own length: per-update work O(nnz_i); 1-D ``data``
     mesh only, DESIGN.md §9); rows are sharded across the mesh's
     ``data`` axis, padded to p-divisibility with masked zero rows (never
     dropped).
@@ -2753,11 +2578,9 @@ def sharded_passcode_solve(
     until the solve finishes, so recording no longer host-syncs (and
     thereby serializes) every epoch.
 
-    ``pipeline``: True (default) folds the whole multi-epoch solve into
-    one jitted dispatch — block permutations drawn on-device inside the
-    shard_map body, gaps accumulated into an on-device buffer (DESIGN.md
-    §11).  False keeps the legacy host loop (one dispatch + one
-    ``device_put`` per epoch); both run bit-matching update sequences.
+    The whole multi-epoch solve is one jitted dispatch — block
+    permutations drawn on-device inside the shard_map body, gaps
+    accumulated into an on-device buffer (DESIGN.md §11).
 
     ``overlap``: on the 2-D fused path with ``delay_rounds ≥ 1``,
     double-buffer the block round so the ``model``-axis (base, Gram)
@@ -2765,8 +2588,8 @@ def sharded_passcode_solve(
     (``_scan_rounds_overlap``).  "auto" (default) enables it exactly
     there; True elsewhere raises (``repro.dist.mesh.pipeline_overlap``).
 
-    Self-tuning knobs (DESIGN.md §12; pipelined path only — validated
-    by ``repro.dist.mesh.resolve_self_tuning``):
+    Self-tuning knobs (DESIGN.md §12; validated by
+    ``repro.dist.mesh.resolve_self_tuning``):
 
     ``shrink_every ≥ 1`` turns on on-device active-set shrinking: every
     that many epochs each device recomputes the LIBLINEAR projected-
@@ -2785,7 +2608,7 @@ def sharded_passcode_solve(
     ``delay_rounds`` (seed 1 to start async); ``adaptive_ratio`` is its
     improvement threshold (0.95 backs off only on a hard stall, 0.5
     anneals async→sync once the gap stops halving per record).  The
-    pipelined result then carries the live per-record metrics: ``eps``
+    result carries the live per-record metrics: ``eps``
     (the backward-error ‖w(α) − ŵ‖ of ``core/backward_error.py``),
     ``active`` (global active fraction) and ``delay`` (effective flag),
     all aligned with ``gaps``.
@@ -2800,7 +2623,7 @@ def sharded_passcode_solve(
     staleness model of a slow cross-pod allreduce.  ``pod_delay_rounds
     = 0`` is a synchronous CoCoA outer round (the ``repro.core.cocoa``
     oracle); admission is validated by ``repro.dist.mesh.
-    pod_merge_policy`` (pipelined path only; no shrinking/overlap;
+    pod_merge_policy`` (no shrinking/overlap;
     ``adaptive`` becomes the pod-level FIFO-drain latch).  ``alpha0`` /
     ``w0`` warm-start the solve from carried state — re-blocked onto
     whatever pod count the mesh has, which is how elastic pod
@@ -2819,10 +2642,6 @@ def sharded_passcode_solve(
         # but NOT folded (shared X), threaded to the engines instead
         Y_host = _validate_multitask_labels(X_host, y_host)
         X_host = _validate_solver_inputs(X_host, None, loss)
-        if not pipeline:
-            raise ValueError(
-                "a multi-task solve needs pipeline=True (see "
-                "repro.dist.mesh.task_axis_policy)")
     else:
         Y_host = None
         X_host = _validate_solver_inputs(X_host, y, loss)
@@ -2830,9 +2649,8 @@ def sharded_passcode_solve(
         X_host, loss, mesh=mesh, mesh_axes=mesh_axes, y=Y_host,
         block_size=block_size, delay_rounds=delay_rounds,
         pod_delay_rounds=pod_delay_rounds, seed=seed, record=record,
-        use_kernel=use_kernel, gap_every=gap_every, pipeline=pipeline,
-        overlap=overlap, shrink_every=shrink_every,
-        shrink_tol=shrink_tol, repack=repack,
+        use_kernel=use_kernel, gap_every=gap_every, overlap=overlap,
+        shrink_every=shrink_every, shrink_tol=shrink_tol, repack=repack,
         repack_threshold=repack_threshold, adaptive=adaptive,
         adaptive_ratio=adaptive_ratio)
     st = setup.tuning
@@ -2849,67 +2667,20 @@ def sharded_passcode_solve(
     alpha = jax.device_put(alpha, data_sh)
     w = jax.device_put(w, w_sh)
     carry_dw = jax.device_put(jnp.zeros_like(w), w_sh)
-    key = jax.random.PRNGKey(setup.seed)  # one chain for both paths
+    key = jax.random.PRNGKey(setup.seed)
     if setup.n_tasks:
         # identical per-task chains: each class replays the binary
         # solve's draws exactly, matching the loop-over-K reference
         key = jnp.broadcast_to(key, (setup.n_tasks,) + key.shape)
 
-    if pipeline:
-        solve_fn = build_pipeline(setup, epochs=epochs)
-        # identical block draws on the 1-D and 2-D paths at equal p and
-        # seed, so the two engines run the same update sequence
-        alpha, w, carry_dw, gaps_arr, eps_arr, act_arr, delay_arr = (
-            solve_fn(setup.X, setup.sq_norms, alpha, w, key, carry_dw,
-                     setup.Y))
-        if (setup.delay_rounds > 0 or st.shrink_every or st.adaptive
-                or setup.pod_delay_rounds > 0):
-            w = w + carry_dw  # flush in-flight aggregate (0 when sync)
-        return _finalize(setup, alpha, w, gaps_arr, epochs, eps_arr,
-                         act_arr, delay_arr)
-    if setup.two_d:
-        epoch_fn = make_sharded_epoch_2d(
-            setup.mesh, loss, delay_rounds=setup.delay_rounds,
-            use_kernel=setup.use_k, interpret=setup.interpret,
-            overlap=st.overlap)
-    else:
-        epoch_fn = make_sharded_epoch(
-            setup.mesh, loss, delay_rounds=setup.delay_rounds,
-            use_kernel=setup.use_k, interpret=setup.interpret,
-            ell=setup.ell)
-    alpha, w, gaps_arr = _drive_epochs(
-        epoch_fn, setup.X, setup.sq_norms, alpha, w, carry_dw,
-        p=setup.p, n_loc=setup.n_loc, n=setup.n,
-        n_blocks=setup.n_blocks, block_size=setup.block_size,
-        epochs=epochs, key=key, record=record,
-        gap_every=setup.gap_every, delay_rounds=setup.delay_rounds,
-        blocks_sharding=data_sh,
-        gap_fn=lambda a: duality_gap(a[:setup.n], setup.X_gap, loss),
-    )
-    return _finalize(setup, alpha, w, gaps_arr, epochs)
-
-
-def sharded_passcode_feature(
-    X_host,
-    loss,
-    *,
-    mesh: Mesh | None = None,
-    epochs: int = 10,
-    seed: int = 0,
-):
-    """Back-compat shim for the old feature-sharded demo — now a thin
-    wrapper over the unified 2D engine
-    (``sharded_passcode_solve(mesh_axes=("data", "model"))``), which
-    replaced the dense, serial, unjitted original.  data=1 with one
-    n-sized block per epoch reproduces the original's full serial
-    permutation pass, so Algorithm 1 semantics are kept exactly.
-    Returns ``(alpha, w)`` like the original; prefer the unified solver
-    in new code."""
-    if mesh is None:
-        mesh = solver_mesh_2d(data=1, model=len(jax.devices()))
-    n = X_host.n_rows if isinstance(X_host, EllMatrix) else X_host.shape[0]
-    r = sharded_passcode_solve(
-        X_host, loss, mesh=mesh, epochs=epochs, block_size=n,
-        seed=seed, record=False,
-    )
-    return r.alpha, r.w_hat
+    solve_fn = build_pipeline(setup, epochs=epochs)
+    # identical block draws on the 1-D and 2-D paths at equal p and
+    # seed, so the two engines run the same update sequence
+    alpha, w, carry_dw, gaps_arr, eps_arr, act_arr, delay_arr = (
+        solve_fn(setup.X, setup.sq_norms, alpha, w, key, carry_dw,
+                 setup.Y))
+    if (setup.delay_rounds > 0 or st.shrink_every or st.adaptive
+            or setup.pod_delay_rounds > 0):
+        w = w + carry_dw  # flush in-flight aggregate (0 when sync)
+    return _finalize(setup, alpha, w, gaps_arr, epochs, eps_arr,
+                     act_arr, delay_arr)

@@ -105,7 +105,7 @@ def solver_mesh_tasks(task: int = 2, data: int | None = None,
     return make_mesh((task, data), ("task", "data"))
 
 
-def task_axis_policy(n_tasks: int, *, mesh, pipeline: bool = True) -> int:
+def task_axis_policy(n_tasks: int, *, mesh) -> int:
     """Admission rule for the multi-task (one-vs-rest) task axis
     (DESIGN.md §16) — which knob combinations admit a leading (K,) task
     axis is *distribution* policy, so it lives here next to
@@ -116,8 +116,6 @@ def task_axis_policy(n_tasks: int, *, mesh, pipeline: bool = True) -> int:
     segmented resume — because each task carries its own latches and
     the shared epoch counter stays an unbatched scalar.  Restrictions:
 
-      * ``pipeline=False`` — the host driver has no per-task carry; the
-        multi-task solve only exists as the single-dispatch epoch scan;
       * a ``task`` mesh axis needs ``n_tasks`` divisible by its size
         (the per-class state stack shards evenly, no padding classes);
       * ``task`` + ``pod`` on one mesh is rejected: the cross-pod merge
@@ -129,12 +127,6 @@ def task_axis_policy(n_tasks: int, *, mesh, pipeline: bool = True) -> int:
     K = int(n_tasks)
     if K < 1:
         raise ValueError(f"n_tasks must be >= 1, got {n_tasks}")
-    if not pipeline:
-        raise ValueError(
-            "a multi-task solve needs pipeline=True — the per-task "
-            "state (α/w stacks, latches, record buffers) lives in the "
-            "on-device epoch-scan carry; the host driver path has no "
-            "carry to put it in")
     if "task" in mesh.axis_names:
         t = mesh.shape["task"]
         if K % t:
@@ -150,7 +142,7 @@ def task_axis_policy(n_tasks: int, *, mesh, pipeline: bool = True) -> int:
 
 
 def pod_merge_policy(pod_delay_rounds: int, *, n_pods: int,
-                     pipeline: bool = True, record: bool = True,
+                     record: bool = True,
                      shrink_every: int = 0, adaptive: bool = False,
                      overlap: bool | str = "auto") -> int:
     """Admission/staleness rule for the cross-pod primal merge
@@ -169,9 +161,6 @@ def pod_merge_policy(pod_delay_rounds: int, *, n_pods: int,
     Returns the validated ``pod_delay_rounds``.  Raises on
     combinations the pod merge scan does not (yet) compose with:
 
-      * ``pipeline=False`` — the outer merge scan only exists in the
-        pipelined (single-dispatch) path; the host driver has no
-        cross-epoch carry to keep a merge in flight in;
       * ``shrink_every >= 1`` — the active mask lives in the dyn round
         scan, which the pod path's static inner rounds do not run;
       * ``overlap=True`` — the in-flight (base, Gram) psum is only
@@ -187,12 +176,6 @@ def pod_merge_policy(pod_delay_rounds: int, *, n_pods: int,
             f"pod_delay_rounds must be >= 0, got {pod_delay_rounds}")
     if int(n_pods) < 1:
         raise ValueError(f"n_pods must be >= 1, got {n_pods}")
-    if not pipeline:
-        raise ValueError(
-            "a pod mesh needs pipeline=True — the cross-pod merge scan "
-            "(and its in-flight delayed aggregates) lives in the "
-            "on-device epoch-scan carry; the host driver path has no "
-            "carry to put it in")
     if shrink_every:
         raise ValueError(
             "shrink_every is not composed with the pod merge loop — "
@@ -511,17 +494,15 @@ class SelfTuning(NamedTuple):
 
 
 def resolve_self_tuning(shrink_every, repack, adaptive, *, overlap_knob,
-                        overlap_on: bool, pipeline: bool,
-                        record: bool) -> SelfTuning:
+                        overlap_on: bool, record: bool) -> SelfTuning:
     """Resolve/validate the solver's self-tuning knobs (DESIGN.md §12).
 
     ``shrink_every`` ∈ {0 = off, k ≥ 1}: recompute the active mask every
     k epochs.  ``repack`` ∈ {False, True, "auto"}: draw repacked epochs
     over the compacted active set so they take fewer block rounds.
-    ``adaptive`` toggles the gap-trend delay controller.  The knobs need
-    the pipelined (on-device epoch scan) path — mask, repack ids and the
-    delay flag all live in the scan carry — and the controller needs the
-    recorded gap as its input signal.
+    ``adaptive`` toggles the gap-trend delay controller.  Mask, repack
+    ids and the delay flag all live in the epoch scan's carry, and the
+    controller needs the recorded gap as its input signal.
 
     Interactions with the 2-D overlapped schedule: the overlapped round
     keeps a (base, Gram) psum in flight that is only valid for the block
@@ -537,11 +518,6 @@ def resolve_self_tuning(shrink_every, repack, adaptive, *, overlap_knob,
     if every < 0:
         raise ValueError(f"shrink_every must be >= 0, got {shrink_every}")
     adaptive = bool(adaptive)
-    if (every or adaptive) and not pipeline:
-        raise ValueError(
-            "shrink_every/adaptive need pipeline=True — the active mask "
-            "and delay flag live in the on-device epoch-scan carry; the "
-            "host driver path has no carry to put them in")
     if adaptive and not record:
         raise ValueError(
             "adaptive=True needs record=True — the gap-trend controller "

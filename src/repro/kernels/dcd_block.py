@@ -23,7 +23,7 @@ Two addressing modes share the δ machinery:
     carried across steps like w.  This computes exactly what the sharded
     solver's ``_local_block_update`` computes on a permuted block, which
     is how ``repro.core.sharded`` fuses its per-device round
-    (``make_sharded_epoch(use_kernel=True)``).  The VMEM feasibility
+    (``use_kernel=True`` on a dense shard).  The VMEM feasibility
     policy for the resident shard lives in ``repro.dist.mesh``
     (``dcd_kernel_fits`` / ``dcd_block_rows``).  Indexed mode also takes
     an optional ``y`` (±1 per row) folded *on read* — wx ← y_i·(w·x_i),

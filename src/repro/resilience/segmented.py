@@ -212,9 +212,8 @@ def solve_segmented(
         X_host, loss, mesh=mesh, mesh_axes=mesh_axes, y=Y_host,
         block_size=block_size, delay_rounds=delay_rounds,
         pod_delay_rounds=pod_delay_rounds, seed=seed, record=record,
-        use_kernel=use_kernel, gap_every=gap_every, pipeline=True,
-        overlap=overlap, shrink_every=shrink_every,
-        shrink_tol=shrink_tol, repack=repack,
+        use_kernel=use_kernel, gap_every=gap_every, overlap=overlap,
+        shrink_every=shrink_every, shrink_tol=shrink_tol, repack=repack,
         repack_threshold=repack_threshold, adaptive=adaptive,
         adaptive_ratio=adaptive_ratio)
     total = int(epochs)

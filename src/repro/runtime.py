@@ -2,7 +2,7 @@
 
 Only the persistent compilation cache lives here: where it is kept is
 part of its key, so every script that compiles on the chip
-(``chip_smoke.py``, ``benchmarks/run.py``) resolves it the same way.
+(``chip_smoke.py``, ``bench/run.py``) resolves it the same way.
 """
 
 from __future__ import annotations

@@ -214,14 +214,11 @@ def test_admission(d, use_kernel, want):
 
 def test_admission_rule():
     """On the chip (compiled) the pass serves R = d_run / 128 ≤ R_MAX:
-    rcv1's R = 370, never news20's 10,588; the host loop's gap and the
-    dense layout keep their own products."""
+    rcv1's R = 370, never news20's 10,588; the jnp engine and the dense
+    layout keep their own products."""
     assert sharded._gap_onehot(True, True, lane_pad(RCV1_D + 1))
     assert not sharded._gap_onehot(True, True, lane_pad(NEWS20_D + 1))
     assert not sharded._gap_onehot(True, False, 1024)
     assert not sharded._gap_onehot(False, True, lane_pad(RCV1_D + 1))
     assert gap_onehot_fits(128 * R_MAX)
     assert not gap_onehot_fits(128 * (R_MAX + 1))
-    setup = sharded.prepare_solver(_tiny_ell(RCV1_D), Hinge(C=1.0),
-                                   use_kernel=True, pipeline=False)
-    assert setup.gap_engine == "xla"

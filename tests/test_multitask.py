@@ -176,17 +176,12 @@ def test_multitask_label_validation():
     with pytest.raises(ValueError):  # column count != n
         sharded_passcode_solve(X, loss, y=np.ones((2, 31), np.float32),
                                epochs=1)
-    with pytest.raises(ValueError):  # host driver has no task carry
-        sharded_passcode_solve(X, loss, y=np.asarray(ovr_labels(y_int, 2)),
-                               epochs=1, pipeline=False)
 
 
 def test_task_axis_policy_validation():
     mesh = jax.make_mesh((1,), ("data",))
     with pytest.raises(ValueError):
         task_axis_policy(0, mesh=mesh)
-    with pytest.raises(ValueError):
-        task_axis_policy(4, mesh=mesh, pipeline=False)
     pod_task = jax.make_mesh((1, 1, 1), ("task", "pod", "data"))
     with pytest.raises(ValueError):
         task_axis_policy(4, mesh=pod_task)

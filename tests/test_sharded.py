@@ -39,7 +39,6 @@ _SUBPROCESS = textwrap.dedent("""
     from repro.core import sharded_passcode_solve, dcd_solve
     from repro.core.duals import Hinge
     from repro.core.objective import w_of_alpha
-    from repro.core.sharded import sharded_passcode_feature
     from repro.data.synthetic import make_dataset
 
     assert len(jax.devices()) == 8
@@ -62,7 +61,9 @@ _SUBPROCESS = textwrap.dedent("""
     assert eps < 1e-2, f"psum lost updates?! eps={{eps}}"
     # feature-sharded (model-parallel) variant == serial DCD semantics
     mesh_m = jax.make_mesh((8,), ("model",))
-    alpha, w = sharded_passcode_feature(X, loss, mesh=mesh_m, epochs=8)
+    r_m = sharded_passcode_solve(X, loss, mesh=mesh_m, epochs=8,
+                                 block_size=X.shape[0], record=False)
+    alpha, w = r_m.alpha, r_m.w_hat
     ref = dcd_solve(X, loss, epochs=8)
     from repro.core.objective import duality_gap
     g2 = float(duality_gap(alpha, X, loss))

@@ -17,20 +17,15 @@ import sys
 import textwrap
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import dcd_epoch, sharded_passcode_solve
-from repro.core.dcd import DcdState
+from conftest import serial_replay
+from repro.core import sharded_passcode_solve
 from repro.core.duals import Hinge, Logistic, SquaredHinge
-from repro.core.sharded import (
-    _masked_block_perms,
-    _resolve_kernel_mode_feature,
-    sharded_passcode_feature,
-)
+from repro.core.sharded import _resolve_kernel_mode_feature
 from repro.data.sparse import dense_to_ell, ell_column_split
 from repro.dist.mesh import (
     dcd_ell_kernel_fits,
@@ -50,23 +45,6 @@ def mesh_2d():
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
-def _serial_reference(X_dense, loss, *, epochs, block_size, seed=0):
-    """Serial DCD fed the exact per-epoch block order the sharded solver
-    draws at p=1, so the update sequences are identical."""
-    n, d = X_dense.shape
-    sq = jnp.sum(X_dense * X_dense, axis=1)
-    state = DcdState(jnp.zeros((n,), jnp.float32),
-                     jnp.zeros((d,), jnp.float32))
-    n_blocks = max(n // block_size, 1)
-    key = jax.random.PRNGKey(seed)
-    for _ in range(epochs):
-        key, sub = jax.random.split(key)
-        perm = _masked_block_perms(sub, 1, n, n, n_blocks,
-                                   block_size).reshape(-1)
-        state = dcd_epoch(X_dense, sq, state, perm, loss)
-    return state
-
-
 @pytest.mark.parametrize("delay_rounds", [0, 1])
 @pytest.mark.parametrize(
     "loss", [Hinge(C=1.0), SquaredHinge(C=1.0), Logistic(C=1.0)],
@@ -82,14 +60,15 @@ def test_feature_engine_equivalence(tiny_ell, tiny_dense, mesh_2d, loss,
     r_fused = sharded_passcode_solve(tiny_ell, loss, mesh=mesh_2d,
                                      use_kernel=True, **kw)
     refs = [r_1d]
-    if delay_rounds == 0:
-        # delayed rounds defer the data-axis psum, so only the
-        # undelayed schedule is serial-equivalent
-        serial = _serial_reference(tiny_dense, loss, epochs=2,
-                                   block_size=32)
-        np.testing.assert_allclose(np.asarray(r_1d.alpha),
-                                   np.asarray(serial.alpha),
-                                   rtol=1e-5, atol=1e-5)
+    # on one data shard the delayed round folds the same sum as the
+    # eager one, so both schedules are serial DCD in the draw order
+    serial = serial_replay(tiny_dense, loss, epochs=2, block_size=32,
+                           seed=0)
+    np.testing.assert_allclose(np.asarray(r_1d.alpha),
+                               np.asarray(serial.alpha),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(r_1d.w_hat),
+                               np.asarray(serial.w), rtol=1e-5, atol=1e-5)
     for r in (r_2d, r_fused):
         for ref in refs:
             np.testing.assert_allclose(np.asarray(r.alpha),
@@ -128,17 +107,6 @@ def test_dense_input_takes_feature_path(tiny_dense, hinge, mesh_2d):
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(r2.w_hat), np.asarray(r1.w_hat),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_feature_shim_keeps_legacy_contract(tiny_dense, hinge):
-    """``sharded_passcode_feature`` survives as a wrapper over the
-    unified 2D engine and still returns (alpha, w)."""
-    alpha, w = sharded_passcode_feature(tiny_dense, hinge, epochs=8)
-    from repro.core.objective import duality_gap
-
-    assert alpha.shape[0] == tiny_dense.shape[0]
-    assert w.shape[0] == tiny_dense.shape[1]
-    assert float(duality_gap(alpha, tiny_dense, hinge)) < 1.0
 
 
 def test_feature_auto_mode_falls_back_on_cpu(tiny_ell, hinge, mesh_2d):

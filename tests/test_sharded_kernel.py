@@ -1,5 +1,5 @@
-"""Fused (Pallas) vs unfused (jnp) sharded PASSCoDe — the two block
-engines of ``make_sharded_epoch`` must agree to atol 1e-5 for every loss
+"""Fused (Pallas) vs unfused (jnp) sharded PASSCoDe — the two dense
+block engines of the sharded solve must agree to atol 1e-5 for every loss
 in the family and for delayed (stale-τ) rounds, in CPU interpret mode.
 
 Multi-device agreement is covered by an 8-host-device subprocess, same

@@ -1,12 +1,12 @@
 """On-device multi-epoch pipeline of the sharded PASSCoDe solver
-(DESIGN.md §11): the single-dispatch solve (``pipeline=True``, the
-default) must run the *bit-identical* update sequence of the legacy
-per-epoch host driver — per-device block permutations drawn inside the
-shard_map body must match the host draw exactly, alpha/w must agree to
-atol 1e-5 for every loss × delay_rounds on both 1-D and 2-D meshes, and
-the on-device duality-gap buffer must reproduce the driver's values and
-``gap_every`` schedule.  The double-buffered fused 2-D round
-(``overlap``) must agree with the unfused per-update-psum reference.
+(DESIGN.md §11): the single-dispatch solve must run the update sequence
+of the serial oracle (``conftest.serial_replay``: ``dcd_epoch`` fed the
+solver's own per-device block draws) — alpha/w must agree to atol 1e-5
+for every loss × delay_rounds × engine on the 1-D mesh and on the 2-D
+mesh, the per-device draw must cycle a permutation of the device's valid
+rows, and the on-device duality-gap buffer must hold the oracle's gaps
+on the ``gap_every`` schedule.  The double-buffered fused 2-D round
+(``overlap``) must agree with the oracle too.
 
 Also the regression tests for this PR's silent-data-loss fixes:
 ``dense_to_ell`` must raise on a lossy ``k_max`` instead of truncating
@@ -24,18 +24,14 @@ import sys
 import textwrap
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import serial_replay
 from repro.core import sharded_passcode_solve
 from repro.core.duals import Hinge, Logistic, SquaredHinge
-from repro.core.sharded import (
-    _device_block_perm,
-    _gap_slots,
-    _masked_block_perms,
-    _n_blocks,
-)
+from repro.core.objective import duality_gap
+from repro.core.sharded import _device_block_perm, _gap_slots, _n_blocks
 from repro.data.sparse import dense_to_ell
 
 
@@ -49,16 +45,35 @@ def mesh_2d():
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
-def _assert_same(r_a, r_b, *, gaps_tol=None):
-    np.testing.assert_allclose(np.asarray(r_a.alpha), np.asarray(r_b.alpha),
+def _assert_matches(r, ref, X=None, loss=None):
+    """(α, ŵ) equal to the oracle's at atol 1e-5; with ``X``, the last
+    recorded gap equal to the oracle's gap (f32 sums in another order)."""
+    np.testing.assert_allclose(np.asarray(r.alpha), np.asarray(ref.alpha),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(r_a.w_hat), np.asarray(r_b.w_hat),
+    np.testing.assert_allclose(np.asarray(r.w_hat), np.asarray(ref.w),
                                rtol=1e-5, atol=1e-5)
-    if gaps_tol is not None:
-        assert r_a.gaps.shape == r_b.gaps.shape
-        np.testing.assert_allclose(np.asarray(r_a.gaps),
-                                   np.asarray(r_b.gaps), rtol=gaps_tol,
-                                   atol=gaps_tol)
+    if X is not None:
+        g = float(duality_gap(ref.alpha, X, loss))
+        np.testing.assert_allclose(float(r.gaps[-1]), g, rtol=1e-3,
+                                   atol=1e-3)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["jnp", "pallas-interpret"])
+@pytest.mark.parametrize("delay_rounds", [0, 1])
+@pytest.mark.parametrize(
+    "loss", [Hinge(C=1.0), SquaredHinge(C=1.0), Logistic(C=1.0)],
+    ids=["hinge", "sq", "logistic"],
+)
+def test_pipeline_matches_serial_1d(tiny_ell, loss, delay_rounds,
+                                    use_kernel):
+    """Single-dispatch solve == serial DCD in its draw order, 1-D ELL
+    path, on the jnp engine and the streamed kernel."""
+    r = sharded_passcode_solve(tiny_ell, loss, epochs=2, block_size=32,
+                               delay_rounds=delay_rounds,
+                               use_kernel=use_kernel)
+    ref = serial_replay(tiny_ell, loss, epochs=2, block_size=32, seed=0)
+    _assert_matches(r, ref, tiny_ell, loss)
 
 
 @pytest.mark.parametrize("delay_rounds", [0, 1])
@@ -66,49 +81,34 @@ def _assert_same(r_a, r_b, *, gaps_tol=None):
     "loss", [Hinge(C=1.0), SquaredHinge(C=1.0), Logistic(C=1.0)],
     ids=["hinge", "sq", "logistic"],
 )
-def test_pipeline_matches_driver_1d(tiny_ell, loss, delay_rounds):
-    """Single-dispatch solve == per-epoch host driver, 1-D ELL path."""
-    kw = dict(epochs=2, block_size=32, delay_rounds=delay_rounds)
-    r_drv = sharded_passcode_solve(tiny_ell, loss, pipeline=False, **kw)
-    r_pipe = sharded_passcode_solve(tiny_ell, loss, pipeline=True, **kw)
-    _assert_same(r_pipe, r_drv, gaps_tol=1e-3)
+def test_pipeline_matches_serial_2d(tiny_ell, mesh_2d, loss, delay_rounds):
+    """Single-dispatch solve == serial DCD in its draw order, 2-D mesh."""
+    r = sharded_passcode_solve(tiny_ell, loss, mesh=mesh_2d, epochs=2,
+                               block_size=32, delay_rounds=delay_rounds)
+    ref = serial_replay(tiny_ell, loss, epochs=2, block_size=32, seed=0)
+    _assert_matches(r, ref, tiny_ell, loss)
 
 
-@pytest.mark.parametrize("delay_rounds", [0, 1])
-@pytest.mark.parametrize(
-    "loss", [Hinge(C=1.0), SquaredHinge(C=1.0), Logistic(C=1.0)],
-    ids=["hinge", "sq", "logistic"],
-)
-def test_pipeline_matches_driver_2d(tiny_ell, mesh_2d, loss, delay_rounds):
-    """Single-dispatch solve == per-epoch host driver, 2-D mesh."""
-    kw = dict(mesh=mesh_2d, epochs=2, block_size=32,
-              delay_rounds=delay_rounds)
-    r_drv = sharded_passcode_solve(tiny_ell, loss, pipeline=False, **kw)
-    r_pipe = sharded_passcode_solve(tiny_ell, loss, pipeline=True, **kw)
-    _assert_same(r_pipe, r_drv, gaps_tol=1e-3)
-
-
-def test_pipeline_matches_driver_dense(tiny_dense, hinge):
+def test_pipeline_matches_serial_dense(tiny_dense, hinge):
     """The dense 1-D engine pipelines too (X.T@α / X@w gap path)."""
-    kw = dict(epochs=2, block_size=32)
-    r_drv = sharded_passcode_solve(tiny_dense, hinge, pipeline=False, **kw)
-    r_pipe = sharded_passcode_solve(tiny_dense, hinge, pipeline=True, **kw)
-    _assert_same(r_pipe, r_drv, gaps_tol=1e-3)
+    r = sharded_passcode_solve(tiny_dense, hinge, epochs=2, block_size=32)
+    ref = serial_replay(tiny_dense, hinge, epochs=2, block_size=32, seed=0)
+    _assert_matches(r, ref, tiny_dense, hinge)
 
 
 def test_overlap_agrees_with_unfused(tiny_ell, hinge, mesh_2d):
     """The double-buffered fused round — stale base⁰ + Gram carried in
     flight, base repaired by ``dcd_feature_base_correction`` — is the
-    same update sequence as the eager per-update-psum engine."""
+    update sequence of the eager per-update-psum engine and of the
+    serial oracle."""
     kw = dict(mesh=mesh_2d, epochs=2, block_size=32, delay_rounds=1,
               record=False)
-    r_ref = sharded_passcode_solve(tiny_ell, hinge, pipeline=False, **kw)
+    r_eager = sharded_passcode_solve(tiny_ell, hinge, **kw)
     r_ov = sharded_passcode_solve(tiny_ell, hinge, use_kernel=True,
                                   overlap=True, **kw)
-    r_ov_drv = sharded_passcode_solve(tiny_ell, hinge, use_kernel=True,
-                                      overlap=True, pipeline=False, **kw)
-    _assert_same(r_ov, r_ref)
-    _assert_same(r_ov_drv, r_ref)
+    ref = serial_replay(tiny_ell, hinge, epochs=2, block_size=32, seed=0)
+    _assert_matches(r_eager, ref)
+    _assert_matches(r_ov, ref)
 
 
 def test_overlap_knob_validation(tiny_ell, hinge, mesh_2d):
@@ -128,34 +128,49 @@ def test_overlap_knob_validation(tiny_ell, hinge, mesh_2d):
     assert r.w_hat.shape[0] == tiny_ell.n_features
 
 
-def test_device_perm_bit_matches_host_draw():
-    """The in-body draw is bit-identical to the host driver's
-    ``_masked_block_perms`` — including devices whose shard is partly or
-    entirely padding — so pipeline=True/False run the same sequence."""
+def test_device_perm_cycles_valid_rows():
+    """Each device's in-body draw cycles a permutation of its valid rows
+    — including devices whose shard is partly or entirely padding — so
+    padding rows are never drawn where the device owns a real row; with
+    no padding it is the plain ``permutation(n_loc)`` of the device's
+    key, cycled to n_blocks·B slots."""
     for p, n_loc, n_rows, n_blocks, B in ((4, 26, 102, 4, 8),
                                           (1, 256, 256, 8, 32),
                                           (4, 8, 9, 2, 4)):  # dev 2+: pad
         key = jax.random.PRNGKey(7)
-        ref = _masked_block_perms(key, p, n_loc, n_rows, n_blocks, B)
-        got = jax.vmap(
-            lambda my: _device_block_perm(key, my, p, n_loc, n_rows,
-                                          n_blocks, B)
-        )(jnp.arange(p))
-        np.testing.assert_array_equal(np.asarray(got.reshape(p, -1)),
-                                      np.asarray(ref))
+        m = n_blocks * B
+        for my in range(p):
+            got = np.asarray(_device_block_perm(
+                key, my, p, n_loc, n_rows, n_blocks, B)).reshape(-1)
+            assert got.shape == (m,)
+            real = min(max(n_rows - my * n_loc, 0), n_loc)
+            v = max(real, 1)  # a padding-only device draws its row 0
+            first = got[:min(v, m)]
+            assert len(set(first.tolist())) == first.size
+            assert set(first.tolist()) <= set(range(v))
+            np.testing.assert_array_equal(got, got[np.arange(m) % v])
+            if real:
+                assert got.max() < real  # no padding row drawn
+            if real == n_loc:
+                perm = np.asarray(jax.random.permutation(
+                    jax.random.split(key, p)[my], n_loc))
+                np.testing.assert_array_equal(got,
+                                              perm[np.arange(m) % n_loc])
 
 
 def test_gap_buffer_honors_gap_every(tiny_ell, hinge):
     """Gaps accumulate into the preallocated on-device buffer on the
-    driver's exact schedule: every ``gap_every``-th epoch + the final."""
+    ``gap_every`` schedule — every ``gap_every``-th epoch + the final —
+    and each is the oracle's gap after that many epochs."""
     assert _gap_slots(5, 2) == 3 and _gap_slots(4, 2) == 2
     assert _gap_slots(3, 10) == 1 and _gap_slots(0, 1) == 0
-    kw = dict(epochs=5, block_size=32, gap_every=2)
-    r_drv = sharded_passcode_solve(tiny_ell, hinge, pipeline=False, **kw)
-    r_pipe = sharded_passcode_solve(tiny_ell, hinge, pipeline=True, **kw)
-    assert r_pipe.gaps.shape == (3,)  # epochs 2, 4 and the final 5
-    np.testing.assert_allclose(np.asarray(r_pipe.gaps),
-                               np.asarray(r_drv.gaps), rtol=1e-3)
+    r = sharded_passcode_solve(tiny_ell, hinge, epochs=5, block_size=32,
+                               gap_every=2)
+    assert r.gaps.shape == (3,)  # epochs 2, 4 and the final 5
+    want = [float(duality_gap(
+        serial_replay(tiny_ell, hinge, epochs=e, block_size=32,
+                      seed=0).alpha, tiny_ell, hinge)) for e in (2, 4, 5)]
+    np.testing.assert_allclose(np.asarray(r.gaps), want, rtol=1e-3)
     r_off = sharded_passcode_solve(tiny_ell, hinge, epochs=2,
                                    block_size=32, record=False)
     assert r_off.gaps.shape == (0,)
@@ -187,16 +202,13 @@ def test_epoch_visits_every_row():
     assert _n_blocks(10, 4) == 3 and _n_blocks(8, 4) == 2
     assert _n_blocks(3, 64) == 1
     X = 0.5 * np.eye(10, dtype=np.float32)
-    for pipeline in (True, False):
-        r = sharded_passcode_solve(X, Hinge(C=1.0), epochs=1,
-                                   block_size=4, record=False,
-                                   pipeline=pipeline)
-        assert (np.asarray(r.alpha) > 0).all(), (pipeline,
-                                                 np.asarray(r.alpha))
+    r = sharded_passcode_solve(X, Hinge(C=1.0), epochs=1, block_size=4,
+                               record=False)
+    assert (np.asarray(r.alpha) > 0).all(), np.asarray(r.alpha)
     # the ceil'd draw cycles valid rows instead of dropping them
-    perms = _masked_block_perms(jax.random.PRNGKey(0), 1, 10, 10,
-                                _n_blocks(10, 4), 4)
-    assert set(np.asarray(perms).ravel()) == set(range(10))
+    perm = _device_block_perm(jax.random.PRNGKey(0), 0, 1, 10, 10,
+                              _n_blocks(10, 4), 4)
+    assert set(np.asarray(perm).ravel()) == set(range(10))
 
 
 # ------------------------------------------------- multi-device case ----
@@ -204,9 +216,13 @@ def test_epoch_visits_every_row():
 
 _SUBPROCESS = textwrap.dedent("""
     import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys
     sys.path.insert(0, {src!r})
+    sys.path.insert(0, {tests!r})
+    # the test suite's conftest clears XLA_FLAGS on import: set them
+    # after it, before the first device query
+    from conftest import serial_replay
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, numpy as np
     from repro.core import sharded_passcode_solve
     from repro.core.duals import Hinge
@@ -225,43 +241,37 @@ _SUBPROCESS = textwrap.dedent("""
                    np.asarray(r.gaps))
     kw = dict(epochs=3, block_size=8)
 
-    # 1D: pipeline == driver, tail rows trained
-    a0, w0, g0 = A(sharded_passcode_solve(ell, loss, mesh=mesh1,
-                                          pipeline=False, **kw))
-    a1, w1, g1 = A(sharded_passcode_solve(ell, loss, mesh=mesh1,
-                                          pipeline=True, **kw))
+    # the p = 4 round-synchronous replay of the solver's draws
+    ref = serial_replay(ell, loss, epochs=3, block_size=8, seed=0, p=4)
+    a0, w0 = np.asarray(ref.alpha), np.asarray(ref.w)
+
+    # 1D: pipeline == oracle, tail rows trained
+    a1, w1, g1 = A(sharded_passcode_solve(ell, loss, mesh=mesh1, **kw))
     d1 = max(np.abs(a0 - a1).max(), np.abs(w0 - w1).max())
     assert d1 < 1e-5, d1
-    dg = np.abs(g0 - g1).max()
-    assert dg < 1e-2 * (1 + np.abs(g0).max()), (g0, g1)
     assert np.abs(a1[96:]).sum() > 0  # tail trained, not dropped
 
-    # 2D: pipeline == driver == 1D sequence
-    a2, w2, g2 = A(sharded_passcode_solve(ell, loss, mesh=mesh2,
-                                          pipeline=False, **kw))
-    a3, w3, g3 = A(sharded_passcode_solve(ell, loss, mesh=mesh2,
-                                          pipeline=True, **kw))
-    d2 = max(np.abs(a2 - a3).max(), np.abs(w2 - w3).max(),
-             np.abs(a1 - a3).max(), np.abs(w1 - w3).max())
+    # 2D: pipeline == 1D sequence
+    a3, w3, g3 = A(sharded_passcode_solve(ell, loss, mesh=mesh2, **kw))
+    d2 = max(np.abs(a1 - a3).max(), np.abs(w1 - w3).max())
     assert d2 < 1e-5, d2
+    assert np.abs(g1 - g3).max() < 1e-3 * (1 + np.abs(g1).max()), (g1, g3)
 
-    # fused overlap (delayed): same sequence as the unfused reference
+    # fused overlap (delayed): the oracle's sequence too
     kwd = dict(epochs=3, block_size=8, delay_rounds=1, record=False)
-    a4, w4, _ = A(sharded_passcode_solve(ell, loss, mesh=mesh2,
-                                         pipeline=False, **kwd))
     a5, w5, _ = A(sharded_passcode_solve(ell, loss, mesh=mesh2,
                                          use_kernel=True, overlap=True,
                                          **kwd))
-    d3 = max(np.abs(a4 - a5).max(), np.abs(w4 - w5).max())
+    d3 = max(np.abs(a0 - a5).max(), np.abs(w0 - w5).max())
     assert d3 < 1e-5, d3
     print("SUBPROCESS_OK", d1, d2, d3)
 """)
 
 
 def test_multi_device_pipeline_subprocess():
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    code = _SUBPROCESS.format(src=src)
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = _SUBPROCESS.format(src=os.path.join(here, "..", "src"),
+                              tests=here)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", code], env=env,

@@ -153,20 +153,11 @@ def test_pod_merge_policy_rejections():
     with pytest.raises(ValueError):
         pod_merge_policy(1, n_pods=0)
     with pytest.raises(ValueError):
-        pod_merge_policy(1, n_pods=2, pipeline=False)
-    with pytest.raises(ValueError):
         pod_merge_policy(1, n_pods=2, shrink_every=2)
     with pytest.raises(ValueError):
         pod_merge_policy(1, n_pods=2, overlap=True)
     with pytest.raises(ValueError):
         pod_merge_policy(1, n_pods=2, adaptive=True, record=False)
-
-
-def test_pod_mesh_rejects_host_driver(X102, sq_hinge):
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
-    with pytest.raises(ValueError):
-        sharded_passcode_solve(X102, sq_hinge, mesh=mesh, epochs=2,
-                               pipeline=False)
 
 
 def test_pod_mesh_rejects_shrinking(X102, sq_hinge):

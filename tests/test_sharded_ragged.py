@@ -26,13 +26,12 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from conftest import serial_replay
 from repro.core import dcd_solve, sharded_passcode_solve
 from repro.core import sharded
-from repro.core.dcd import DcdState, dcd_epoch
 from repro.core.duals import Hinge, Logistic, SquaredHinge
 from repro.core.objective import duality_gap
 from repro.data.sparse import (
-    CsrMatrix,
     EllMatrix,
     csr_from_rows,
     ell_append,
@@ -77,25 +76,6 @@ def csr():
     return _ragged()
 
 
-def _serial_in_solver_order(X: CsrMatrix, loss, *, epochs, block_size,
-                            seed):
-    """Serial DCD (``core/dcd.py``'s epoch) on the padded rows, in the
-    update order the one-device solver draws (its PRNG chain)."""
-    n = X.n_rows
-    n_blocks = sharded._n_blocks(n, block_size)
-    ell = X.to_ell()
-    sq = jnp.asarray(X.row_sq_norms())
-    state = DcdState(jnp.zeros((n,), jnp.float32),
-                     jnp.zeros((X.n_features,), jnp.float32))
-    key = jax.random.PRNGKey(seed)
-    for _ in range(epochs):
-        key, sub = jax.random.split(key)
-        perm = sharded._device_block_perm(sub, 0, 1, n, n, n_blocks,
-                                          block_size).reshape(-1)
-        state = dcd_epoch(ell, sq, state, perm, loss)
-    return state
-
-
 @pytest.mark.parametrize("delay_rounds", [0, 2])
 @pytest.mark.parametrize("loss", LOSSES, ids=LOSS_IDS)
 def test_ragged_solve_matches_serial_dcd(csr, loss, delay_rounds):
@@ -103,8 +83,7 @@ def test_ragged_solve_matches_serial_dcd(csr, loss, delay_rounds):
     r = sharded_passcode_solve(csr, loss, epochs=2, block_size=16,
                                delay_rounds=delay_rounds, seed=5)
     assert r.engine == "ragged/jnp"
-    ref = _serial_in_solver_order(csr, loss, epochs=2, block_size=16,
-                                  seed=5)
+    ref = serial_replay(csr, loss, epochs=2, block_size=16, seed=5)
     np.testing.assert_allclose(np.asarray(r.alpha), np.asarray(ref.alpha),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(r.w_hat), np.asarray(ref.w),
@@ -302,8 +281,6 @@ def _refusal(what, csr):
             csr, loss, y=np.ones((2, csr.n_rows), np.float32))
     if what == "shrink":
         return lambda: sharded.prepare_solver(csr, loss, shrink_every=1)
-    if what == "host_loop":
-        return lambda: sharded.prepare_solver(csr, loss, pipeline=False)
     if what == "ell_append":
         return lambda: ell_append(csr.to_ell(), csr)
     if what == "ell_repack":
@@ -316,8 +293,7 @@ def _refusal(what, csr):
 
 
 @pytest.mark.parametrize("what", ["2d", "pod", "task", "shrink",
-                                  "host_loop", "ell_append", "ell_repack",
-                                  "serve"])
+                                  "ell_append", "ell_repack", "serve"])
 def test_paths_without_ragged_rows_refuse_them(csr, what):
     with pytest.raises((ValueError, TypeError), match="CsrMatrix"):
         _refusal(what, csr)()
@@ -325,15 +301,17 @@ def test_paths_without_ragged_rows_refuse_them(csr, what):
 
 _SUBPROCESS = textwrap.dedent("""
     import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys
     sys.path.insert(0, {src!r})
     sys.path.insert(0, {tests!r})
+    # the test module imports the suite's conftest, which clears
+    # XLA_FLAGS: set them after it, before the first device query
+    from test_sharded_ragged import _ragged
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, numpy as np
     from repro.core import sharded_passcode_solve
     from repro.core.duals import Hinge
     from repro.dist.mesh import make_mesh
-    from test_sharded_ragged import _ragged
 
     assert len(jax.devices()) == 8
     mesh = make_mesh((8,), ("data",))

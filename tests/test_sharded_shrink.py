@@ -190,15 +190,12 @@ def test_adaptive_ratio_anneals_to_sync(tiny_ell, hinge):
 
 def test_self_tuning_validation(tiny_ell, hinge):
     """Invalid knob combinations raise instead of silently degrading."""
-    with pytest.raises(ValueError):  # driver path has no scan carry
-        sharded_passcode_solve(tiny_ell, hinge, epochs=1, shrink_every=1,
-                               pipeline=False)
     with pytest.raises(ValueError):  # controller needs the gap signal
         sharded_passcode_solve(tiny_ell, hinge, epochs=1, adaptive=True,
                                record=False)
     with pytest.raises(ValueError):  # repack without a mask to compact
         resolve_self_tuning(0, True, False, overlap_knob="auto",
-                            overlap_on=False, pipeline=True, record=True)
+                            overlap_on=False, record=True)
     with pytest.raises(ValueError):  # overlapped gram vs repacked draw
         mesh2 = jax.make_mesh((1, 1), ("data", "model"))
         sharded_passcode_solve(tiny_ell, hinge, mesh=mesh2, epochs=1,
