@@ -21,7 +21,11 @@ resident grows with neither n_loc nor k; the VMEM policy is
 
 The padded primal (d₁ = d+1 lane-padded words; slot d is the *dummy
 slot*) lives in VMEM as a (d₁/128, 128) ref, so feature c sits at
-sublane row c // 128, lane c % 128.  Per update t of the block:
+sublane row c // 128, lane c % 128.  Every id a walk reads lies in
+[0, d] (padding is d, the dummy slot), never below 0, so the walks form
+that row and lane as ``c >> 7`` and ``c & 127``: the same two numbers
+as the signed floor division and remainder, without their divide and
+sign corrections on every nonzero.  Per update t of the block:
 
   * wait for row t's ids and values in SMEM and start row t+1's copy;
   * w·x_i = Σ_j w[c_j]·v_j — per nonzero, one dynamic row slice of the
@@ -54,6 +58,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+# a feature id c ≥ 0 splits into its primal row c >> LANE_BITS and lane
+# c & (LANES - 1): two scalar ops, where c // LANES and c % LANES on a
+# signed int32 cost a divide, a remainder and their floor corrections
+LANE_BITS = LANES.bit_length() - 1
+assert LANES == 1 << LANE_BITS
 # Nonzeros walked per loop iteration.  Mosaic lowers a fori_loop either
 # rolled or fully unrolled, so the walks unroll by hand.  On a TPU v5e a
 # row walk costs 55 ns per nonzero and walk rolled, 11.5 ns unrolled by
@@ -94,8 +103,8 @@ def row_dot(w_ref, cbuf, vbuf, k=None):
 
     def body(j, acc):
         c = cbuf[0, j]
-        r = w_ref[pl.ds(c // LANES, 1), :]
-        return acc + jnp.where(lane == c % LANES, r, 0.0) * vbuf[0, j]
+        r = w_ref[pl.ds(c >> LANE_BITS, 1), :]
+        return acc + jnp.where(lane == c & (LANES - 1), r, 0.0) * vbuf[0, j]
 
     acc = _walk(cbuf.shape[1] if k is None else k, body,
                 jnp.zeros((1, LANES), jnp.float32))
@@ -106,12 +115,13 @@ def row_axpy(w_ref, cbuf, vbuf, scale, k=None):
     """w[c_j] += scale·v_j over the first ``k`` entries of the SMEM row
     pair; ``scale`` is (1, 1)."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    scale = jnp.broadcast_to(scale, (1, LANES))  # once, not per nonzero
 
     def body(j, carry):
         c = cbuf[0, j]
-        r = pl.ds(c // LANES, 1)
+        r = pl.ds(c >> LANE_BITS, 1)
         w_ref[r, :] = w_ref[r, :] + jnp.where(
-            lane == c % LANES, scale * vbuf[0, j], 0.0)
+            lane == c & (LANES - 1), scale * vbuf[0, j], 0.0)
         return carry
 
     _walk(cbuf.shape[1] if k is None else k, body, 0)
@@ -131,7 +141,7 @@ def stream_rows(cols, vals):
 
 def _row_copies(col_hbm, val_hbm, idx_ref, t, cbuf, vbuf, sem):
     """The DMAs that bring step ``t``'s row into SMEM slot t % 2."""
-    i, slot = idx_ref[t], t % 2
+    i, slot = idx_ref[t], t & 1
     return (pltpu.make_async_copy(col_hbm.at[i], cbuf.at[slot],
                                   sem.at[0, slot]),
             pltpu.make_async_copy(val_hbm.at[i], vbuf.at[slot],
@@ -171,7 +181,7 @@ def _dcd_ell_stream_kernel(
                                   vbuf, sem):
                 cp.start()
 
-        cb, vb = cbuf.at[t % 2], vbuf.at[t % 2]
+        cb, vb = cbuf.at[t & 1], vbuf.at[t & 1]
         step = pl.ds(t, 1)
         yi = row_ref[3, step, :]  # (1, 1) ±1 — folds the row on read
         wx = yi * row_dot(w_out, cb, vb, k)
@@ -256,6 +266,8 @@ def dcd_ell_block_pallas_call(
 
 GRAIN = UNROLL  # slots per walk group; a packed row is a multiple of it
 GROUPS_PER_TILE = LANES // GRAIN
+GROUP_BITS = GROUPS_PER_TILE.bit_length() - 1
+assert GROUPS_PER_TILE == 1 << GROUP_BITS
 # lane tiles per SMEM row slot: 512 words, the slot news20's 455-wide
 # rows already take; two slots of ids and values are 8 KiB of SMEM
 CHUNK_TILES = 4
@@ -267,12 +279,14 @@ def _ragged_dot(w_ref, cbuf, vbuf, base, lo, hi, acc):
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
     def group(g, acc):
-        tile, l0 = base + g // GROUPS_PER_TILE, (g % GROUPS_PER_TILE) * GRAIN
+        tile = base + (g >> GROUP_BITS)
+        l0 = (g & (GROUPS_PER_TILE - 1)) * GRAIN
+        cb, vb = cbuf.at[tile], vbuf.at[tile]
         for u in range(GRAIN):
-            c = cbuf[tile, 0, l0 + u]
-            r = w_ref[pl.ds(c // LANES, 1), :]
-            acc = acc + jnp.where(lane == c % LANES, r, 0.0) * vbuf[
-                tile, 0, l0 + u]
+            c = cb[0, l0 + u]
+            r = w_ref[pl.ds(c >> LANE_BITS, 1), :]
+            acc = acc + jnp.where(lane == c & (LANES - 1), r, 0.0) * vb[
+                0, l0 + u]
         return acc
 
     return jax.lax.fori_loop(lo, hi, group, acc)
@@ -281,14 +295,17 @@ def _ragged_dot(w_ref, cbuf, vbuf, base, lo, hi, acc):
 def _ragged_axpy(w_ref, cbuf, vbuf, base, lo, hi, scale):
     """w[c_j] += scale·v_j over the groups [lo, hi) of one chunk."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    scale = jnp.broadcast_to(scale, (1, LANES))  # once, not per slot
 
     def group(g, carry):
-        tile, l0 = base + g // GROUPS_PER_TILE, (g % GROUPS_PER_TILE) * GRAIN
+        tile = base + (g >> GROUP_BITS)
+        l0 = (g & (GROUPS_PER_TILE - 1)) * GRAIN
+        cb, vb = cbuf.at[tile], vbuf.at[tile]
         for u in range(GRAIN):
-            c = cbuf[tile, 0, l0 + u]
-            r = pl.ds(c // LANES, 1)
+            c = cb[0, l0 + u]
+            r = pl.ds(c >> LANE_BITS, 1)
             w_ref[r, :] = w_ref[r, :] + jnp.where(
-                lane == c % LANES, scale * vbuf[tile, 0, l0 + u], 0.0)
+                lane == c & (LANES - 1), scale * vb[0, l0 + u], 0.0)
         return carry
 
     jax.lax.fori_loop(lo, hi, group, 0)

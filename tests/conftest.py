@@ -93,6 +93,30 @@ def serial_replay(X, loss, epochs, block_size, seed, p=1):
     return DcdState(state.alpha[:n], state.w)
 
 
+def lane_edge_rows(d: int, seed: int = 5):
+    """(ids, values, w) whose ids sit at the lane edges 0, 127, 128, 129
+    and d − 1, with d + 1 a whole number of lane tiles so that the dummy
+    slot d is the padded primal's last word; no id is shared by two rows,
+    and the values and w are multiples of 1/8 and 1/16 in [−1, 1] and
+    [−2, 2], so every row's products, and their sums in any order, are
+    exact in f32 and each visit reads the starting w: two engines that
+    split the ids alike then agree to the bit."""
+    assert (d + 1) % 128 == 0 and d >= 1023
+    rng = np.random.default_rng(seed)
+    edges = [[0, 127, 128, 129, d - 1], [1, 126, 255, 256, 257, 383, 384],
+             [130, 254, d - 2]]
+    rest = np.setdiff1d(np.arange(d), np.concatenate(edges))
+    long_row = np.sort(rng.choice(rest, 600, replace=False))
+    rest = rng.permutation(np.setdiff1d(rest, long_row))
+    rows = edges + [long_row] + [np.sort(r) for r in
+                                 np.split(rest[:60], [7, 20, 26, 41])]
+    vals = [rng.integers(-8, 9, len(r)) / 8.0 for r in rows]
+    vals = [np.where(v == 0.0, 0.125, v).astype(np.float32) for v in vals]
+    w = np.zeros(d + 1, np.float32)
+    w[:d] = rng.integers(-32, 33, d) / 16.0
+    return [np.asarray(r, np.int32) for r in rows], vals, w
+
+
 @pytest.fixture(scope="session")
 def tiny():
     return make_dataset("tiny")

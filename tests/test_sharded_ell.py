@@ -23,6 +23,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from conftest import lane_edge_rows
 from repro.core import duality_gap, sharded_passcode_solve
 from repro.core.duals import Hinge, Logistic, SquaredHinge
 from repro.core.sharded import _local_block_update_ell, _resolve_kernel_mode
@@ -95,8 +96,24 @@ def test_ell_vmem_policy_admits_what_dense_rejects():
 def _stream_case(case: str, seed: int = 7):
     """One block's operands for the streamed kernel and the jnp engine:
     rows of 73 nonzeros over d = 300 (k and d₁ off the 128-lane tile),
-    a padding row, and a primal and duals away from zero."""
+    a padding row, and a primal and duals away from zero; or, for
+    ``lane_edges``, ``lane_edge_rows`` padded to the widest row with the
+    dummy slot, each row visited once."""
     rng = np.random.default_rng(seed)
+    if case == "lane_edges":
+        d = 1023
+        rows, rvals, w = lane_edge_rows(d)
+        k = max(len(r) for r in rows)
+        n = len(rows) + 1  # and an all-padding row
+        ids = np.full((n, k), d, np.int32)
+        vals = np.zeros((n, k), np.float32)
+        for i, (r, v) in enumerate(zip(rows, rvals)):
+            ids[i, :len(r)], vals[i, :len(v)] = r, v
+        sq = np.maximum((vals * vals).sum(1), 1.0).astype(np.float32)
+        alpha = rng.uniform(0.0, 1.0, n).astype(np.float32)
+        return (jnp.asarray(ids), jnp.asarray(vals), jnp.asarray(sq),
+                jnp.asarray(alpha), jnp.asarray(w),
+                jnp.asarray(rng.permutation(n).astype(np.int32)), None, None)
     n, k, d, b = (40, 73, 300, 64) if case == "revisit" else (150, 73, 300, 64)
     ids = np.stack([np.sort(rng.choice(d, k, replace=False))
                     for _ in range(n)]).astype(np.int32)
@@ -124,22 +141,29 @@ def _stream_case(case: str, seed: int = 7):
             jnp.asarray(idx.astype(np.int32)), act, y)
 
 
-@pytest.mark.parametrize("case", ["k73", "revisit", "act_y"])
+@pytest.mark.parametrize("case", ["k73", "revisit", "act_y", "lane_edges"])
 @pytest.mark.parametrize("loss", LOSSES, ids=LOSS_IDS)
 def test_streamed_block_matches_jnp_engine(loss, case):
     """One block of the streamed kernel (interpret mode) against the jnp
-    ELL engine on the same operands: same α and Δw to atol 1e-5."""
+    ELL engine on the same operands: same α and Δw to atol 1e-5, and to
+    the bit on the exact ``lane_edges`` rows."""
     cols, vals, sq, alpha, w, idx, act, y = _stream_case(case)
     a_ref, dw_ref = _local_block_update_ell(cols, vals, sq, alpha, w, idx,
                                             loss, act=act, y=y)
     a_k, dw_k = dcd_ell_block_update_pallas(
         stream_rows(cols, vals), sq, alpha, w, idx, k=cols.shape[1],
         loss=loss, interpret=True, active=act, y=y)
-    np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_ref),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(dw_k), np.asarray(dw_ref),
-                               rtol=1e-5, atol=1e-5)
-    assert np.all(np.asarray(dw_k)[300:] == 0.0)  # dummy and lane slots
+    if case == "lane_edges":
+        np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_ref))
+        np.testing.assert_array_equal(np.asarray(dw_k), np.asarray(dw_ref))
+        assert np.asarray(dw_k)[:-1].any()
+    else:
+        np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_ref),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(dw_k), np.asarray(dw_ref),
+                                   rtol=1e-5, atol=1e-5)
+    d = w.shape[0] - 1 if case == "lane_edges" else 300
+    assert np.all(np.asarray(dw_k)[d:] == 0.0)  # dummy and lane slots
 
 
 @pytest.mark.parametrize("delay_rounds", [0, 1])

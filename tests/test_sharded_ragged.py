@@ -26,7 +26,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from conftest import serial_replay
+from conftest import lane_edge_rows, serial_replay
 from repro.core import dcd_solve, sharded_passcode_solve
 from repro.core import sharded
 from repro.core.duals import Hinge, Logistic, SquaredHinge
@@ -124,8 +124,20 @@ def test_dcd_solve_takes_ragged_rows(csr, hinge):
 def _block_case(case: str, seed: int = 11):
     """One block's operands over packed rows for the kernel and the jnp
     engine: short rows, rows spanning 2–4 SMEM chunks, a revisiting
-    block, and the active-mask and label operands."""
+    block, and the active-mask and label operands; or, for
+    ``lane_edges``, ``lane_edge_rows`` and an empty row, each visited
+    once (the 600-slot row streams in two chunks)."""
     rng = np.random.default_rng(seed)
+    if case == "lane_edges":
+        d = 1023
+        rows, vals, w = lane_edge_rows(d)
+        X = csr_from_rows(list(zip(rows, vals)) + [((), ())], d)
+        n = X.n_rows
+        p = pack_ragged(X, 1, n, grain=GRAIN)
+        sq = np.maximum(X.row_sq_norms(), 1.0).astype(np.float32)
+        alpha = rng.uniform(0.0, 1.0, n).astype(np.float32)
+        return p, jnp.asarray(sq), jnp.asarray(alpha), jnp.asarray(w), \
+            jnp.asarray(rng.permutation(n).astype(np.int32)), None, None
     long_rows = (600, 1500, 513) if case == "chunked" else ()
     X = _ragged(n=40, d=1600, seed=seed, long_rows=long_rows)
     n = X.n_rows
@@ -150,28 +162,39 @@ def _block_case(case: str, seed: int = 11):
         jnp.asarray(idx.astype(np.int32)), act, y
 
 
-@pytest.mark.parametrize("case", ["short", "chunked", "revisit", "act_y"])
+@pytest.mark.parametrize("case", ["short", "chunked", "revisit", "act_y",
+                                  "lane_edges"])
 @pytest.mark.parametrize("loss", LOSSES, ids=LOSS_IDS)
 def test_streamed_ragged_block_matches_jnp_engine(loss, case):
+    """The same α and Δw as the jnp engine to atol 1e-5, and to the bit
+    on the exact ``lane_edges`` rows."""
     p, sq, alpha, w, idx, act, y = _block_case(case)
     cols, vals = jnp.asarray(p.cols), jnp.asarray(p.vals)
     ptr, wid = jnp.asarray(p.ptr), jnp.asarray(p.wid)
+    span = CHUNK_TILES * LANES
+    tiles = -(-(p.ptr % LANES + p.wid) // LANES)
     if case == "chunked":
-        span = CHUNK_TILES * LANES
-        tiles = -(-(p.ptr % LANES + p.wid) // LANES)
         assert (tiles * LANES > span).sum() >= 3  # rows in 2+ chunks
         assert (-(-tiles // CHUNK_TILES)).max() >= 3
+    if case == "lane_edges":
+        assert (-(-tiles // CHUNK_TILES)).max() == 2
     a_k, dw_k = dcd_ragged_block_update_pallas(
         ragged_stream_rows(cols, vals), ptr, wid, sq, alpha, w, idx,
         loss=loss, interpret=True,
         active=None if act is None else act.astype(jnp.float32), y=y)
     a_j, dw_j = sharded._local_block_update_ragged(
         (cols, vals, ptr, wid), sq, alpha, w, idx, loss, act=act, y=y)
-    np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_j),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(dw_k), np.asarray(dw_j),
-                               rtol=1e-5, atol=1e-5)
-    assert float(jnp.abs(dw_k[1600:]).max()) == 0.0  # dummy + lane pad
+    if case == "lane_edges":
+        np.testing.assert_array_equal(np.asarray(a_k), np.asarray(a_j))
+        np.testing.assert_array_equal(np.asarray(dw_k), np.asarray(dw_j))
+        assert np.asarray(dw_k)[:-1].any()
+    else:
+        np.testing.assert_allclose(np.asarray(a_k), np.asarray(a_j),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(dw_k), np.asarray(dw_j),
+                                   rtol=1e-5, atol=1e-5)
+    d = w.shape[0] - 1 if case == "lane_edges" else 1600
+    assert float(jnp.abs(dw_k[d:]).max()) == 0.0  # dummy + lane pad
 
 
 @settings(max_examples=25, deadline=None)
@@ -237,11 +260,12 @@ def test_ragged_kernel_admission():
 # sha256 of the one-epoch pipeline's jaxpr for a fixed-width EllMatrix
 # (source locations stripped) — the jnp engine as the program compiled
 # before ragged rows existed, and the streamed kernel in interpret mode
-# with the gap's products as the one-hot MXU pass (kernels/gap_onehot.py);
+# with the gap's products as the one-hot MXU pass (kernels/gap_onehot.py)
+# and its walks splitting ids by shift and mask (kernels/dcd_ell.py);
 # JAX 0.9.0.  A fixed-width matrix takes exactly the ELL path.
 FIXED_JAXPR_SHA256 = {
     False: "60a2abe75fa494f7",
-    True: "3858bcfeb8fa81dc",
+    True: "6059152d9b23ee1e",
 }
 
 

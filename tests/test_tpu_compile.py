@@ -61,6 +61,35 @@ N_FEAT = _frontier(lambda n: dcd_feature_kernel_fits(
     n, K_RCV1, D_RCV1, block_size=B))
 
 
+def test_walks_lower_without_divides(tmp_path):
+    """The streamed ELL and ragged kernels at rcv1's and rcv1-tail's full
+    shapes, compiled with Mosaic's dump on (``scripts/walk_llo.py``): no
+    integer divide in any loop that walks nonzeros — ids split by shift
+    and mask, the ragged slot read at its group's tile — and at most one
+    cross-lane permute in the ELL kernel, the axpy's scale broadcast once
+    per row rather than once per nonzero.  The compile runs in a child
+    whose ``LIBTPU_INIT_ARGS`` carries the dump flag, read only when
+    libtpu starts; this test comes first in the file so that the child
+    starts before this process loads libtpu for the fixtures below."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).parent.parent / "scripts" / "walk_llo.py"
+    spec = importlib.util.spec_from_file_location("walk_llo", path)
+    walk_llo = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(walk_llo)
+    rc = walk_llo.dump(tmp_path, timeout=120.0)
+    if rc == 2:
+        pytest.skip("no v5e:2x2 topology can be described here")
+    assert rc == 0
+    ell, ragged = (walk_llo.count(walk_llo.final_llo(tmp_path, k))
+                   for k in walk_llo.KERNELS)
+    for c in (ell, ragged):
+        assert c["walk_loops"] >= 2  # the dot's walk and the axpy's
+        assert c["walk_divides"] == 0, c
+    assert ell["vperm"] <= 1, ell
+
+
 @pytest.fixture(scope="module")
 def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
